@@ -34,13 +34,9 @@ scaling-efficiency curves from ``bench_scaling.scaling_curves``.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import textwrap
 
-from .common import csv_row, write_bench_json
+from .common import csv_row, run_cpu_child, write_bench_json
 
 _SCRIPT = textwrap.dedent("""
     import os
@@ -265,15 +261,10 @@ _SCRIPT = textwrap.dedent("""
 
 
 def _measured():
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(__file__), "..", "src"))
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _SCRIPT], capture_output=True,
-                       text=True, env=env, timeout=560)
-    if r.returncode != 0:
-        raise RuntimeError(r.stderr[-2000:])
-    line = [l for l in r.stdout.splitlines() if l.startswith("RESULTS ")][-1]
-    return json.loads(line[len("RESULTS "):])
+    res, platform = run_cpu_child(_SCRIPT)
+    for r in res["rows"]:
+        r["platform"] = platform
+    return res
 
 
 def run(print_rows=True):

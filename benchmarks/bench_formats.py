@@ -163,8 +163,7 @@ def run_corpus(print_rows=True):
         sd_t = ops.as_device(m, **tuned.build_kwargs())
         fns["tuned"] = (lambda f, v: (lambda: f(v)))(
             jax.jit(lambda v, s=sd_t: s.matvec(v, backend=backend)), x)
-        pick = ops.select_format(m, diag_align=16,
-                                 x_tiles=ops.choose_x_tiles(m.shape[1], 4))
+        pick = ops.select_format(m, diag_align=16)
         times = _interleaved_times(fns)
         fmt_times = {k: v for k, v in times.items() if k != "tuned"}
         best_fmt = min(fmt_times, key=fmt_times.get)

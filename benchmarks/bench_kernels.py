@@ -75,8 +75,8 @@ def bytes_per_nnz_rows(m, x, truth, mat: str, fmt: str, rows: list,
         # vectors stay f32 whatever the stored width (vec_bytes default)
         pred_s = PM.predicted_spmv_seconds(
             sd.storage_elements(), n, n_nzr,
-            perm_bytes=PM.perm_traffic_bytes(n, 4,
-                                             window_local=(fmt != "pjds")),
+            perm_bytes=(PM.perm_traffic_bytes(n, 4)
+                        if fmt in PM.SORTED_ROW_FORMATS else 0.0),
             value_bytes=vb, index_bytes=ib)
         f = jax.jit(lambda v, sd=sd: sd.matvec(v, backend="ref"))
         xv = jnp.asarray(x)

@@ -17,14 +17,10 @@ full-slice 1-D, gathered/overlap 1-D, and the 2-D grid — whose rows
 CI artifact)."""
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import textwrap
 
 from repro.core import perf_model as PM
-from .common import csv_row
+from .common import csv_row, run_cpu_child
 
 _SCRIPT = textwrap.dedent("""
     import os
@@ -66,15 +62,10 @@ _SCRIPT = textwrap.dedent("""
 
 
 def _measured():
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(__file__), "..", "src"))
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _SCRIPT], capture_output=True,
-                       text=True, env=env, timeout=560)
-    if r.returncode != 0:
-        raise RuntimeError(r.stderr[-2000:])
-    line = [l for l in r.stdout.splitlines() if l.startswith("RESULTS ")][-1]
-    return json.loads(line[len("RESULTS "):])
+    rows, platform = run_cpu_child(_SCRIPT)
+    for r in rows:
+        r["platform"] = platform
+    return rows
 
 
 _CURVES_SCRIPT = textwrap.dedent("""
@@ -171,15 +162,9 @@ _CURVES_SCRIPT = textwrap.dedent("""
 def scaling_curves(print_rows=True):
     """Measured strong/weak parallel-efficiency rows (see module
     docstring); consumed by ``bench_dist`` into ``BENCH_dist.json``."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(__file__), "..", "src"))
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _CURVES_SCRIPT],
-                       capture_output=True, text=True, env=env, timeout=560)
-    if r.returncode != 0:
-        raise RuntimeError(r.stderr[-2000:])
-    line = [l for l in r.stdout.splitlines() if l.startswith("RESULTS ")][-1]
-    rows = json.loads(line[len("RESULTS "):])
+    rows, platform = run_cpu_child(_CURVES_SCRIPT)
+    for r in rows:
+        r["platform"] = platform
     if print_rows:
         for row in rows:
             print(csv_row(

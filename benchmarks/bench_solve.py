@@ -172,8 +172,8 @@ def run(print_rows=True):
         m = make()
         rng = seeded_rng()
         b = jnp.asarray(rng.standard_normal(m.n_rows).astype(np.float32))
-        op = operator(m, format="sell", x_tiles=1)
-        op_lo = operator(m, format="sell", x_tiles=1,
+        op = operator(m, format="sell")
+        op_lo = operator(m, format="sell",
                          dtype=jnp.bfloat16, index_dtype="auto")
 
         t_launch, t_fused, t_lo = (
@@ -253,7 +253,7 @@ def run(print_rows=True):
     m = make()
     rng = seeded_rng()
     b = jnp.asarray(rng.standard_normal(m.n_rows).astype(np.float32))
-    op = operator(m, format="sell", x_tiles=1)
+    op = operator(m, format="sell")
     bare_fn = lambda: jax.block_until_ready(api._one_solve(
         op, b, method=method, strategy="fused",
         maxiter=LADDER_PROBE_ITERS, tol=0.0, precond=None).x)

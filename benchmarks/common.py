@@ -2,7 +2,10 @@
 from __future__ import annotations
 
 import json
+import os
 import pathlib
+import subprocess
+import sys
 import time
 
 import jax
@@ -33,6 +36,34 @@ def time_fn(fn, *args, warmup: int = 2, iters: int = 10) -> float:
 
 def csv_row(name: str, us_per_call: float, derived: str) -> str:
     return f"{name},{us_per_call:.1f},{derived}"
+
+
+# Appended to every child script: the child names the platform it ran on.
+_PLATFORM_LINE = ("\nimport jax as _jax\n"
+                  "print('PLATFORM ' + _jax.devices()[0].platform)\n")
+
+
+def run_cpu_child(script: str, timeout: int = 560):
+    """Run ``script`` (which sets its own virtual-device count and prints
+    one ``RESULTS <json>`` line) in a child Python process pinned to the
+    CPU backend, and return ``(results, platform)``.
+
+    The children exist to emulate a multi-device mesh on host CPUs.
+    ``JAX_PLATFORMS=cpu`` keeps them off any accelerator: a parent that
+    already holds a TPU never has a child reaching for it (the chip
+    belongs to one process at a time)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", script + _PLATFORM_LINE],
+                       capture_output=True, text=True, env=env,
+                       timeout=timeout)
+    if r.returncode != 0:
+        raise RuntimeError(r.stderr[-2000:])
+    lines = r.stdout.splitlines()
+    res = [l for l in lines if l.startswith("RESULTS ")][-1]
+    platform = [l for l in lines if l.startswith("PLATFORM ")][-1]
+    return json.loads(res[len("RESULTS "):]), platform[len("PLATFORM "):]
 
 
 def _jsonable(o):

@@ -61,13 +61,14 @@ def solver_pricing(matrix: str, scale: float, method: str) -> list[dict]:
     rows = []
     for dlabel, dtype in (("f32", None), ("bf16", jnp.bfloat16)):
         sd = ops.as_device(m, format="sell", dtype=dtype,
-                           index_dtype="auto", x_tiles=1)
+                           index_dtype="auto")
         vb = jnp.dtype(sd.value_dtype).itemsize
         ib = jnp.dtype(sd.index_dtype).itemsize
         stored = sd.storage_elements()
-        spmv_only = PM.SOLVER_SPMV_COUNT[method] * PM.spmvm_bytes(
-            stored, m.n_rows, 1.0 / max(m.n_nzr, 1.0), m.n_nzr,
-            value_bytes=vb, index_bytes=ib, vec_bytes=4)
+        spmv_only = PM.SOLVER_SPMV_COUNT[method] * (
+            PM.spmvm_bytes(stored, m.n_rows, 0.0, m.n_nzr,
+                           value_bytes=vb, index_bytes=ib, vec_bytes=4)
+            + PM.gathered_rhs_bytes(stored, 4))
         for strategy in ("composed", "fused"):
             full = PM.solver_iteration_bytes(
                 stored, m.n_rows, m.n_nzr, method=method,

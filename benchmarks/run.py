@@ -1,7 +1,10 @@
 """Benchmark driver: one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows.  Heavy multi-device cases
-run in subprocesses so this process keeps one device.
+run in child processes pinned to the CPU (``JAX_PLATFORMS=cpu``, 8
+virtual devices, ``common.run_cpu_child``), so this process keeps its
+one device: on a TPU host the chip belongs to this process alone, and
+no child ever reaches for it.
 
     PYTHONPATH=src python -m benchmarks.run [--only table1,fig3,...]
 """
@@ -19,6 +22,9 @@ def main() -> None:
                          "ops,dist,tune,solve,serve,formats")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import (bench_formats, bench_histograms, bench_perf_model,
                    bench_scaling, bench_kernels, bench_sell, bench_sparse_ffn,

@@ -1,40 +1,2 @@
-"""Compatibility shims for the pinned toolchain (jax==0.4.37).
-
-The repo targets the modern jax API surface (``jax.shard_map``,
-``jax.set_mesh``, ``jax.sharding.AxisType``); this module backfills the
-pieces that 0.4.x spells differently so the same code runs on both.
-Import from here instead of guarding at each call site:
-
-    from repro._compat import shard_map, set_mesh, AxisType
-"""
-from __future__ import annotations
-
-import jax
-
-try:  # jax >= 0.5 explicit-sharding API
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - taken on jax 0.4.x
-    AxisType = None
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - taken on jax 0.4.x
-    from jax.experimental.shard_map import shard_map
-
-
-def set_mesh(mesh):
-    """``with set_mesh(mesh): ...`` on any jax version.
-
-    Uses ``jax.set_mesh`` when present; on 0.4.x a ``Mesh`` is its own
-    ambient-mesh context manager, so the mesh itself is returned.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+"""Test-support shims: ``hypothesis_fallback`` stands in for the
+``hypothesis`` package where it is not installed."""

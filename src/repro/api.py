@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import math
 import time
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -86,32 +87,21 @@ def _is_sub_f32(dtype) -> bool:
 
 
 def _fused_eligible(op, method: str, precond, b: jax.Array) -> bool:
-    """The fused iteration needs: a single-device SELL operand with the
-    resident-x grid (x_tiles == 1 — the fused epilogue runs once per
-    window), square, 1-D RHS, no preconditioner (the epilogue reduces
-    plain dots), and a cg/bicgstab recurrence."""
+    """The fused iteration needs: a single-device SELL operand, square,
+    1-D RHS, no preconditioner (the fused step reduces plain dots), and
+    a cg/bicgstab recurrence."""
     from repro.core.operator import DeviceOperator
     return (method in ("cg", "bicgstab") and precond is None
             and b.ndim == 1 and isinstance(op, DeviceOperator)
-            and op.fmt == "sell" and op.dev.x_tiles == 1
+            and op.fmt == "sell"
             and op.shape[0] == op.shape[1])
 
 
 def _fused_dots_of(op):
-    """The fused-pass closure over ``op``'s SELL operand, cached on the
-    operator instance — it is the static jit key of the fused solvers,
-    so one closure per operand means one compile per operand."""
-    cached = getattr(op, "_fused_dots", None)
-    if cached is not None:
-        return cached
+    """The fused-pass callable over ``op``'s SELL operand."""
     from repro.kernels import ops as K
     from repro.kernels.fused_iter import make_matvec_dots
-    mvd = make_matvec_dots(op.dev.dev, backend=K.resolve_backend(op.backend))
-    try:
-        op._fused_dots = mvd
-    except (AttributeError, TypeError):
-        pass
-    return mvd
+    return make_matvec_dots(op.dev.dev, backend=K.resolve_backend(op.backend))
 
 
 def _cast_low_precision(op):
@@ -440,6 +430,15 @@ def _ladder_solve(op, op_lo, b, *, method, strategy, maxiter, tol, precond,
                          precond=precond, fallback=fallback)
     ladder, res, warm = [], None, None
     for rung in rungs:
+        if rung["label"] == "kernel->ref":
+            # The ref path is a different, much slower program: never
+            # take it without a trace of why the kernels did not serve.
+            last = ladder[-1] if ladder else {}
+            warnings.warn(
+                "repro.solve: the Pallas kernel path failed ("
+                + last.get("error", f"status {last.get('status')}")
+                + "); retrying on the XLA reference path",
+                RuntimeWarning, stacklevel=3)
         rung_x0 = None if rung["fresh_x0"] else (x0 if warm is None else warm)
         try:
             rn_prev, restarts = float("inf"), 0

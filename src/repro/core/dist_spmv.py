@@ -99,9 +99,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import formats as F
-from repro._compat import shard_map
 from repro.kernels import ops
 from repro.kernels import ref as R
+from repro.kernels._backend import group_max_chunks
 
 Mode = Literal["vector", "naive", "overlap", "pipeline"]
 Halo = Literal["gathered", "full"]
@@ -558,16 +558,13 @@ def partition_csr(
     n_blocks = blk_rows // b_r
 
     def _max_chunks(devs) -> int:
-        # Static per-block chunk ceiling ACROSS devices, including the
-        # phantom chunks the shared-extent padding appends to each
+        # Static per-output-group chunk ceiling ACROSS devices, including
+        # the phantom chunks the shared-extent padding appends to each
         # device's last block.
         longest = max(int(dv.chunk_map.shape[0]) for dv in devs)
-        mx = 1
-        for dv in devs:
-            cm = _pad_lead(np.asarray(dv.chunk_map), longest, edge=True)
-            if len(cm):
-                mx = max(mx, int(np.bincount(cm, minlength=1).max()))
-        return mx
+        return max(group_max_chunks(_pad_lead(np.asarray(dv.chunk_map),
+                                              longest, edge=True))
+                   for dv in devs)
 
     return DistPJDS(
         loc_val=_stack(locs, "val"),
@@ -833,6 +830,17 @@ def _pipeline_body(dist: DistPJDS, x_blk, loc_spmv, loc_args, *, axis: str,
     return y
 
 
+def place_on_mesh(tree, mesh: Mesh, axis: str = "data"):
+    """Commit every array of ``tree`` (a ``DistPJDS`` or a global padded
+    vector) to ``mesh``, split along its leading axis — one row slab per
+    device.  Built on the host, a partition otherwise lives on the
+    default device, and every sharded apply would move the other
+    devices' slabs across the interconnect again."""
+    sharding = NamedSharding(mesh, P(axis))
+    return jax.tree_util.tree_map(lambda a: jax.device_put(a, sharding),
+                                  tree)
+
+
 def _make_dist_op(dist: DistPJDS, mesh: Mesh, axis: str, mode: Mode,
                   backend: ops.Backend, halo: Halo, multi_rhs: bool):
     n_dev = dist.n_dev
@@ -849,11 +857,14 @@ def _make_dist_op(dist: DistPJDS, mesh: Mesh, axis: str, mode: Mode,
     )
     x_spec = P(axis, None) if multi_rhs else P(axis)
 
+    # check_vma=False: a pallas_call's out_shape carries no varying-axes
+    # annotation, so the kernel backend cannot run under the VMA check.
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(operand_specs, x_spec),
         out_specs=x_spec,
+        check_vma=False,
     )
     def _mv(d, x_blk):
         return dist_matvec_local(d, x_blk, axis=axis, mode=mode,

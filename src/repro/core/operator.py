@@ -484,7 +484,7 @@ def operator(
     ``SparseDevice``, or already an operator (returned unchanged).
     Conversion and caching ride :func:`kernels.ops.as_device`;
     ``format``/``convert_kwargs`` (b_r, diag_align, sigma, chunk_l,
-    dtype, index_dtype, x_tiles, tune, reorder) pass through — in
+    dtype, index_dtype, tune, reorder) pass through — in
     particular ``reorder="auto"`` runs the priced RCM preprocessing
     stage (``core.reorder.preprocess``): the permutation is recorded on
     the device operand and every apply transparently permutes in and
@@ -609,8 +609,8 @@ def dist_operator(
         if halo == "auto":
             halo = PM.choose_halo(m, mode=mode,
                                   value_bytes=m.loc_val.dtype.itemsize)
-        return DistOperator(m, mesh, axis=axis, mode=mode, backend=backend,
-                            halo=halo)
+        return DistOperator(D.place_on_mesh(m, mesh, axis), mesh, axis=axis,
+                            mode=mode, backend=backend, halo=halo)
     n_dev = mesh.shape[axis]
     if tune not in ("off", "auto", "force"):
         raise ValueError(f"tune must be 'off', 'auto' or 'force'; "
@@ -695,6 +695,8 @@ def dist_operator(
             np.concatenate([perm_host, tail]).astype(np.int32))
         pre_inv = jnp.asarray(
             np.concatenate([inv_host, tail]).astype(np.int32))
-    return DistOperator(dist, mesh, t_dist=t_dist, diag=jnp.asarray(dg),
+    dist, t_dist, diag = D.place_on_mesh((dist, t_dist, jnp.asarray(dg)),
+                                         mesh, axis)
+    return DistOperator(dist, mesh, t_dist=t_dist, diag=diag,
                         axis=axis, mode=mode, backend=backend, halo=halo,
                         pre_perm=pre_perm, pre_inv=pre_inv)

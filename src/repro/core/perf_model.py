@@ -8,8 +8,11 @@ Paper (Fermi GPU)                    ->  here (TPU v5e target)
 Eq. (1): worst-case code balance of the ELLPACK/pJDS kernel,
     B_W^DP = (6 + 4*alpha + 8/N_nzr_max) bytes/flop
 with alpha in [1/N_nzr, 1] the RHS cache-reuse parameter.  On TPU the
-pJDS kernel keeps the local RHS slice resident in VMEM, which *enforces*
-the alpha -> 1/N_nzr limit for the distributed blocks (DESIGN.md §2).
+RHS is gathered ahead of the kernels, in XLA (the TPU compiler has no
+in-kernel 1-D gather): every stored slot reads x once and the kernel
+streams the gathered copy, so the program prices its RHS with
+:func:`gathered_rhs_bytes` and :func:`spmvm_bytes` stays the paper's
+minimum (DESIGN.md §2).
 
 Eq. (2)-(4): device-vs-link time model.  The paper derives the range of
 N_nzr for which accelerator spMVM is worthwhile given the ratio
@@ -48,7 +51,9 @@ __all__ = [
     "n_nzr_lower_for_link_penalty",
     "spmvm_flops",
     "spmvm_bytes",
+    "gathered_rhs_bytes",
     "perm_traffic_bytes",
+    "SORTED_ROW_FORMATS",
     "CMRS_RIS_BYTES",
     "cmrs_reduce_seconds",
     "predicted_spmv_seconds",
@@ -68,7 +73,6 @@ class TPUSpec:
     peak_flops_f32: float    # FLOP/s per chip (f32 VPU-bound spMVM path)
     hbm_bw: float            # bytes/s per chip
     ici_bw: float            # bytes/s per link
-    vmem_bytes: int
     hbm_bytes: int
 
 
@@ -78,7 +82,6 @@ TPU_V5E = TPUSpec(
     peak_flops_f32=197e12 / 4,  # f32 through the MXU at quarter rate
     hbm_bw=819e9,
     ici_bw=50e9,
-    vmem_bytes=128 * 2 ** 20,
     hbm_bytes=16 * 2 ** 30,
 )
 
@@ -293,8 +296,7 @@ def spmvm_flops(nnz: int) -> int:
 
 def spmvm_bytes(stored_elements: int, n_rows: int, alpha: float,
                 n_nzr: float, value_bytes: int = 8,
-                index_bytes: int = 4, x_tiles: int = 1,
-                n_row_blocks: int = 1,
+                index_bytes: int = 4,
                 vec_bytes: int | None = None) -> float:
     """Minimum HBM traffic of one spMVM in a given format: matrix values +
     indices stream once; RHS traffic scales with alpha; LHS written once.
@@ -305,39 +307,39 @@ def spmvm_bytes(stored_elements: int, n_rows: int, alpha: float,
     4+4 to 2+2 before padding).  ``vec_bytes`` is the width of the
     RHS/LHS vectors, which do NOT compress with the matrix — a bf16
     build still reads f32 x and writes the f32 accumulator — and
-    defaults to at least f32 (``max(4, value_bytes)``).
-
-    ``x_tiles > 1`` prices the column-blocked-x kernel grid
-    (row block, x tile, chunk): the matrix stream is re-read once per x
-    tile, and the RHS — no longer resident — is re-read once per row
-    block (``n_row_blocks``) instead of once, replacing the alpha term.
-    The model makes the trade explicit: column blocking buys a bounded
-    VMEM footprint with strictly more HBM traffic, so dispatch only
-    reaches for it when x cannot be resident at all."""
+    defaults to at least f32 (``max(4, value_bytes)``)."""
     if vec_bytes is None:
         vec_bytes = max(4, value_bytes)
-    if x_tiles > 1:
-        rhs = n_row_blocks * n_rows * vec_bytes        # x re-read per block
-    else:
-        rhs = alpha * n_nzr * n_rows * vec_bytes       # resident: alpha term
     return (
-        x_tiles * stored_elements * (value_bytes + index_bytes)
-        + rhs
+        stored_elements * (value_bytes + index_bytes)
+        + alpha * n_nzr * n_rows * vec_bytes
         + 2 * n_rows * vec_bytes
     )
 
 
+def gathered_rhs_bytes(stored_elements: int, vec_bytes: int = 4) -> float:
+    """HBM traffic of the RHS as the program moves it: the XLA gather
+    ahead of every kernel (``kernels._backend.gather_rhs``) reads x once
+    per STORED slot, padding included (irregular), and writes the
+    gathered copy, which the kernel reads back beside the values: three
+    vector-width accesses per slot.  It replaces the ``alpha`` RHS term
+    of :func:`spmvm_bytes`; padding the kernel skips (ELLPACK-R's
+    per-tile early exit) is still gathered."""
+    return 3.0 * float(stored_elements) * vec_bytes
+
+
+# Formats whose kernel writes y in a sorted row order (pJDS globally,
+# SELL-C-sigma within sigma windows): y is unpermuted after the kernel.
+SORTED_ROW_FORMATS = ("pjds", "sell")
+
+
 def perm_traffic_bytes(n_rows: int, value_bytes: int = 4,
-                       index_bytes: int = 4,
-                       window_local: bool = False) -> float:
+                       index_bytes: int = 4) -> float:
     """Extra HBM traffic of undoing a row sort OUTSIDE the kernel: the
-    permutation index stream plus a read+write pass over y.  A
-    window-local (SELL-C-sigma) unpermute is fused into the kernel while
-    y is still VMEM-resident, so it costs no HBM traffic at all — the
-    structural advantage dispatch weighs against pJDS's (slightly)
-    smaller padding (DESIGN.md §5)."""
-    if window_local:
-        return 0.0
+    permutation index stream plus a read+write pass over y.  Both sorted
+    formats (:data:`SORTED_ROW_FORMATS`) pay it: the TPU compiler has no
+    in-kernel gather, so even SELL-C-sigma's window-local unpermute runs
+    in XLA after the kernel (DESIGN.md §5)."""
     return float(n_rows) * (2 * value_bytes + index_bytes)
 
 
@@ -363,30 +365,29 @@ def predicted_spmv_seconds(stored_elements: int, n_rows: int, n_nzr: float,
                            spec: TPUSpec = TPU_V5E,
                            value_bytes: int = 4,
                            index_bytes: int = 4,
-                           x_tiles: int = 1,
-                           n_row_blocks: int = 1,
                            vec_bytes: int | None = None,
                            fmt: str | None = None,
                            calibration="default") -> float:
     """Memory-bound time estimate of one spMVM in a candidate format —
-    the quantity ``kernels.ops.select_format`` minimises.  Uses the
-    enforced alpha -> 1/N_nzr limit (VMEM-resident RHS, DESIGN.md §2);
+    the quantity ``kernels.ops.select_format`` minimises.  The RHS is
+    priced as the program moves it (:func:`gathered_rhs_bytes`: read
+    per stored slot, written and read back as the gathered stream);
     ``irregular_factor`` derates formats without a blocked kernel (CSR's
     scalar gather stream cannot saturate HBM).  ``value_bytes`` /
     ``index_bytes`` are the STORED stream widths, ``vec_bytes`` the
-    uncompressed RHS/LHS width, and ``x_tiles`` / ``n_row_blocks``
-    price the column-blocked-x grid — see :func:`spmvm_bytes`.
+    uncompressed RHS/LHS width — see :func:`spmvm_bytes`.
 
     ``calibration`` applies a measured :class:`Calibration` — effective
     bandwidth scale plus the per-format overhead looked up by ``fmt`` —
     on top of the structural byte model; the default picks up whatever
     :func:`set_calibration` installed (``None`` forces the uncalibrated
     data-sheet estimate)."""
-    n_nzr = max(n_nzr, 1e-9)
-    alpha = 1.0 / n_nzr
-    b = spmvm_bytes(stored_elements, n_rows, alpha, n_nzr,
-                    value_bytes, index_bytes, x_tiles, n_row_blocks,
-                    vec_bytes)
+    if vec_bytes is None:
+        vec_bytes = max(4, value_bytes)
+    # alpha = 0: the gathered stream below carries every RHS read
+    b = (spmvm_bytes(stored_elements, n_rows, 0.0, n_nzr,
+                     value_bytes, index_bytes, vec_bytes)
+         + gathered_rhs_bytes(stored_elements, vec_bytes))
     t = (b * irregular_factor + perm_bytes) / spec.hbm_bw
     if calibration == "default":
         calibration = _CALIBRATION
@@ -428,9 +429,7 @@ def solver_iteration_bytes(stored_elements: int, n_rows: int, n_nzr: float,
                            *, method: str = "cg",
                            strategy: str = "composed",
                            value_bytes: int = 4, index_bytes: int = 4,
-                           vec_bytes: int = 4, n_vec: int = 1,
-                           x_tiles: int = 1,
-                           n_row_blocks: int = 1) -> float:
+                           vec_bytes: int = 4, n_vec: int = 1) -> float:
     """Minimum HBM traffic of ONE solver iteration: the method's spMV
     streams plus the carrier-vector passes around them.
 
@@ -443,10 +442,9 @@ def solver_iteration_bytes(stored_elements: int, n_rows: int, n_nzr: float,
     """
     spmv_count = SOLVER_SPMV_COUNT[method]
     passes = SOLVER_VECTOR_PASSES[method][strategy]
-    alpha = 1.0 / max(n_nzr, 1e-9)
-    spmv = spmvm_bytes(stored_elements, n_rows, alpha, n_nzr,
-                       value_bytes, index_bytes, x_tiles, n_row_blocks,
-                       vec_bytes)
+    spmv = (spmvm_bytes(stored_elements, n_rows, 0.0, n_nzr,
+                        value_bytes, index_bytes, vec_bytes)
+            + gathered_rhs_bytes(stored_elements, vec_bytes))
     return spmv_count * spmv + passes * n_vec * float(n_rows) * vec_bytes
 
 
@@ -456,7 +454,6 @@ def predicted_iteration_seconds(stored_elements: int, n_rows: int,
                                 spec: TPUSpec = TPU_V5E,
                                 value_bytes: int = 4, index_bytes: int = 4,
                                 vec_bytes: int = 4, n_vec: int = 1,
-                                x_tiles: int = 1, n_row_blocks: int = 1,
                                 fmt: str | None = None,
                                 calibration="default") -> float:
     """Memory-bound time of one solver iteration — the quantity
@@ -467,8 +464,7 @@ def predicted_iteration_seconds(stored_elements: int, n_rows: int,
     b = solver_iteration_bytes(
         stored_elements, n_rows, n_nzr, method=method, strategy=strategy,
         value_bytes=value_bytes, index_bytes=index_bytes,
-        vec_bytes=vec_bytes, n_vec=n_vec, x_tiles=x_tiles,
-        n_row_blocks=n_row_blocks)
+        vec_bytes=vec_bytes, n_vec=n_vec)
     t = b / spec.hbm_bw
     if calibration == "default":
         calibration = _CALIBRATION

@@ -169,8 +169,8 @@ def _best_format_seconds(rl: np.ndarray, n: int, nnz: int, *,
         ib = index_bytes + (PM.CMRS_RIS_BYTES if fmt == "cmrs" else 0)
         t = PM.predicted_spmv_seconds(
             -(-elems // n_dev), n_loc, max(nnz / max(n, 1), 1.0),
-            perm_bytes=PM.perm_traffic_bytes(
-                n_loc, vec_bytes, window_local=(fmt != "pjds")),
+            perm_bytes=(PM.perm_traffic_bytes(n_loc, vec_bytes)
+                        if fmt in PM.SORTED_ROW_FORMATS else 0.0),
             spec=spec, value_bytes=value_bytes, index_bytes=ib,
             vec_bytes=vec_bytes, fmt=fmt, calibration=calibration)
         if fmt == "cmrs":

@@ -32,8 +32,7 @@ Two iteration strategies share each method's math:
 * the FUSED bodies (``fused_cg``/``fused_bicgstab``) take a
   ``matvec_dots(v, w1, w2)`` closure (``kernels.fused_iter``) returning
   ``(Av, <Av,w1>, <Av,w2>, <Av,Av>, <w2,w2>, <w1,w2>)`` — the dots
-  reduced in
-  the spMV kernel's epilogue while y is still VMEM-resident — and carry
+  reduced beside the spMV in one jitted step — and carry
   every remaining scalar (BiCGStab's rho, the exit test's look-ahead
   norm) by algebraic recurrence, so the loop body contains NO
   standalone vector reduction.  Carriers live at the operand's padded
@@ -65,6 +64,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.tree_util import Partial
 
 __all__ = ["SolveResult", "STATUS_NAMES", "cg", "bicgstab", "block_cg",
            "fused_cg", "fused_bicgstab", "iterative_refinement",
@@ -152,33 +152,34 @@ def _result(method: str, x, iters, residual, tol: float, *,
                        diagnostics=dict(diagnostics or {}))
 
 
+def _apply_operator(op, x: jax.Array) -> jax.Array:
+    return op.matvec(x) if x.ndim == 1 else op.matmat(x)
+
+
+def _as_partial(f) -> Partial:
+    """A callable as a jit ARGUMENT: a ``Partial``'s function is its
+    static part (hashed by identity, so one function means one compile)
+    and its bound arguments are traced leaves."""
+    return f if isinstance(f, Partial) else Partial(f)
+
+
 def _matvec_of(a) -> MatVec:
     """Normalize ``SparseOperator | MatVec`` to one apply callable.
 
     Operators dispatch 1-D carriers to ``matvec`` and 2-D blocks to
     ``matmat`` (the distributed operator shards the two differently);
-    bare closures pass through untouched — the pre-protocol call sites
-    keep working as shims.
+    bare closures pass through — the pre-protocol call sites keep
+    working as shims.
+
+    The result is a ``Partial`` that the jitted solvers take as an
+    argument: an operator's arrays enter the program as inputs.  Closed
+    over instead, they would be baked into every compiled solver as
+    constants — at published matrix sizes, hundreds of MB of executable
+    per program — and every operator would compile its own programs.
     """
-    mv = getattr(a, "matvec", None)
-    if mv is None:
-        return a
-    # One closure PER OPERATOR, cached on the instance: the closure is
-    # the jitted solvers' static cache key, so a fresh one per call
-    # would retrace + recompile every solve.
-    cached = getattr(a, "_solver_apply", None)
-    if cached is not None:
-        return cached
-    mm = getattr(a, "matmat", None)
-
-    def apply(x: jax.Array) -> jax.Array:
-        return mv(x) if x.ndim == 1 else mm(x)
-
-    try:
-        a._solver_apply = apply
-    except (AttributeError, TypeError):
-        pass
-    return apply
+    if getattr(a, "matvec", None) is None:
+        return _as_partial(a)
+    return Partial(_apply_operator, a)
 
 
 def jacobi(a) -> MatVec:
@@ -191,15 +192,11 @@ def jacobi(a) -> MatVec:
             "jacobi needs a SparseOperator with .diagonal(); got "
             f"{type(a).__name__} — pass M as an explicit callable instead")
     cached = getattr(a, "_jacobi_precond", None)
-    if cached is not None:       # stable closure == stable jit cache key
+    if cached is not None:
         return cached
     diag = d()
     inv = jnp.where(diag != 0, 1.0 / jnp.where(diag != 0, diag, 1), 1.0)
-    inv = inv.astype(diag.dtype)
-
-    def precond(r: jax.Array) -> jax.Array:
-        return r * (inv if r.ndim == 1 else inv[:, None])
-
+    precond = Partial(_scale_rows, inv.astype(diag.dtype))
     try:
         a._jacobi_precond = precond
     except (AttributeError, TypeError):
@@ -207,10 +204,16 @@ def jacobi(a) -> MatVec:
     return precond
 
 
+def _scale_rows(inv: jax.Array, r: jax.Array) -> jax.Array:
+    return r * (inv if r.ndim == 1 else inv[:, None])
+
+
 def _identity(r: jax.Array) -> jax.Array:
-    """Module-level no-op preconditioner: a STABLE static jit key (a
-    fresh lambda per call would recompile the solver every time)."""
     return r
+
+
+# The no-op preconditioner: one stable object, so one compile.
+_IDENTITY = Partial(_identity)
 
 
 def _not_done(res2, tol):
@@ -279,7 +282,7 @@ def _precond_of(M, a) -> MatVec | None:
     if M == "jacobi":
         return jacobi(a)
     if callable(M):
-        return M
+        return _as_partial(M)
     raise TypeError(f"M must be None, 'jacobi' or a callable; got {M!r}")
 
 
@@ -314,7 +317,7 @@ def _health_init(rel2, tol):
     return flag, jnp.asarray(best, jnp.asarray(rel2).dtype), jnp.int32(0)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3))
+@functools.partial(jax.jit, static_argnums=(3,))
 def _cg(matvec: MatVec, b: jax.Array, x0: jax.Array,
         maxiter: int = 500, tol: float = 1e-6):
     x = x0
@@ -351,7 +354,7 @@ def _cg(matvec: MatVec, b: jax.Array, x0: jax.Array,
     return x, k, jnp.sqrt(rs / b2), flag
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 4))
+@functools.partial(jax.jit, static_argnums=(4,))
 def _pcg(matvec: MatVec, precond: MatVec, b: jax.Array, x0: jax.Array,
          maxiter: int = 500, tol: float = 1e-6):
     """Preconditioned CG: same recurrence with z = M r directions."""
@@ -401,14 +404,14 @@ def bicgstab(a: Operator, b: jax.Array, *, x0: jax.Array | None = None,
     :func:`cg` (right preconditioning: A M z-directions).
     """
     matvec = _matvec_of(a)
-    pre = _precond_of(M, a) or _identity
+    pre = _precond_of(M, a) or _IDENTITY
     x0 = jnp.zeros_like(b) if x0 is None else x0
     x, k, res, flag = _bicgstab(matvec, pre, b, x0, maxiter, tol)
     return _result("bicgstab", x, k, res, tol, flag=flag,
                    strategy="composed")
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 4))
+@functools.partial(jax.jit, static_argnums=(4,))
 def _bicgstab(matvec: MatVec, precond: MatVec, b: jax.Array, x0: jax.Array,
               maxiter: int = 1000, tol: float = 1e-6):
     dt = b.dtype
@@ -473,11 +476,10 @@ def fused_cg(matvec_dots: MatVecDots, b: jax.Array, *,
              tol: float = 1e-6) -> SolveResult:
     """CG whose loop body is ONE fused spMV+dots pass and three axpys.
 
-    ``matvec_dots`` is the closure ``kernels.fused_iter.make_matvec_dots``
-    builds over a SELL operand (build it once — it is the static jit
-    key).  Each pass ``matvec_dots(p, p, r)`` returns Ap together with
-    <Ap,p>, <Ap,r>, <Ap,Ap> and the EXACT <r,r> (the epilogue's free
-    self-dot of the w2 slab), so alpha and beta use an exact residual
+    ``matvec_dots`` is the callable ``kernels.fused_iter.make_matvec_dots``
+    builds over a SELL operand.  Each pass ``matvec_dots(p, p, r)`` returns Ap together with
+    <Ap,p>, <Ap,r>, <Ap,Ap> and the EXACT <r,r> (the free self-dot
+    of w2), so alpha and beta use an exact residual
     norm every iteration; only the exit test's one-step look-ahead
 
         <r',r'> = <r,r> - 2 alpha <Ap,r> + alpha^2 <Ap,Ap>
@@ -488,7 +490,7 @@ def fused_cg(matvec_dots: MatVecDots, b: jax.Array, *,
     residual/converged are always honest.  Carriers live at the
     operand's padded length (pad rows stay exactly zero through every
     recurrence); ``x0`` is donated to the solve.  Unpreconditioned (the
-    fused epilogue reduces plain dots; ``repro.solve`` falls back to the
+    fused step reduces plain dots; ``repro.solve`` falls back to the
     composed body when a preconditioner is requested).
     """
     return _fused_drive(_fused_cg, "cg", matvec_dots, b, x0, maxiter, tol)
@@ -533,6 +535,7 @@ def _fused_drive(loop_fn, method: str, matvec_dots: MatVecDots,
     with the evidence in ``diagnostics`` — never returned as converged.
     """
     x = jnp.zeros_like(b) if x0 is None else x0
+    matvec_dots = _as_partial(matvec_dots)
     total, restarts = 0, 0
     rn_prev = float("inf")
     flag, demoted = 0, False
@@ -565,13 +568,13 @@ def _fused_drive(loop_fn, method: str, matvec_dots: MatVecDots,
                    strategy="fused", restarts=restarts)
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
+@jax.jit
 def _true_residual(matvec_dots: MatVecDots, b: jax.Array, x: jax.Array):
     r = b - matvec_dots(x, x, x)[0]
     return jnp.sqrt(jnp.vdot(r, r) / jnp.maximum(jnp.vdot(b, b), 1e-30))
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+@functools.partial(jax.jit, donate_argnums=(2,))
 def _fused_cg(matvec_dots: MatVecDots, b: jax.Array, x0: jax.Array,
               maxiter, tol):
     r = b - matvec_dots(x0, x0, b)[0]
@@ -603,7 +606,7 @@ def _fused_cg(matvec_dots: MatVecDots, b: jax.Array, x0: jax.Array,
     return x, k, jnp.sqrt(rs / b2), flag
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+@functools.partial(jax.jit, donate_argnums=(2,))
 def _fused_bicgstab(matvec_dots: MatVecDots, b: jax.Array, x0: jax.Array,
                     maxiter, tol):
     dt = b.dtype
@@ -721,7 +724,7 @@ def lanczos(a: Operator, v0: jax.Array, m: int = 50):
     return _lanczos(_matvec_of(a), v0, m)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2))
+@functools.partial(jax.jit, static_argnums=(2,))
 def _lanczos(matvec: MatVec, v0: jax.Array, m: int = 50):
     v = v0 / jnp.linalg.norm(v0)
 
@@ -774,7 +777,7 @@ def block_cg(a: Operator, b: jax.Array, *, x0: jax.Array | None = None,
                    strategy="composed")
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3))
+@functools.partial(jax.jit, static_argnums=(3,))
 def _block_cg(matvec: MatVec, b: jax.Array, x0: jax.Array,
               maxiter: int = 500, tol: float = 1e-6):
     x = x0
@@ -845,7 +848,7 @@ def block_lanczos(a: Operator, v0: jax.Array, m: int = 25):
     return _block_lanczos(_matvec_of(a), v0, m)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2))
+@functools.partial(jax.jit, static_argnums=(2,))
 def _block_lanczos(matvec: MatVec, v0: jax.Array, m: int = 25):
     v, _ = _chol_qr(v0)
     k = v.shape[1]
@@ -896,7 +899,7 @@ def power_iteration(a: Operator, v0: jax.Array, iters: int = 100):
     return _power_iteration(_matvec_of(a), v0, iters)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2))
+@functools.partial(jax.jit, static_argnums=(2,))
 def _power_iteration(matvec: MatVec, v0: jax.Array, iters: int = 100):
     def body(v, _):
         w = matvec(v)

@@ -4,14 +4,47 @@ Lives below ``kernels.ops`` (which imports the kernel modules) so the
 kernels themselves can resolve defaults without a circular import;
 ``ops.resolve_backend`` / ``ops.resolve_interpret`` re-export these as
 the public spellings.
+
+Two rules of the TPU compiler shape every kernel here (DESIGN.md §2b):
+
+* **No gather from a large VMEM array.**  Mosaic lowers a gather only
+  between 2-D arrays of one shape (a shuffle inside a tile), so
+  ``x[col_idx]`` cannot run inside a kernel.  :func:`gather_rhs` runs it
+  ahead of the kernel in XLA instead, and the kernels stream the
+  gathered operand ``xg`` tile for tile beside ``val``.  That costs one
+  write and one read of ``xg`` (the RHS width per stored slot) on top of
+  the value and index streams.
+* **Blocks are multiples of (8, 128) or whole arrays.**  One row block's
+  output is a single ``(1, b_r)`` row, so the blocked kernels write
+  groups of :data:`OUT_BLOCKS` row blocks: the grid walks every chunk of
+  a group while the ``(OUT_BLOCKS, b_r)`` output block stays pinned in
+  VMEM, and each chunk adds its row sum into its block's sublane
+  (``slot``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["resolve_interpret", "acc_dtype", "chunk_clamp", "tile_contrib",
-           "pad_x_to_tiles"]
+__all__ = ["resolve_interpret", "acc_dtype", "chunk_clamp", "gather_rhs",
+           "OUT_BLOCKS", "VMEM_LIMIT_BYTES", "compiler_params",
+           "group_max_chunks", "grouped_matvec_call", "row_sum"]
+
+# Row blocks per kernel output block: the sublane height of one f32 tile.
+OUT_BLOCKS = 8
+
+# Scoped VMEM the kernels may use.  A step holds a few (chunk_l, b_r)
+# tiles and one output block, double-buffered: well under a MiB for
+# every shape the tuner emits, so the limit only has to stay above
+# v5e's 16 MiB default with room for the spMM tiles.
+VMEM_LIMIT_BYTES = 32 * 2 ** 20
+
+
+def compiler_params() -> pltpu.CompilerParams:
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -46,28 +79,115 @@ def chunk_clamp(c, cnt):
     return jnp.minimum(c, jnp.maximum(cnt - 1, 0))
 
 
-def tile_contrib(val, idx, x, t, x_t, x_tiles, dt):
-    """Per-entry contribution ``val * x[idx]`` of one (chunk_l, b_r) tile
-    against the resident x tile ``t`` — the shared body of the blocked
-    spMV kernels.  With one tile (resident x) it is a plain gather; with
-    a column-blocked x the gather is masked to the tile's column range
-    (entries outside contribute 0 this sweep and are picked up by their
-    own tile)."""
-    if x_tiles == 1:
-        return val.astype(dt) * x[idx].astype(dt)
-    lo = t * x_t
-    loc = jnp.clip(idx - lo, 0, x_t - 1)
-    hit = (idx >= lo) & (idx < lo + x_t)
-    return jnp.where(hit, val.astype(dt) * x[loc].astype(dt), 0)
+def gather_rhs(col_idx: jax.Array, x: jax.Array) -> jax.Array:
+    """``x[col_idx]`` in XLA, ahead of the kernel: the RHS value of every
+    stored slot, shaped like ``col_idx``.  A multi-RHS block ``x`` of
+    shape (n_cols, k) gathers into ``(k,) + col_idx.shape``, RHS columns
+    leading, so each column keeps the matrix's tile layout.  Padding
+    slots hold ``formats.PAD_COL`` (column 0), so they gather x[0] and
+    meet a zero value in the kernel."""
+    idx = col_idx.astype(jnp.int32)
+    return x[idx] if x.ndim == 1 else x.T[:, idx]
 
 
-def pad_x_to_tiles(x: jax.Array, x_tiles: int):
-    """Zero-pad a 1-D RHS to a multiple of ``x_tiles`` (kernel tiling
-    needs equal tiles; stored column indices never reach the pad, and a
-    padded lane's gather is masked or multiplied by a zero value).
-    Returns (padded x, tile length)."""
-    n = x.shape[0]
-    rem = n % x_tiles
-    if rem:
-        x = jnp.pad(x, (0, x_tiles - rem))
-    return x, x.shape[0] // x_tiles
+def group_max_chunks(chunk_map) -> int:
+    """Static chunk ceiling of any output group (``OUT_BLOCKS`` row blocks
+    sharing one output block): the chunk-axis extent of the grouped
+    kernel grid.  ``chunk_map`` is the host-side non-decreasing block id
+    per chunk."""
+    cm = np.asarray(chunk_map)
+    if cm.size == 0:
+        return 1
+    return int(np.bincount(cm // OUT_BLOCKS).max())
+
+
+def _group_extents(chunk_map: jax.Array, n_groups: int):
+    """Per-group (first chunk, chunk count) plus each chunk's sublane slot
+    in its group's output block — the scalar-prefetch operands.
+    ``chunk_map`` must be non-decreasing (distributed operands pad with
+    the LAST block id, which keeps it so)."""
+    gmap = chunk_map // OUT_BLOCKS
+    start = jnp.searchsorted(gmap, jnp.arange(n_groups, dtype=gmap.dtype),
+                             side="left").astype(jnp.int32)
+    cnt = jnp.diff(jnp.append(start, jnp.int32(chunk_map.shape[0])))
+    slot = (chunk_map - gmap * OUT_BLOCKS).astype(jnp.int32)
+    return start, cnt, slot
+
+
+def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
+                        chunk_l: int, max_chunks: int | None, dt,
+                        interpret: bool | None, name: str) -> jax.Array:
+    """The grouped Pallas grid shared by the pJDS/SELL, CMRS and pJDS
+    multi-RHS kernels.
+
+    ``streams`` are ``(total, b_r)`` arrays tiled ``(chunk_l, b_r)`` and
+    walked chunk by chunk (``val``, the gathered RHS ``xg``, and for
+    CMRS the row routing); a multi-RHS gathered stream is
+    ``(n_rhs, total, b_r)`` and rides whole along its leading axis.
+    ``reduce_rows(*tiles)`` turns one chunk's tiles into its block's
+    ``(1, b_r)`` partial row (``(n_rhs, 1, b_r)`` for multi-RHS).  Grid
+    ``(group, chunk)``: chunks of one group are contiguous (the chunk map
+    is non-decreasing), so the scalar-prefetched extents drive the
+    stream index maps and steps past a group's extent clamp to its last
+    tile and skip compute.  ``max_chunks`` is the static group ceiling
+    (:func:`group_max_chunks`); None falls back to the total chunk count.
+    Returns y: ``([n_rhs,] n_blocks * b_r)`` in storage row order, dtype
+    ``dt``.
+    """
+    total, b_r = streams[0].shape
+    if total % chunk_l:
+        raise ValueError(f"stream length {total} not a multiple of "
+                         f"chunk_l={chunk_l}")
+    lead = streams[-1].shape[:-2]          # (n_rhs,) or ()
+    n_chunks = total // chunk_l
+    n_groups = max(-(-n_blocks // OUT_BLOCKS), 1)
+    start, cnt, slot = _group_extents(chunk_map, n_groups)
+
+    def kernel(start_ref, cnt_ref, slot_ref, *refs):
+        *in_refs, y_ref = refs
+        g = pl.program_id(0)
+        c = pl.program_id(1)
+
+        @pl.when(c == 0)
+        def _init():
+            y_ref[...] = jnp.zeros_like(y_ref)
+
+        @pl.when(c < cnt_ref[g])
+        def _body():
+            s = slot_ref[start_ref[g] + c]
+            y_ref[..., pl.ds(s, 1), :] += reduce_rows(
+                *(r[...] for r in in_refs))
+
+    def spec(a):
+        pre = (0,) * (a.ndim - 2)
+        return pl.BlockSpec(
+            a.shape[:-2] + (chunk_l, b_r),
+            lambda g, c, s, n, sl: pre + (s[g] + chunk_clamp(c, n[g]), 0))
+
+    pre = (0,) * len(lead)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n_groups, n_chunks if max_chunks is None else max_chunks),
+        in_specs=[spec(a) for a in streams],
+        out_specs=pl.BlockSpec(lead + (OUT_BLOCKS, b_r),
+                               lambda g, c, s, n, sl: pre + (g, 0)),
+    )
+    y = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(lead + (n_groups * OUT_BLOCKS, b_r),
+                                       dt),
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
+        name=name,
+    )(start, cnt, slot, *streams)
+    return y.reshape(lead + (-1,))[..., : n_blocks * b_r]
+
+
+def row_sum(dt):
+    """``reduce_rows`` of the pJDS/SELL kernel: every slot of lane r
+    belongs to row r of the block, so a chunk reduces over sublanes."""
+    def reduce_rows(val, xg):
+        return jnp.sum(val.astype(dt) * xg.astype(dt), axis=0, keepdims=True)
+    return reduce_rows
+

@@ -7,24 +7,19 @@ lane-major tiles with an int8 ``row_in_strip`` stream routing every
 slot back to its row.  Relative to pJDS this trades per-row padding for
 an in-kernel segment reduction:
 
-* The grid and scalar-prefetch machinery are pJDS's exactly —
-  ``(strip, x_tile, chunk)`` with per-strip (start, count) extents
-  driving the val/col/ris BlockSpec index maps
-  (``pjds_spmv.block_extents``); only the reduction differs.
+* The grid, the gathered RHS stream and the grouped output blocks are
+  pJDS's exactly (``_backend.grouped_matvec_call``); only the chunk
+  reduction differs.
 * A pJDS chunk reduces over sublanes (every slot of lane r belongs to
   row r).  A CMRS chunk's slots belong to ARBITRARY rows of the strip,
-  so the kernel flattens the chunk to ``(1, chunk_l * b_r)`` and
-  multiplies by a one-hot ``(chunk_l * b_r, b_r)`` routing matrix built
-  from ``row_in_strip`` — a segment-sum phrased as an MXU matmul,
-  costing ``2 * b_r`` flops per stored slot
+  so each sublane of the chunk is multiplied by a one-hot ``(b_r, b_r)``
+  routing matrix built from its ``row_in_strip`` row — a segment-sum
+  phrased as MXU matmuls, costing ``2 * b_r`` flops per stored slot
   (``perf_model.cmrs_reduce_seconds``; dispatch prices the kernel as
   ``max(memory_term, compute_term)``).
 * Padding slots carry val == 0 / col == PAD_COL / row_in_strip == 0:
   they gather x[0] and route a zero product into row 0 — harmless, no
   masking needed (the ``formats.PAD_COL`` contract).
-
-VMEM working set per step: 3 matrix tiles (val, col, int8 ris) + the
-x tile + the one-hot routing matrix + one (1, b_r) output block.
 """
 from __future__ import annotations
 
@@ -32,46 +27,35 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ._backend import (acc_dtype, chunk_clamp, pad_x_to_tiles,
-                       resolve_interpret, tile_contrib)
-from .pjds_spmv import block_extents
+from ._backend import acc_dtype, gather_rhs, grouped_matvec_call
 
 __all__ = ["cmrs_matvec_kernel_call"]
 
 
-def _cmrs_spmv_kernel(start_ref, cnt_ref, val_ref, col_ref, ris_ref, x_ref,
-                      y_ref, *, x_tiles, x_t):
-    s = pl.program_id(0)
-    t = pl.program_id(1)
-    c = pl.program_id(2)
-
-    # First visit of this strip's output block: zero it while VMEM-pinned.
-    @pl.when((t == 0) & (c == 0))
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    @pl.when(c < cnt_ref[s])
-    def _body():
-        idx = col_ref[...].astype(jnp.int32)     # (chunk_l, b_r); int16 ok
-        contrib = tile_contrib(val_ref[...], idx, x_ref[...], t, x_t,
-                               x_tiles, y_ref.dtype)
+def _routed_sum(dt):
+    """``reduce_rows`` of the CMRS kernel: route each slot's product to
+    lane ``row_in_strip`` of the output row.  The routing stream is
+    transposed once so that sublane r's routes form a column, and each
+    sublane contributes ``contrib[r] @ onehot_r`` with
+    ``onehot_r[l, j] = (route[r, l] == j)``."""
+    def reduce_rows(val, xg, ris):
+        contrib = val.astype(dt) * xg.astype(dt)          # (chunk_l, b_r)
         chunk_l, b_r = contrib.shape
-        flat = contrib.reshape(1, chunk_l * b_r)
-        ris = ris_ref[...].astype(jnp.int32).reshape(chunk_l * b_r, 1)
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (chunk_l * b_r, b_r), 1)
-        onehot = (ris == lanes).astype(y_ref.dtype)
-        y_ref[0, :] += jnp.dot(flat, onehot,
-                               preferred_element_type=y_ref.dtype)[0]
+        routes = ris.astype(jnp.int32).T                  # (b_r, chunk_l)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (b_r, b_r), 1)
+        part = jnp.zeros((1, b_r), dt)
+        for r in range(chunk_l):
+            onehot = (routes[:, r:r + 1] == lanes).astype(dt)
+            part = part + jnp.dot(contrib[r:r + 1, :], onehot,
+                                  preferred_element_type=dt,
+                                  precision=jax.lax.Precision.HIGHEST)
+        return part
+    return reduce_rows
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("n_strips", "chunk_l", "max_chunks", "x_tiles",
-                     "interpret"),
-)
+    jax.jit, static_argnames=("n_strips", "chunk_l", "max_chunks", "interpret"))
 def cmrs_matvec_kernel_call(
     val: jax.Array,
     col_idx: jax.Array,
@@ -82,7 +66,6 @@ def cmrs_matvec_kernel_call(
     n_strips: int,
     chunk_l: int = 8,
     max_chunks: int | None = None,
-    x_tiles: int = 1,
     interpret: bool | None = None,
 ) -> jax.Array:
     """y = A_cmrs @ x in the ORIGINAL row order.
@@ -91,43 +74,18 @@ def cmrs_matvec_kernel_call(
                  == 0 (guaranteed when the format was built with
                  ``diag_align`` a multiple of ``chunk_l``; the
                  ``ops.to_device_cmrs`` wrapper checks).  col_idx int16
-                 or int32, row_in_strip int8 — both upcast in-kernel.
+                 or int32, row_in_strip int8.
     chunk_map:   (total_su // chunk_l,) non-decreasing int32 strip id
                  per chunk.
-    x:           (n_cols_pad,) RHS, original column order.
-    max_chunks:  static max chunks of any single strip (``CMRSDevice``
-                 carries it); None falls back to the total chunk count.
+    x:           (n_cols,) RHS, original column order.
+    max_chunks:  static chunk ceiling of any output group of strips
+                 (``CMRSDevice`` carries it); None falls back to the
+                 total chunk count.
     interpret:   None = compiled on TPU, interpret elsewhere.
     Returns y:   (n_strips * b_r,) in the accumulator dtype.
     """
-    total_su, b_r = val.shape
-    if total_su % chunk_l:
-        raise ValueError(
-            f"total_su={total_su} not a multiple of chunk_l={chunk_l}")
-    n_chunks = total_su // chunk_l
-    if max_chunks is None:
-        max_chunks = n_chunks
-    x, x_t = pad_x_to_tiles(x, x_tiles)
     dt = acc_dtype(val.dtype, x.dtype)
-    start, cnt = block_extents(chunk_map, n_strips)
-
-    mat_map = lambda b, t, c, s, n: (s[b] + chunk_clamp(c, n[b]), 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_strips, x_tiles, max_chunks),
-        in_specs=[
-            pl.BlockSpec((chunk_l, b_r), mat_map),                # val tile
-            pl.BlockSpec((chunk_l, b_r), mat_map),                # col tile
-            pl.BlockSpec((chunk_l, b_r), mat_map),                # ris tile
-            pl.BlockSpec((x_t,), lambda b, t, c, s, n: (t,)),     # x tile
-        ],
-        out_specs=pl.BlockSpec((1, b_r), lambda b, t, c, s, n: (b, 0)),
-    )
-    y_blk = pl.pallas_call(
-        functools.partial(_cmrs_spmv_kernel, x_tiles=x_tiles, x_t=x_t),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_strips, b_r), dt),
-        interpret=resolve_interpret(interpret),
-        name="cmrs_spmv",
-    )(start, cnt, val, col_idx, row_in_strip, x)
-    return y_blk.reshape(n_strips * b_r)
+    return grouped_matvec_call(
+        _routed_sum(dt), (val, gather_rhs(col_idx, x), row_in_strip),
+        chunk_map, n_blocks=n_strips, chunk_l=chunk_l,
+        max_chunks=max_chunks, dt=dt, interpret=interpret, name="cmrs_spmv")

@@ -1,237 +1,53 @@
-"""Fused Krylov-iteration kernel: SELL-C-sigma spMV + partial dot
-reductions in ONE pass over the stored tiles.
+"""The fused Krylov iteration's operator step: SELL-C-sigma spMV plus the
+iteration's dot products, as one call per iteration.
 
 The paper's roofline (§4) prices an spMVM-bound solver entirely by HBM
 traffic per iteration, and the SELL-C-sigma follow-up (arXiv:1307.6209)
-points at amortising that traffic across the whole iteration as the way
-past it.  A composed CG/BiCGStab step leaks traffic around the spMV
-kernel: the dot products (<p,Ap>, <r,r>, ...) re-read y = Ax and the
-carrier vectors from HBM as separate HLO reductions.  This kernel rides
-``sell_spmv.py``'s PrefetchScalarGridSpec grid unchanged — scalar-
-prefetched window extents, VMEM-pinned output slab, window-local
-unpermute fused as the slab epilogue — and extends the epilogue: while
-the finished slab is STILL VMEM-resident (already back in original row
-order), it reduces the three lane-partial dot products
+points at amortising that traffic across the whole iteration.  One call
+returns
 
-    d1 = <y, w1>   d2 = <y, w2>   dy = <y, y>   dw = <w2, w2>
-    dz = <w1, w2>
+    y = A x,  d1 = <y, w1>   d2 = <y, w2>   dy = <y, y>
+              dw = <w2, w2>  dz = <w1, w2>
 
-against two weight slabs that ride the same (w, 0) BlockSpec as the
-inverse permutation.  The partials leave the kernel as one (n_win, b_r)
-row per window — b_r lanes instead of n_rows elements — and a tiny jnp
-``sum`` outside finishes the scalars.  y itself is written to HBM once,
-exactly as before; the dots cost no extra pass over y or the carriers.
+so a fully-recurrent CG/BiCGStab body (``core.solvers.fused_cg`` /
+``fused_bicgstab``) needs NO other per-iteration vector reduction: every
+alpha/beta/omega/residual-norm scalar follows algebraically from these
+dots.  The solvers route their residual-type carrier through ``w2``, so
+every iteration gets an EXACT ||r||^2 (or ||s||^2) — the scalar that,
+carried purely by recurrence, cancels catastrophically once convergence
+is fast — and BiCGStab reads the exact <rhat, s> from ``dz``.
 
-``dw`` and ``dz`` never touch y at all: the self-dot of the second
-weight slab and the cross-dot of the two weight slabs, reduced while
-both are resident anyway.  The solvers always route their residual-type
-carrier through ``w2``, so every iteration gets an EXACT ||r||^2 (or
-||s||^2) for free — the scalar that, carried purely by recurrence,
-cancels catastrophically once convergence is fast (the classic
-pipelined-CG drift) — and BiCGStab reads the EXACT <rhat, s> from
-``dz`` instead of assuming it zero (the assumption whose f32 drift
-stalls the pipelined rho recurrence).  Only the single-step look-ahead
-used for the loop's exit test remains a recurrence.
-
-With the right (w1, w2) choice per call, a fully-recurrent CG/BiCGStab
-body (``core.solvers.fused_cg`` / ``fused_bicgstab``) needs NO other
-per-iteration vector reduction: every alpha/beta/omega/residual-norm
-scalar follows algebraically from these four dots.
-
-Restrictions (checked): resident RHS only (``x_tiles == 1`` — the
-column-blocked grid would visit the slab once per x tile and the
-epilogue runs once), square operands, 1-D carriers.  ``dw`` is reduced
-in the epilogue, so a sigma-window with NO stored chunks contributes
-nothing to it — exact whenever every row window stores at least one
-chunk (any operand with nonzero diagonals qualifies; the dispatcher's
-ref path has no such caveat).
-
-Off-TPU the dispatcher (:func:`fused_matvec_dots`) uses the jnp ref
-path — ``sell_matvec_ref`` plus the five dots — which XLA fuses inside
-the solver's ``while_loop``; the kernel path compiles on TPU.
+On the kernel backend y comes from the SELL kernel (``ops.sell_matvec``:
+the RHS gather and the window unpermute run in XLA around the Pallas
+pass, see ``kernels._backend``) and the five dots are XLA reductions
+over the unpermuted y and the carriers, which XLA fuses inside the
+solver's ``while_loop``.  The dots cannot ride the kernel's output
+epilogue: the kernel's y is in window-sorted row order and the carriers
+are not, and the TPU compiler has no in-kernel gather to bring them
+together.
 """
 from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.tree_util import Partial
 
-from . import ref as R
-from ._backend import acc_dtype, chunk_clamp, resolve_interpret
-from .pjds_spmv import block_extents
-from .sell_spmv import window_blocks
+from . import ops
 
-__all__ = ["fused_spmv_dots_kernel_call", "fused_matvec_dots",
-           "make_matvec_dots"]
+__all__ = ["fused_matvec_dots", "make_matvec_dots"]
 
 
-def _fused_iter_kernel(wstart_ref, wcnt_ref, slot_ref, val_ref, col_ref,
-                       x_ref, w1_ref, w2_ref, inv_ref,
-                       y_ref, d1_ref, d2_ref, dy_ref, dw_ref, dz_ref):
-    w = pl.program_id(0)
-    c = pl.program_id(1)
-
-    # First visit of this window: zero the slab AND its dot partials (a
-    # window with no stored chunks never reaches the epilogue, so its
-    # contribution to every dot must already be zero).
-    @pl.when(c == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-        d1_ref[...] = jnp.zeros_like(d1_ref)
-        d2_ref[...] = jnp.zeros_like(d2_ref)
-        dy_ref[...] = jnp.zeros_like(dy_ref)
-        dw_ref[...] = jnp.zeros_like(dw_ref)
-        dz_ref[...] = jnp.zeros_like(dz_ref)
-
-    @pl.when(c < wcnt_ref[w])
-    def _body():
-        slot = slot_ref[wstart_ref[w] + c]       # row block within the slab
-        idx = col_ref[...].astype(jnp.int32)     # (chunk_l, b_r); int16 ok
-        contrib = val_ref[...].astype(y_ref.dtype) \
-            * x_ref[idx].astype(y_ref.dtype)
-        y_ref[slot, :] += jnp.sum(contrib, axis=0)
-
-    # Epilogue on the window's last chunk: unpermute in-slab (exactly as
-    # sell_spmv does), then reduce the dot partials against the weight
-    # slabs while everything is VMEM-resident — the permutation AND the
-    # reductions never touch HBM.
-    @pl.when(c == wcnt_ref[w] - 1)
-    def _epilogue():
-        ys = y_ref[...].reshape(-1)
-        yo = ys[inv_ref[...].reshape(-1)].reshape(y_ref.shape)
-        y_ref[...] = yo
-        w1s = w1_ref[...].astype(yo.dtype)
-        w2s = w2_ref[...].astype(yo.dtype)
-        d1_ref[0, :] = jnp.sum(yo * w1s, axis=0)
-        d2_ref[0, :] = jnp.sum(yo * w2s, axis=0)
-        dy_ref[0, :] = jnp.sum(yo * yo, axis=0)
-        dw_ref[0, :] = jnp.sum(w2s * w2s, axis=0)
-        dz_ref[0, :] = jnp.sum(w1s * w2s, axis=0)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_blocks", "chunk_l", "sigma", "max_win_chunks",
-                     "interpret"),
-)
-def fused_spmv_dots_kernel_call(
-    val: jax.Array,
-    col_idx: jax.Array,
-    chunk_map: jax.Array,
-    inv_perm: jax.Array,
-    x: jax.Array,
-    w1: jax.Array,
-    w2: jax.Array,
-    *,
-    n_blocks: int,
-    chunk_l: int = 8,
-    sigma: int = 0,
-    max_win_chunks: int | None = None,
-    interpret: bool | None = None,
-):
-    """(y, <y,w1>, <y,w2>, <y,y>, <w2,w2>, <w1,w2>) with y = A_sell @ x
-    in ORIGINAL row order.
-
-    Same operand contract as ``sell_matvec_kernel_call`` (resident-x
-    grid only); ``w1``/``w2`` are (n_blocks * b_r,) weight vectors in
-    the original basis, zero-padded past the real rows — padded rows
-    store zero values, so y is zero there and the y-dots are exact.
-    Returns y of shape (n_blocks * b_r,) plus five scalars, all in the
-    accumulator dtype.  The <w2,w2> and <w1,w2> partials reduce in the
-    epilogue, so they miss windows with zero stored chunks (see module
-    docstring).
-    """
-    total_jds, b_r = val.shape
-    if total_jds % chunk_l:
-        raise ValueError(
-            f"total_jds={total_jds} not a multiple of chunk_l={chunk_l}")
-    n_pad = n_blocks * b_r
-    for name, v in (("inv_perm", inv_perm), ("w1", w1), ("w2", w2)):
-        if v.shape != (n_pad,):
-            raise ValueError(f"{name} shape {v.shape} != ({n_pad},)")
-    n_chunks = total_jds // chunk_l
-    if max_win_chunks is None:
-        max_win_chunks = n_chunks
-    dt = acc_dtype(val.dtype, x.dtype)
-
-    w_b = window_blocks(sigma, b_r, n_blocks)
-    n_win = -(-n_blocks // w_b)
-    n_out = n_win * w_b * b_r
-    win_map = chunk_map // w_b
-    wstart, wcnt = block_extents(win_map, n_win)
-    slot = (chunk_map - win_map * w_b).astype(jnp.int32)
-    inv_pad = jnp.concatenate([
-        inv_perm.astype(jnp.int32),
-        jnp.arange(n_pad, n_out, dtype=jnp.int32)])
-    inv_local = (inv_pad - (jnp.arange(n_out, dtype=jnp.int32)
-                            // (w_b * b_r)) * (w_b * b_r))
-    inv_local = inv_local.reshape(n_win * w_b, b_r)
-
-    def _slab(v):
-        return jnp.pad(v, (0, n_out - n_pad)).reshape(n_win * w_b, b_r)
-
-    x_len = x.shape[0]
-    mat_map = lambda w, c, ws, wc, sl: (ws[w] + chunk_clamp(c, wc[w]), 0)
-    slab_map = lambda w, c, ws, wc, sl: (w, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_win, max_win_chunks),
-        in_specs=[
-            pl.BlockSpec((chunk_l, b_r), mat_map),                 # val
-            pl.BlockSpec((chunk_l, b_r), mat_map),                 # col
-            pl.BlockSpec((x_len,), lambda w, c, ws, wc, sl: (0,)),  # x
-            pl.BlockSpec((w_b, b_r), slab_map),                    # w1 slab
-            pl.BlockSpec((w_b, b_r), slab_map),                    # w2 slab
-            pl.BlockSpec((w_b, b_r), slab_map),                    # inv slab
-        ],
-        out_specs=[
-            pl.BlockSpec((w_b, b_r), slab_map),                    # y slab
-            pl.BlockSpec((1, b_r), slab_map),                      # d1
-            pl.BlockSpec((1, b_r), slab_map),                      # d2
-            pl.BlockSpec((1, b_r), slab_map),                      # dy
-            pl.BlockSpec((1, b_r), slab_map),                      # dw
-            pl.BlockSpec((1, b_r), slab_map),                      # dz
-        ],
-    )
-    y_blk, d1, d2, dy, dw, dz = pl.pallas_call(
-        _fused_iter_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_win * w_b, b_r), dt),
-            jax.ShapeDtypeStruct((n_win, b_r), dt),
-            jax.ShapeDtypeStruct((n_win, b_r), dt),
-            jax.ShapeDtypeStruct((n_win, b_r), dt),
-            jax.ShapeDtypeStruct((n_win, b_r), dt),
-            jax.ShapeDtypeStruct((n_win, b_r), dt),
-        ],
-        interpret=resolve_interpret(interpret),
-        name="fused_iter_spmv_dots",
-    )(wstart, wcnt, slot, val, col_idx, x, _slab(w1), _slab(w2), inv_local)
-    y = y_blk.reshape(n_out)[:n_pad]
-    return y, d1.sum(), d2.sum(), dy.sum(), dw.sum(), dz.sum()
-
-
-def fused_matvec_dots(a, x, w1, w2, *, backend: str = "ref",
-                      interpret: bool | None = None):
-    """Dispatching (y, <y,w1>, <y,w2>, <y,y>, <w2,w2>, <w1,w2>) over a
-    ``SELLDevice``.
+def fused_matvec_dots(a, x, w1, w2, *, backend: str = "ref"):
+    """(y, <y,w1>, <y,w2>, <y,y>, <w2,w2>, <w1,w2>) over a ``SELLDevice``,
+    y = A x in ORIGINAL row order.
 
     ``backend`` is the RESOLVED backend string ("kernel" on TPU, "ref"
-    elsewhere — callers go through ``ops.resolve_backend``); the ref
-    path is the same gather/segment-sum jnp program the plain sell
-    matvec uses, plus three dots XLA fuses into the solver loop.
-    Carriers live at the padded length ``a.n_rows_pad``.
+    elsewhere — callers go through ``ops.resolve_backend``).  Carriers
+    live at the padded length ``a.n_rows_pad``; padded rows store zero
+    values, so y is zero there and the y-dots are exact.
     """
-    if backend == "kernel":
-        return fused_spmv_dots_kernel_call(
-            a.val, a.col_idx, a.chunk_map, a.inv_perm, x, w1, w2,
-            n_blocks=a.n_blocks, chunk_l=a.chunk_l, sigma=a.sigma,
-            max_win_chunks=a.max_win_chunks, interpret=interpret)
-    y = R.sell_matvec_ref(a.val, a.col_idx, a.row_block, a.inv_perm, x,
-                          a.n_blocks)
+    y = ops.sell_matvec(a, x, backend)
     dt = y.dtype
     w1c = w1.astype(dt)
     w2c = w2.astype(dt)
@@ -239,10 +55,15 @@ def fused_matvec_dots(a, x, w1, w2, *, backend: str = "ref",
             jnp.vdot(y, y), jnp.vdot(w2c, w2c), jnp.vdot(w1c, w2c))
 
 
+# One function per backend: the static part of every fused solver's jit
+# key, so operands of one structure share their compiled programs.
+_MATVEC_DOTS = {b: functools.partial(fused_matvec_dots, backend=b)
+                for b in ("kernel", "ref")}
+
+
 def make_matvec_dots(a, *, backend: str = "ref"):
-    """A stable closure over one ``SELLDevice`` — the static jit key the
-    fused solvers (``core.solvers.fused_cg``/``fused_bicgstab``) hash on,
-    so build it once per operand and reuse it across solves."""
-    def matvec_dots(v, w1, w2):
-        return fused_matvec_dots(a, v, w1, w2, backend=backend)
-    return matvec_dots
+    """``(v, w1, w2) -> fused_matvec_dots(a, v, w1, w2)`` over one
+    ``SELLDevice``, as a ``Partial`` the fused solvers
+    (``core.solvers.fused_cg``/``fused_bicgstab``) take as a jit
+    argument: the operand's arrays are traced inputs, not constants."""
+    return Partial(_MATVEC_DOTS[backend], a)

@@ -37,11 +37,10 @@ import numpy as np
 from repro.core import formats as F
 from repro.core import perf_model as PM
 from . import ref as R
-from ._backend import resolve_interpret
+from ._backend import group_max_chunks, resolve_interpret
 from .pjds_spmv import pjds_matvec_kernel_call
 from .pjds_spmm import pjds_matmat_kernel_call
 from .ellr_spmv import ell_matvec_kernel_call
-from .sell_spmv import sell_matvec_kernel_call, window_blocks
 from .cmrs_spmv import cmrs_matvec_kernel_call
 
 __all__ = [
@@ -53,6 +52,7 @@ __all__ = [
     "SparseDevice",
     "to_device_pjds",
     "to_device_ell",
+    "ell_tile",
     "to_device_sell",
     "to_device_csr",
     "to_device_cmrs",
@@ -68,7 +68,6 @@ __all__ = [
     "clear_device_cache",
     "resolve_backend",
     "resolve_interpret",
-    "choose_x_tiles",
 ]
 
 Backend = Literal["auto", "kernel", "ref"]
@@ -106,9 +105,9 @@ class PJDSDevice:
     ``val`` carries the (possibly bf16-compressed) value stream and
     ``col_idx`` the (possibly int16-compressed) index stream exactly as
     built by ``formats.csr_to_pjds(index_dtype=...)``; ``max_chunks`` is
-    the static per-block chunk ceiling the prefetched kernel grid needs
-    (None falls back to the total chunk count — correct, more grid
-    steps)."""
+    the static chunk ceiling of any kernel output group
+    (``_backend.group_max_chunks``) that the prefetched grid needs (None
+    falls back to the total chunk count — correct, more grid steps)."""
 
     val: jax.Array                     # (total_jds, b_r)
     col_idx: jax.Array                 # (total_jds, b_r) int16/int32
@@ -140,7 +139,7 @@ class ELLDevice:
 @dataclasses.dataclass(frozen=True)
 class SELLDevice:
     """Device-resident SELL-C-sigma operand: pJDS chunk layout plus the
-    window-local inverse permutation the kernel fuses into its epilogue."""
+    window-local inverse permutation that takes y back to row order."""
 
     val: jax.Array                     # (total_jds, b_r)
     col_idx: jax.Array                 # (total_jds, b_r) int16/int32
@@ -151,10 +150,8 @@ class SELLDevice:
     b_r: int = dataclasses.field(metadata=dict(static=True))
     chunk_l: int = dataclasses.field(metadata=dict(static=True))
     sigma: int = dataclasses.field(metadata=dict(static=True))
-    max_win_chunks: Optional[int] = dataclasses.field(
-        default=None, metadata=dict(static=True))
     max_chunks: Optional[int] = dataclasses.field(
-        default=None, metadata=dict(static=True))   # per-BLOCK (spMM path)
+        default=None, metadata=dict(static=True))
 
     @property
     def n_rows_pad(self) -> int:
@@ -222,12 +219,27 @@ def to_device_pjds(p: F.PJDSMatrix, chunk_l: int = 8,
         n_blocks=p.n_blocks,
         b_r=p.b_r,
         chunk_l=chunk_l,
-        max_chunks=int(p.block_len.max(initial=chunk_l)) // chunk_l,
+        max_chunks=group_max_chunks(chunk_map),
     )
+
+
+# ELLPACK-R's row tile is a lane slice of one (max_nzr, n_pad) array, so
+# the TPU compiler takes it only in whole 128-lane vregs; the blocked
+# formats store each block as a whole (total, b_r) array and take any b_r.
+ELL_LANES = 128
+
+
+def ell_tile(b_r: int) -> int:
+    """The ELLPACK-R row tile built for a requested ``b_r``: rounded up
+    to whole :data:`ELL_LANES`."""
+    return -(-b_r // ELL_LANES) * ELL_LANES
 
 
 def to_device_ell(e: F.ELLMatrix, chunk_l: int = 8, tile_r: int = 128,
                   dtype=None) -> ELLDevice:
+    if tile_r % ELL_LANES:
+        raise ValueError(f"ELLPACK-R tile_r={tile_r} must be a multiple of "
+                         f"{ELL_LANES} lanes; use ell_tile(b_r)")
     if e.val.shape[0] % chunk_l or e.n_rows_pad % tile_r:
         raise ValueError("ELL shapes not aligned to (chunk_l, tile_r); "
                          "rebuild with matching row_align/diag_align")
@@ -254,11 +266,6 @@ def to_device_sell(s: F.SELLMatrix, chunk_l: int = 8,
         )
     row_block, chunk_map = _blocked_maps(p.block_len, chunk_l, p.n_blocks)
     val = p.val if dtype is None else p.val.astype(dtype)
-    # Static per-window chunk ceiling for the slab-output kernel grid.
-    w_b = window_blocks(s.sigma, p.b_r, p.n_blocks)
-    win_chunks = (np.add.reduceat(p.block_len // chunk_l,
-                                  np.arange(0, p.n_blocks, w_b))
-                  if p.n_blocks else np.array([1]))
     return SELLDevice(
         val=jnp.asarray(val),
         col_idx=jnp.asarray(p.col_idx),
@@ -269,8 +276,7 @@ def to_device_sell(s: F.SELLMatrix, chunk_l: int = 8,
         b_r=p.b_r,
         chunk_l=chunk_l,
         sigma=s.sigma,
-        max_win_chunks=int(win_chunks.max(initial=1)),
-        max_chunks=int(p.block_len.max(initial=chunk_l)) // chunk_l,
+        max_chunks=group_max_chunks(chunk_map),
     )
 
 
@@ -304,37 +310,17 @@ def to_device_cmrs(c: F.CMRSMatrix, chunk_l: int = 8,
         n_strips=c.n_strips,
         b_r=c.b_r,
         chunk_l=chunk_l,
-        max_chunks=int(c.strip_len.max(initial=chunk_l)) // chunk_l,
+        max_chunks=group_max_chunks(chunk_map),
     )
 
 
-def choose_x_tiles(n_cols_pad: int, itemsize: int,
-                   vmem_limit: Optional[int] = None) -> int:
-    """Column-tile count for the x-blocked kernels: the smallest power of
-    two whose x tile fits the VMEM allowance (a quarter of the chip's
-    VMEM by default — the matrix tiles, the output block and double
-    buffering need the rest).  Matrices whose RHS already fits return 1
-    (the resident fast path).  Callers fall back to 1 when the tile
-    count does not divide the runtime x length."""
-    if vmem_limit is None:
-        vmem_limit = PM.TPU_V5E.vmem_bytes // 4
-    t = 1
-    while n_cols_pad * itemsize > t * vmem_limit and t < 4096:
-        t *= 2
-    return t
-
-
 def pjds_matvec(a: PJDSDevice, x: jax.Array,
-                backend: Backend = "ref", x_tiles: int = 1) -> jax.Array:
-    """y = A x in the permuted basis; y has n_rows_pad entries.
-    ``x_tiles > 1`` column-blocks the RHS on the kernel path (the ref is
-    a flat gather and never needs it); the kernel pads x internally to a
-    tile multiple, so any x length tiles."""
+                backend: Backend = "ref") -> jax.Array:
+    """y = A x in the permuted basis; y has n_rows_pad entries."""
     if resolve_backend(backend) == "kernel":
         return pjds_matvec_kernel_call(
             a.val, a.col_idx, a.chunk_map, x,
             n_blocks=a.n_blocks, chunk_l=a.chunk_l, max_chunks=a.max_chunks,
-            x_tiles=x_tiles,
         )
     return R.pjds_matvec_ref(a.val, a.col_idx, a.row_block, x, a.n_blocks)
 
@@ -362,15 +348,15 @@ def ell_matvec(a: ELLDevice, x: jax.Array,
 
 
 def sell_matvec(a: SELLDevice, x: jax.Array,
-                backend: Backend = "ref", x_tiles: int = 1) -> jax.Array:
-    """y = A x with rows back in the ORIGINAL order (the window-local
-    inverse permutation is fused); y has n_rows_pad entries."""
+                backend: Backend = "ref") -> jax.Array:
+    """y = A x with rows back in the ORIGINAL order; y has n_rows_pad
+    entries.  The kernel path is the pJDS kernel over the SELL chunks
+    (same storage layout) followed by the window-local unpermute."""
     if resolve_backend(backend) == "kernel":
-        return sell_matvec_kernel_call(
-            a.val, a.col_idx, a.chunk_map, a.inv_perm, x,
-            n_blocks=a.n_blocks, chunk_l=a.chunk_l, sigma=a.sigma,
-            max_win_chunks=a.max_win_chunks, x_tiles=x_tiles,
-        )
+        return pjds_matvec_kernel_call(
+            a.val, a.col_idx, a.chunk_map, x,
+            n_blocks=a.n_blocks, chunk_l=a.chunk_l, max_chunks=a.max_chunks,
+        )[a.inv_perm]
     return R.sell_matvec_ref(a.val, a.col_idx, a.row_block, a.inv_perm, x,
                              a.n_blocks)
 
@@ -383,13 +369,12 @@ def csr_matvec(a: CSRDevice, x: jax.Array,
 
 
 def cmrs_matvec(a: CMRSDevice, x: jax.Array,
-                backend: Backend = "ref", x_tiles: int = 1) -> jax.Array:
+                backend: Backend = "ref") -> jax.Array:
     """y = A x in the ORIGINAL row order; y has n_rows_pad entries."""
     if resolve_backend(backend) == "kernel":
         return cmrs_matvec_kernel_call(
             a.val, a.col_idx, a.row_in_strip, a.chunk_map, x,
             n_strips=a.n_strips, chunk_l=a.chunk_l, max_chunks=a.max_chunks,
-            x_tiles=x_tiles,
         )
     return R.cmrs_matvec_ref(a.val, a.col_idx, a.row_in_strip, a.strip_map,
                              x, a.n_strips)
@@ -412,7 +397,6 @@ def select_format(
     spec: PM.TPUSpec = PM.TPU_V5E,
     value_dtype=None,
     index_dtype="auto",
-    x_tiles: int = 1,
 ) -> str:
     """Pick a storage format from row-length statistics alone.
 
@@ -434,12 +418,7 @@ def select_format(
     ``index_dtype`` (int16 when the column span fits halves the index
     stream) — so compressed variants are priced correctly; RHS/LHS
     traffic stays priced at the uncompressed vector width (the vectors
-    do not shrink with the matrix).  ``x_tiles > 1`` — dispatch has
-    determined x cannot be VMEM-resident — restricts the choice to the
-    formats whose kernels support a column-blocked RHS (sell/pjds) and
-    prices them with the tiled grid's re-read terms
-    (``perf_model.spmvm_bytes``: matrix stream × x_tiles, x re-read per
-    row block).  The full rationale is DESIGN.md §5.
+    do not shrink with the matrix).  The full rationale is DESIGN.md §5.
     """
     n = m.n_rows
     if m.nnz == 0 or n < _CSR_MIN_ROWS_FACTOR * b_r:
@@ -452,10 +431,10 @@ def select_format(
         else m.data.dtype.itemsize
     vecb = max(4, m.data.dtype.itemsize)
     ib = F.resolve_index_dtype(index_dtype, m.shape[1]).itemsize
-    n_row_blocks = -(-n // b_r)
 
-    ell_elems = F.estimate_storage_elements(rl, "ellpack_r", b_r, diag_align)
-    if x_tiles <= 1 and ell_elems / m.nnz - 1.0 <= _ELL_OVERHEAD_TOL:
+    ell_elems = F.estimate_storage_elements(rl, "ellpack_r", ell_tile(b_r),
+                                            diag_align)
+    if ell_elems / m.nnz - 1.0 <= _ELL_OVERHEAD_TOL:
         return "ellpack_r"    # rows (nearly) constant: no sort, no perm
 
     candidates = {
@@ -465,25 +444,22 @@ def select_format(
         "sell": PM.predicted_spmv_seconds(
             F.estimate_storage_elements(rl, "sell", b_r, diag_align, sigma),
             n, n_nzr,
-            perm_bytes=PM.perm_traffic_bytes(n, vecb, window_local=True),
+            perm_bytes=PM.perm_traffic_bytes(n, vecb),
             spec=spec, value_bytes=vb, index_bytes=ib, vec_bytes=vecb,
-            x_tiles=x_tiles, n_row_blocks=n_row_blocks, fmt="sell"),
+            fmt="sell"),
         "pjds": PM.predicted_spmv_seconds(
             F.estimate_storage_elements(rl, "pjds", b_r, diag_align),
             n, n_nzr,
-            perm_bytes=PM.perm_traffic_bytes(n, vecb, window_local=False),
+            perm_bytes=PM.perm_traffic_bytes(n, vecb),
             spec=spec, value_bytes=vb, index_bytes=ib, vec_bytes=vecb,
-            x_tiles=x_tiles, n_row_blocks=n_row_blocks, fmt="pjds"),
+            fmt="pjds"),
     }
     cmrs_elems = F.estimate_storage_elements(rl, "cmrs", b_r, diag_align)
     candidates["cmrs"] = max(
         PM.predicted_spmv_seconds(
             cmrs_elems, n, n_nzr, spec=spec, value_bytes=vb,
-            index_bytes=ib + PM.CMRS_RIS_BYTES, vec_bytes=vecb,
-            x_tiles=x_tiles, n_row_blocks=n_row_blocks, fmt="cmrs"),
-        PM.cmrs_reduce_seconds(cmrs_elems * x_tiles, b_r, spec))
-    if x_tiles > 1:
-        candidates.pop("ellpack_r")   # its kernel keeps x resident
+            index_bytes=ib + PM.CMRS_RIS_BYTES, vec_bytes=vecb, fmt="cmrs"),
+        PM.cmrs_reduce_seconds(cmrs_elems, b_r, spec))
     return min(candidates, key=candidates.get)
 
 
@@ -507,7 +483,6 @@ class SparseDevice:
     shape: Tuple[int, int] = dataclasses.field(metadata=dict(static=True))
     dev: Union[PJDSDevice, ELLDevice, SELLDevice, CSRDevice, CMRSDevice]
     inv_perm: Optional[jax.Array]      # pjds only: undo the global row sort
-    x_tiles: int = dataclasses.field(default=1, metadata=dict(static=True))
     # Preprocessing (reorder=) permutation: the stored matrix is
     # B = P A P^T with perm[k] = old index at new position k
     # (core.reorder's convention), and every entry point sandwiches —
@@ -552,14 +527,12 @@ class SparseDevice:
         if self.fmt == "ellpack_r":
             return ell_matvec(self.dev, x, backend)[: self.n_rows]
         if self.fmt == "sell":
-            return sell_matvec(self.dev, x, backend,
-                               x_tiles=self.x_tiles)[: self.n_rows]
+            return sell_matvec(self.dev, x, backend)[: self.n_rows]
         if self.fmt == "pjds":
-            y_p = pjds_matvec(self.dev, x, backend, x_tiles=self.x_tiles)
+            y_p = pjds_matvec(self.dev, x, backend)
             return y_p[self.inv_perm][: self.n_rows]
         if self.fmt == "cmrs":
-            return cmrs_matvec(self.dev, x, backend,
-                               x_tiles=self.x_tiles)[: self.n_rows]
+            return cmrs_matvec(self.dev, x, backend)[: self.n_rows]
         raise ValueError(f"unknown format {self.fmt!r}")
 
     def matmat(self, x: jax.Array, backend: Backend = "auto") -> jax.Array:
@@ -733,7 +706,6 @@ def as_device(
     chunk_l: int = 16,
     dtype=None,
     index_dtype="auto",
-    x_tiles: Union[int, str] = "auto",
     tune: Tune = "off",
     validate: str = "off",
     reorder: str = "off",
@@ -752,9 +724,6 @@ def as_device(
     * ``index_dtype`` — the stored column-index dtype; ``"auto"``
       (default) compresses to int16 whenever the column span fits
       (``formats.min_index_dtype``), falling back to int32.
-    * ``x_tiles`` — RHS column blocking for the blocked kernels;
-      ``"auto"`` picks :func:`choose_x_tiles` (1 — resident x — unless
-      the RHS would blow the VMEM budget).
 
     ``chunk_l`` defaults to 16 — the measured sweet spot of the
     grid-step-count vs padding trade now that the prefetched kernels
@@ -767,7 +736,7 @@ def as_device(
     matrix's structural fingerprint up in the persistent tuning cache,
     measuring the pruned candidate set on a miss; ``"force"``
     re-measures and overwrites the cached decision.  The tuned statics
-    (format, b_r, diag_align, chunk_l, sigma, x_tiles) then REPLACE the
+    (format, b_r, diag_align, chunk_l, sigma) then REPLACE the
     corresponding arguments — an explicit ``format`` (not ``"auto"``)
     restricts the search to that format, and the ``dtype`` /
     ``index_dtype`` storage policy is part of the cache key, never
@@ -825,16 +794,10 @@ def as_device(
         raise ValueError(f"reorder must be 'off', 'auto' or 'rcm'; "
                          f"got {reorder!r}")
 
-    if x_tiles == "auto":
-        # Size the tile by the RUNTIME vector width (>= f32), not the
-        # stored value width: a bf16 build still gathers from an f32 x.
-        x_tiles = choose_x_tiles(a.shape[1], max(4, a.data.dtype.itemsize))
-    x_tiles = int(x_tiles)
-
     key = (id(a), format, b_r, diag_align, sigma, chunk_l,
            np.dtype(dtype).name if dtype is not None else None,
            "auto" if index_dtype == "auto" else np.dtype(index_dtype).name,
-           x_tiles, reorder, tune)
+           reorder, tune)
     if tune != "force":      # force must re-measure, never serve a hit
         hit = _DEVICE_CACHE.get(key)
         if hit is not None and hit[0]() is a:
@@ -879,23 +842,17 @@ def as_device(
 
     fmt = format
     if fmt == "auto":
-        # When dispatch already decided x cannot be VMEM-resident, only
-        # the sell/pjds kernels can column-block it — select_format then
-        # restricts to those AND prices them with the tiled-grid re-read
-        # terms.  (An EXPLICIT format request, and the matmat paths, run
-        # resident regardless: x_tiles is a spMV-kernel knob, documented
-        # in pjds_spmv.py.)
         fmt = select_format(a, b_r=b_r, diag_align=da, sigma=sigma,
-                            value_dtype=dtype, index_dtype=index_dtype,
-                            x_tiles=x_tiles)
+                            value_dtype=dtype, index_dtype=index_dtype)
 
     inv_perm = None
     if fmt == "csr":
         dev = to_device_csr(a, dtype=dtype)
     elif fmt == "ellpack_r":
-        e = F.csr_to_ell(a, row_align=b_r, diag_align=da,
+        e = F.csr_to_ell(a, row_align=ell_tile(b_r), diag_align=da,
                          index_dtype=index_dtype)
-        dev = to_device_ell(e, chunk_l=chunk_l, tile_r=b_r, dtype=dtype)
+        dev = to_device_ell(e, chunk_l=chunk_l, tile_r=ell_tile(b_r),
+                            dtype=dtype)
     elif fmt == "sell":
         s = F.csr_to_sell(a, c=b_r, sigma=sigma, diag_align=da,
                           permuted_cols=False, index_dtype=index_dtype)
@@ -913,7 +870,7 @@ def as_device(
         raise ValueError(f"unknown format {fmt!r}")
 
     sd = SparseDevice(fmt=fmt, shape=a.shape, dev=dev, inv_perm=inv_perm,
-                      x_tiles=x_tiles, pre_perm=pre_perm, pre_inv=pre_inv)
+                      pre_perm=pre_perm, pre_inv=pre_inv)
     _cache_put(key, a_orig, sd)
     return sd
 
@@ -941,7 +898,7 @@ def spmv(
     path, returning (n_rows, k).  The converted device representation is
     cached, so repeated ``spmv`` calls with the same host matrix convert
     once.  ``convert_kwargs`` (b_r, diag_align, sigma, chunk_l, dtype,
-    index_dtype, x_tiles, tune) pass through to :func:`as_device` — in
+    index_dtype, tune) pass through to :func:`as_device` — in
     particular ``dtype=jnp.bfloat16`` stores a compressed value stream,
     ``index_dtype="auto"`` (the default) compresses indices to int16
     whenever the column span fits, and ``tune="auto"`` replaces the

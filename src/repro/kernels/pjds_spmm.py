@@ -1,22 +1,21 @@
 """Pallas TPU kernel for pJDS sparse matrix x dense matrix (multi-RHS).
 
-Y = A_pjds @ X with X: (n_cols_pad, n_rhs).  This is the kernel behind
+Y = A_pjds @ X with X: (n_cols, n_rhs).  This is the kernel behind
 ``repro.sparse.SparseFFN`` (pJDS-stored pruned FFN weights applied to a
-batch of activations) — the paper's format promoted to a first-class LM
-feature (DESIGN.md §4).
+batch of activations) and the block solvers — the paper's format
+promoted to a first-class LM feature (DESIGN.md §4).
 
-Grid: (rhs tile, row block, chunk) with chunks innermost, sharing the
-prefetched-extent design of ``pjds_spmv.py``: the scalar-prefetched
-``block_chunk_start``/``block_chunks`` arrays drive the val/col
-BlockSpec index maps, the (b_r, rhs_t) output block stays VMEM-pinned
-across its block's chunk sweep and is written back exactly once per rhs
-tile, and the X tile stays resident across a full sweep of the matrix.
-Per step the kernel gathers (chunk_l, b_r) rows of the X tile —
-amortising each gathered RHS row over ``rhs_t`` lanes, which lifts the
-arithmetic intensity from the spMVM's ~2/12 flop/byte to ~2*rhs_t/12:
-multi-RHS is how a sparse format escapes the memory roofline on TPU.
-int16 index / bf16 value streams cut the per-nonzero matrix bytes the
-same way they do for the spMVM kernels; accumulation stays f32.
+It rides the grouped grid of the spMV kernels
+(``_backend.grouped_matvec_call``).  The RHS gather runs in XLA ahead
+of the kernel, one RHS tile of ``rhs_t`` columns at a time, into a
+``(rhs_t, total_jds, b_r)`` stream — RHS columns on the leading axis,
+so every tile keeps the matrix's (sublane, lane) layout and a narrow
+block (k ~ 4 in the distributed block solvers) pads no lanes.  Each
+stored value then multiplies ``rhs_t`` gathered RHS entries, which
+lifts the arithmetic intensity from the spMVM's ~2/12 flop/byte toward
+~2*rhs_t/(12 + 8*rhs_t) per streamed slot.  int16 index / bf16 value
+streams cut the per-nonzero matrix bytes the same way they do for the
+spMVM kernels; accumulation stays f32.
 """
 from __future__ import annotations
 
@@ -24,31 +23,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ._backend import acc_dtype, chunk_clamp, resolve_interpret
-from .pjds_spmv import block_extents
+from ._backend import acc_dtype, gather_rhs, grouped_matvec_call
 
 __all__ = ["pjds_matmat_kernel_call"]
 
 
-def _pjds_spmm_kernel(start_ref, cnt_ref, val_ref, col_ref, x_ref, y_ref):
-    b = pl.program_id(1)
-    c = pl.program_id(2)
-
-    @pl.when(c == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    @pl.when(c < cnt_ref[b])
-    def _body():
-        x = x_ref[...]                              # (n_cols_pad, rhs_t)
-        idx = col_ref[...].astype(jnp.int32)        # (chunk_l, b_r); int16 ok
-        gathered = x[idx]                           # (chunk_l, b_r, rhs_t)
-        dt = y_ref.dtype
-        contrib = val_ref[...].astype(dt)[..., None] * gathered.astype(dt)
-        y_ref[...] += jnp.sum(contrib, axis=0)      # (b_r, rhs_t)
+def _rhs_row_sum(dt):
+    def reduce_rows(val, xg):                 # (cl, b_r), (rhs_t, cl, b_r)
+        return jnp.sum(val.astype(dt)[None] * xg.astype(dt), axis=1,
+                       keepdims=True)
+    return reduce_rows
 
 
 @functools.partial(
@@ -72,44 +57,26 @@ def pjds_matmat_kernel_call(
 
     val/col_idx: (total_jds, b_r), col_idx int16 or int32;
     chunk_map: (total_jds//chunk_l,) non-decreasing int32;
-    x: (n_cols_pad, n_rhs) with n_rhs % min(rhs_t, n_rhs) == 0 — the RHS
+    x: (n_cols, n_rhs) with n_rhs % min(rhs_t, n_rhs) == 0 — the RHS
     tile shrinks to n_rhs for narrow blocks (k < rhs_t), so small
-    multi-RHS counts (the distributed block solvers use k ~ 4) run as a
-    single tile instead of failing the alignment check.
-    max_chunks: static max chunks of any single block (None: total).
+    multi-RHS counts run as a single tile instead of failing the
+    alignment check.
+    max_chunks: static chunk ceiling of any output group (None: total).
     Returns (n_blocks * b_r, n_rhs) in the accumulator dtype.
     """
-    total_jds, b_r = val.shape
-    n_cols_pad, n_rhs = x.shape
+    n_rhs = x.shape[1]
     dt = acc_dtype(val.dtype, x.dtype)
     if n_rhs == 0:                      # empty RHS block: nothing to do
-        return jnp.zeros((n_blocks * b_r, 0), dt)
+        return jnp.zeros((n_blocks * val.shape[1], 0), dt)
     rhs_t = min(rhs_t, n_rhs)
-    if total_jds % chunk_l or n_rhs % rhs_t:
-        raise ValueError("shapes not aligned to (chunk_l, rhs_t)")
-    n_chunks = total_jds // chunk_l
-    if max_chunks is None:
-        max_chunks = n_chunks
-    n_tiles = n_rhs // rhs_t
-    start, cnt = block_extents(chunk_map, n_blocks)
-
-    mat_map = lambda t, b, c, s, n: (s[b] + chunk_clamp(c, n[b]), 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_tiles, n_blocks, max_chunks),
-        in_specs=[
-            pl.BlockSpec((chunk_l, b_r), mat_map),                       # val
-            pl.BlockSpec((chunk_l, b_r), mat_map),                       # col
-            pl.BlockSpec((n_cols_pad, rhs_t),
-                         lambda t, b, c, s, n: (0, t)),                  # X tile
-        ],
-        out_specs=pl.BlockSpec((b_r, rhs_t), lambda t, b, c, s, n: (b, t)),
-    )
-    y = pl.pallas_call(
-        _pjds_spmm_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blocks * b_r, n_rhs), dt),
-        interpret=resolve_interpret(interpret),
-        name="pjds_spmm",
-    )(start, cnt, val, col_idx, x)
-    return y
+    if n_rhs % rhs_t:
+        raise ValueError("n_rhs not a multiple of rhs_t")
+    # One gathered RHS tile is live at a time: the gather for k columns
+    # would hold k stream copies in HBM at once.
+    ys = [grouped_matvec_call(
+              _rhs_row_sum(dt), (val, gather_rhs(col_idx, x[:, t:t + rhs_t])),
+              chunk_map, n_blocks=n_blocks, chunk_l=chunk_l,
+              max_chunks=max_chunks, dt=dt, interpret=interpret,
+              name="pjds_spmm")
+          for t in range(0, n_rhs, rhs_t)]
+    return jnp.concatenate(ys).T

@@ -1,4 +1,5 @@
-"""Pallas TPU kernel for pJDS sparse matrix-vector multiplication.
+"""Pallas TPU kernel for pJDS (and SELL-C-sigma) sparse matrix-vector
+multiplication.
 
 This is the TPU adaptation of paper Listing 2, rebuilt around the memory
 stream (DESIGN.md §2/§2b):
@@ -9,89 +10,36 @@ stream (DESIGN.md §2/§2b):
   ``col_idx`` may be int16 (compressed index stream) or int32; ``val``
   may be bf16 (compressed value stream) or f32/f64 — accumulation is
   always at least f32.
-* The grid is 2-D ``(row_block, chunk)`` (3-D with the optional x-tile
-  axis): chunks of ``chunk_l`` jagged diagonals stream the row block's
-  slab of the matrix while the ``(1, b_r)`` output block stays pinned in
-  VMEM — the whole ``y`` never has to be resident, and each output block
-  is written back to HBM exactly once.
-* The per-block chunk extents ride a ``PrefetchScalarGridSpec``: the
-  scalar-prefetched ``block_chunk_start``/``block_chunks`` arrays (both
-  derived from ``chunk_map`` inside this call) drive the val/col
-  BlockSpec index maps directly, so the next block's tiles are DMA'd
-  while the current one computes — no SMEM lookup on the critical path.
-  Grid steps past a block's real chunk count clamp their index map to
-  the last real tile (no new DMA) and skip compute.
-* ``x_tiles > 1`` column-blocks the RHS: grid axis t holds an
-  ``n_cols_pad / x_tiles`` slice of x in VMEM and the gather is masked
-  to it.  This lifts the x-resident VMEM ceiling for single-device
-  matrices at a measured price — the matrix stream is re-read per x
-  tile and each output block accumulates across tiles —
-  ``perf_model.predicted_spmv_seconds(x_tiles=...)`` prices exactly
-  that trade (the distributed layer instead slices x structurally and
-  always runs ``x_tiles=1``).
+* The RHS gather ``x[col_idx]`` runs in XLA ahead of the kernel
+  (``_backend.gather_rhs``): the TPU compiler has no gather from a large
+  VMEM array.  The kernel streams ``val`` and the gathered ``xg`` in
+  ``(chunk_l, b_r)`` tiles and reduces each chunk over its sublanes.
+* The grid is ``(group, chunk)`` over groups of ``OUT_BLOCKS`` row
+  blocks (``_backend.grouped_matvec_call``): the group's
+  ``(OUT_BLOCKS, b_r)`` output block stays pinned in VMEM and is written
+  back to HBM once; scalar-prefetched extents drive the tile index maps.
+
+SELL-C-sigma stores the same chunk layout with the row sort confined to
+sigma windows, so its kernel is this one followed by the window-local
+unpermute ``y[inv_perm]`` in XLA (``ops.sell_matvec``).
 
 Padded entries follow the ``formats.PAD_COL`` sentinel contract: column
 0 (in range — the gather reads x[0] without masking) and value 0 (the
 product contributes nothing).
-
-VMEM working set per step: 2 tiles * chunk_l * b_r * itemsize
-+ x tile + one (1, b_r) output block.
-
-Accumulation is in f32 for sub-f32 inputs; output dtype is the
-accumulator dtype (callers cast down if desired).
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ._backend import (acc_dtype, chunk_clamp, pad_x_to_tiles,
-                       resolve_interpret, tile_contrib)
+from ._backend import acc_dtype, gather_rhs, grouped_matvec_call, row_sum
 
-__all__ = ["pjds_matvec_kernel_call", "block_extents"]
-
-
-def block_extents(chunk_map: jax.Array, n_blocks: int):
-    """Per-block (first chunk, chunk count) from the ascending per-chunk
-    block-id map — the scalar-prefetch operands of the blocked kernels.
-    ``chunk_map`` must be non-decreasing (stacked/padded distributed
-    operands pad with the LAST block id, which keeps it so); every block
-    has at least one chunk (block_len >= diag_align >= chunk_l)."""
-    n_chunks = chunk_map.shape[0]
-    start = jnp.searchsorted(chunk_map, jnp.arange(n_blocks, dtype=chunk_map.dtype),
-                             side="left").astype(jnp.int32)
-    cnt = jnp.diff(jnp.append(start, jnp.int32(n_chunks)))
-    return start, cnt
-
-
-def _pjds_spmv_kernel(start_ref, cnt_ref, val_ref, col_ref, x_ref, y_ref,
-                      *, x_tiles, x_t):
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-    c = pl.program_id(2)
-
-    # First visit of this output block: zero it while it is VMEM-pinned.
-    @pl.when((t == 0) & (c == 0))
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    @pl.when(c < cnt_ref[b])
-    def _body():
-        idx = col_ref[...].astype(jnp.int32)     # (chunk_l, b_r); int16 ok
-        contrib = tile_contrib(val_ref[...], idx, x_ref[...], t, x_t,
-                               x_tiles, y_ref.dtype)
-        y_ref[0, :] += jnp.sum(contrib, axis=0)
+__all__ = ["pjds_matvec_kernel_call"]
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("n_blocks", "chunk_l", "max_chunks", "x_tiles",
-                     "interpret"),
-)
+    jax.jit, static_argnames=("n_blocks", "chunk_l", "max_chunks", "interpret"))
 def pjds_matvec_kernel_call(
     val: jax.Array,
     col_idx: jax.Array,
@@ -101,57 +49,28 @@ def pjds_matvec_kernel_call(
     n_blocks: int,
     chunk_l: int = 8,
     max_chunks: int | None = None,
-    x_tiles: int = 1,
     interpret: bool | None = None,
 ) -> jax.Array:
     """y = A_pjds @ x (permuted basis).
 
     ``chunk_l`` must divide every pJDS block length (guaranteed when the
     format was built with ``diag_align`` a multiple of ``chunk_l``); the
-    ``ops.to_device_pjds`` wrapper checks this.  Larger ``chunk_l`` means
-    fewer grid steps at the cost of more padding — a measured trade-off in
-    benchmarks/bench_kernels.py.
+    ``ops.to_device_pjds`` wrapper checks this.
 
     val/col_idx: (total_jds, b_r) with total_jds % chunk_l == 0; col_idx
-                 int16 or int32 (upcast in-kernel for the gather).
+                 int16 or int32.
     chunk_map:   (total_jds // chunk_l,) non-decreasing int32 row-block
                  id per chunk.
-    x:           (n_cols_pad,) RHS in the permuted basis (zero-padded
-                 internally to a multiple of x_tiles; stored indices
-                 never reach the pad).
-    max_chunks:  static max chunks of any single block (``PJDSDevice``
-                 carries it); None falls back to the total chunk count —
-                 correct but with n_blocks * n_chunks grid steps.
+    x:           (n_cols,) RHS in the basis the column indices address.
+    max_chunks:  static chunk ceiling of any output group
+                 (``_backend.group_max_chunks``; ``PJDSDevice`` carries
+                 it); None falls back to the total chunk count.
     interpret:   None = compiled on TPU, interpret elsewhere
                  (``ops.resolve_interpret``).
     Returns y:   (n_blocks * b_r,) in the accumulator dtype.
     """
-    total_jds, b_r = val.shape
-    if total_jds % chunk_l:
-        raise ValueError(f"total_jds={total_jds} not a multiple of chunk_l={chunk_l}")
-    n_chunks = total_jds // chunk_l
-    if max_chunks is None:
-        max_chunks = n_chunks
-    x, x_t = pad_x_to_tiles(x, x_tiles)
     dt = acc_dtype(val.dtype, x.dtype)
-    start, cnt = block_extents(chunk_map, n_blocks)
-
-    mat_map = lambda b, t, c, s, n: (s[b] + chunk_clamp(c, n[b]), 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_blocks, x_tiles, max_chunks),
-        in_specs=[
-            pl.BlockSpec((chunk_l, b_r), mat_map),                # val tile
-            pl.BlockSpec((chunk_l, b_r), mat_map),                # col tile
-            pl.BlockSpec((x_t,), lambda b, t, c, s, n: (t,)),     # x tile
-        ],
-        out_specs=pl.BlockSpec((1, b_r), lambda b, t, c, s, n: (b, 0)),
-    )
-    y_blk = pl.pallas_call(
-        functools.partial(_pjds_spmv_kernel, x_tiles=x_tiles, x_t=x_t),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blocks, b_r), dt),
-        interpret=resolve_interpret(interpret),
-        name="pjds_spmv",
-    )(start, cnt, val, col_idx, x)
-    return y_blk.reshape(n_blocks * b_r)
+    return grouped_matvec_call(
+        row_sum(dt), (val, gather_rhs(col_idx, x)), chunk_map,
+        n_blocks=n_blocks, chunk_l=chunk_l, max_chunks=max_chunks, dt=dt,
+        interpret=interpret, name="pjds_spmv")

@@ -35,7 +35,6 @@ from repro.train.optimizer import AdamW
 from repro.train.schedules import cosine
 from repro.train.step import (make_train_step, train_state_shardings,
                               specs_to_shardings)
-from repro import _compat as compat
 from repro.launch.mesh import make_production_mesh
 from repro.launch.hlo_analysis import collective_bytes, hlo_flops_bytes
 
@@ -56,7 +55,7 @@ def _lower_cell(cfg, shape, mesh, rules, *, q_chunk, k_chunk,
                 seq_override=None):
     """Lower (not compile) the cell's step function."""
     model = build_model(cfg)
-    with compat.set_mesh(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         batch_sds, batch_spec_tree = model.input_specs(
             shape, seq_override=seq_override)
         batch_sh = specs_to_shardings(batch_spec_tree, mesh, rules)

@@ -87,13 +87,8 @@ def _group_size(line: str) -> int:
 
 
 def hlo_flops_bytes(cost) -> tuple[float, float]:
-    """Pull (flops, bytes) out of compiled.cost_analysis().
-
-    jax >= 0.5 returns a flat dict; 0.4.x returns a one-element list of
-    per-device dicts.
-    """
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
+    """Pull (flops, bytes) out of compiled.cost_analysis()'s dict."""
+    cost = cost or {}
     flops = float(cost.get("flops", 0.0))
     bts = float(cost.get("bytes accessed", 0.0))
     return flops, bts

@@ -2,8 +2,6 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state; ``jax.make_mesh`` is only called when a launcher actually runs.
-jax-version differences (AxisType absent on 0.4.x) are handled by
-``repro._compat.make_mesh``.
 
 Topology: TPU v5e, 256 chips/pod as a (16, 16) = (data, model) grid;
 multi-pod adds the leading "pod" axis (2 pods = 512 chips) used for
@@ -12,8 +10,13 @@ data parallelism across the DCN/ICI pod boundary.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro._compat import make_mesh
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types (sharding propagated by the
+    compiler, as the models' sharding rules expect)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

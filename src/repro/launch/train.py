@@ -19,7 +19,6 @@ import dataclasses
 
 import jax
 
-from repro import _compat as compat
 from repro import configs
 from repro.models.api import build_model
 from repro.models.sharding import (DEFAULT_SINGLE_POD, set_rules)
@@ -61,7 +60,7 @@ def main():
              else cosine(args.lr, max(args.steps // 10, 1), args.steps))
     opt = AdamW(lr_fn=lr_fn)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         set_rules(rules)
         param_sh, opt_sh = train_state_shardings(model, mesh, rules)
         params = jax.jit(model.init, out_shardings=param_sh)(

@@ -1,7 +1,7 @@
 """Search-space enumeration + model-based pruning for the autotuner.
 
 The kernel-static space after the PR-4 bandwidth overhaul is
-``format x b_r x chunk_l x sigma x x_tiles`` (times the dtype policy,
+``format x b_r x chunk_l x sigma`` (times the dtype policy,
 which is an INPUT here, not a search axis: the caller's storage
 precision is a contract, the tuner only picks layout statics for it).
 Measuring the full cross product would take seconds per matrix, so the
@@ -16,9 +16,7 @@ All legality constraints live in one place (:func:`enumerate_candidates`)
 and mirror the converters': ``diag_align`` is raised to ``chunk_l``
 exactly as ``as_device`` does, ``sigma`` is a SELL-only axis capped at
 the padded row count (where it degenerates to the pJDS global sort),
-and ``x_tiles > 1`` is offered only to the formats whose kernels can
-column-block the RHS (sell/pjds — same restriction as
-``select_format``).
+and ELLPACK-R row tiles are whole multiples of the 128-lane vreg width.
 """
 from __future__ import annotations
 
@@ -66,7 +64,6 @@ class Candidate:
     b_r: int = _DEFAULT_B_R
     chunk_l: int = _DEFAULT_CHUNK_L
     sigma: Optional[int] = None
-    x_tiles: int = 1
 
     def build_kwargs(self) -> dict:
         """Keyword arguments for ``ops.as_device`` (minus the dtype
@@ -77,7 +74,6 @@ class Candidate:
             diag_align=max(_DEFAULT_DIAG_ALIGN, self.chunk_l),
             sigma=self.sigma,
             chunk_l=self.chunk_l,
-            x_tiles=self.x_tiles,
         )
 
     def as_dict(self) -> dict:
@@ -90,14 +86,7 @@ class Candidate:
 
     def label(self) -> str:
         sig = f" sigma={self.sigma}" if self.sigma is not None else ""
-        xt = f" x_tiles={self.x_tiles}" if self.x_tiles != 1 else ""
-        return f"{self.fmt} b_r={self.b_r} chunk_l={self.chunk_l}{sig}{xt}"
-
-
-def _auto_x_tiles(m: F.CSRMatrix) -> int:
-    # Same rule as as_device: the tile is sized by the RUNTIME vector
-    # width (>= f32), whatever the stored value width.
-    return ops.choose_x_tiles(m.shape[1], max(4, m.data.dtype.itemsize))
+        return f"{self.fmt} b_r={self.b_r} chunk_l={self.chunk_l}{sig}"
 
 
 def heuristic_candidate(
@@ -109,13 +98,12 @@ def heuristic_candidate(
     """The exact build ``as_device`` produces with default statics and
     ``tune="off"`` — the baseline every tuned decision is benchmarked
     against, and the candidate :func:`prune_candidates` may never drop."""
-    auto_t = _auto_x_tiles(m)
     da = max(_DEFAULT_DIAG_ALIGN, _DEFAULT_CHUNK_L)
     fmt = format
     if fmt == "auto":
         fmt = ops.select_format(m, b_r=_DEFAULT_B_R, diag_align=da,
                                 sigma=None, value_dtype=dtype,
-                                index_dtype=index_dtype, x_tiles=auto_t)
+                                index_dtype=index_dtype)
     sigma = None
     if fmt == "sell":
         sigma = min(8 * _DEFAULT_B_R,
@@ -125,7 +113,6 @@ def heuristic_candidate(
         b_r=_DEFAULT_B_R,
         chunk_l=_DEFAULT_CHUNK_L,
         sigma=sigma,
-        x_tiles=auto_t if fmt in ("sell", "pjds") else 1,
     )
 
 
@@ -154,26 +141,16 @@ def enumerate_candidates(
 
     fmts = (["csr", "ellpack_r", "pjds", "sell", "cmrs"] if format == "auto"
             else [format])
-    auto_t = _auto_x_tiles(m)
     out = [heur]
     for fmt in fmts:
         if fmt == "csr":
             out.append(Candidate(fmt="csr"))
             continue
-        # x cannot be VMEM-resident -> only the column-blocking kernels
-        # may run (mirrors select_format's restriction); when it CAN be
-        # resident, offering the tiled grid would only add re-read
-        # traffic, so the resident build is the sole option.
-        if fmt in ("sell", "pjds", "cmrs"):
-            tile_opts = sorted({auto_t} | ({1} if auto_t == 1 else
-                                           {auto_t, 2 * auto_t}))
-        else:
-            if auto_t > 1:
-                continue
-            tile_opts = [1]
         for b_r in b_r_options:
             if n < ops._CSR_MIN_ROWS_FACTOR * b_r:
                 continue       # block padding dominates; csr covers this
+            if fmt == "ellpack_r" and b_r != ops.ell_tile(b_r):
+                continue       # as_device would build the 128-lane tile
             sigmas = [None]
             if fmt == "sell":
                 n_pad = _pad_to(n, b_r)
@@ -181,10 +158,8 @@ def enumerate_candidates(
                                  for f in sigma_factors})
             for chunk_l in chunk_l_options:
                 for sigma in sigmas:
-                    for xt in tile_opts:
-                        out.append(Candidate(fmt=fmt, b_r=b_r,
-                                             chunk_l=chunk_l, sigma=sigma,
-                                             x_tiles=xt))
+                    out.append(Candidate(fmt=fmt, b_r=b_r, chunk_l=chunk_l,
+                                         sigma=sigma))
     return list(dict.fromkeys(out))
 
 
@@ -197,8 +172,7 @@ def solver_candidates(
 ) -> list[tuple[str, Candidate]]:
     """The SOLVER-level probe set: (strategy, layout) pairs for
     ``tune_solver``, where strategy is ``"fused"`` (the fused
-    spMV+dots iteration — needs a resident-x SELL build, so those
-    candidates pin ``x_tiles=1``) or ``"composed"`` (separate
+    spMV+dots iteration over a SELL build) or ``"composed"`` (separate
     matvec + reduction HLOs over whatever layout wins per matvec).
 
     Deliberately tiny — a handful of probes, each a fixed-iteration
@@ -209,7 +183,6 @@ def solver_candidates(
     shift the best chunk_l relative to a bare matvec).
     """
     h_sell = heuristic_candidate(m, "sell", dtype, index_dtype)
-    h_sell = dataclasses.replace(h_sell, x_tiles=1)
     alt_cl = 8 if h_sell.chunk_l != 8 else 16
     h_auto = heuristic_candidate(m, "auto", dtype, index_dtype)
     out: list[tuple[str, Candidate]] = [
@@ -300,9 +273,8 @@ def price_candidate(
     da = max(_DEFAULT_DIAG_ALIGN, c.chunk_l)
     elems = F.estimate_storage_elements(rl, c.fmt, c.b_r, da, c.sigma)
     perm_bytes = 0.0
-    if c.fmt in ("sell", "pjds"):
-        perm_bytes = PM.perm_traffic_bytes(
-            n, vecb, window_local=(c.fmt == "sell"))
+    if c.fmt in PM.SORTED_ROW_FORMATS:
+        perm_bytes = PM.perm_traffic_bytes(n, vecb)
     if c.fmt == "cmrs":
         # Same max(memory, compute) pricing as select_format: the int8
         # row_in_strip stream adds a byte per slot, and the one-hot
@@ -311,10 +283,9 @@ def price_candidate(
     t = PM.predicted_spmv_seconds(
         elems, n, n_nzr, perm_bytes=perm_bytes, spec=spec,
         value_bytes=vb, index_bytes=ib, vec_bytes=vecb,
-        x_tiles=c.x_tiles, n_row_blocks=-(-n // c.b_r),
         fmt=c.fmt, calibration=calibration)
     if c.fmt == "cmrs":
-        t = max(t, PM.cmrs_reduce_seconds(elems * c.x_tiles, c.b_r, spec))
+        t = max(t, PM.cmrs_reduce_seconds(elems, c.b_r, spec))
     return t
 
 

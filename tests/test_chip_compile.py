@@ -1,0 +1,167 @@
+"""Compile every kernel of the main path for a described TPU v5e chip.
+
+Interpret mode accepts tile shapes and in-kernel operations that the
+TPU compiler (Mosaic) refuses, so the kernel-vs-ref parity tests cannot
+show that the kernels run on the chip.  These tests lower and compile
+each kernel through its dispatch entry point at sAMG's published
+shapes (paper §1.3: 3.4M rows, ~7 non-zeros per row) under both dtype
+policies, for one chip of a ``v5e:2x2`` topology that is described, not
+attached.  Nothing runs; the compiler accepts or refuses.
+
+The topology is described inside a module fixture (never at import),
+and ``jax.default_backend`` is steered to ``"tpu"`` inside each test so
+that ``backend="auto"`` and ``interpret=None`` resolve as they do on
+the chip.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import matrices as M
+from repro.kernels import ops
+from repro.kernels._backend import OUT_BLOCKS
+
+# sAMG at its published size, stored the way ``ops.as_device`` builds
+# it by default (b_r=128, chunk_l=16): every row block holds one
+# 16-deep chunk at ~7 non-zeros per row; the ELLPACK-R slab is as deep
+# as the longest generated row (30 plus the diagonal) rounded up.
+_N = M._PUBLISHED["sAMG"]["dim"]
+_B_R = 128
+_CHUNK_L = 16
+_N_BLOCKS = -(-_N // _B_R)
+_N_PAD = _N_BLOCKS * _B_R
+_TOTAL = _N_BLOCKS * _CHUNK_L
+_ELL_DEPTH = 32
+_GROUP_CHUNKS = 2 * OUT_BLOCKS
+_N_RHS = 8
+
+_POLICIES = [
+    pytest.param(jnp.float32, jnp.int32, id="f32+int32"),
+    pytest.param(jnp.bfloat16, jnp.int16, id="bf16+int16"),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_chip(monkeypatch, one_chip):
+    """Resolve backend/interpret defaults as on the chip, with the
+    persistent compilation cache off (a compile for a described chip is
+    written to it but cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield one_chip
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return compiled
+
+
+def _blocked_shapes(vdt, idt, chunk_l=_CHUNK_L):
+    return [((_TOTAL, _B_R), vdt), ((_TOTAL, _B_R), idt),
+            ((_TOTAL // chunk_l,), jnp.int32)]
+
+
+def _pjds(val, col, chunk_map, chunk_l=_CHUNK_L):
+    return ops.PJDSDevice(val=val, col_idx=col, chunk_map=chunk_map,
+                          row_block=chunk_map, n_blocks=_N_BLOCKS, b_r=_B_R,
+                          chunk_l=chunk_l, max_chunks=_GROUP_CHUNKS)
+
+
+def _sell(val, col, chunk_map, inv):
+    return ops.SELLDevice(val=val, col_idx=col, chunk_map=chunk_map,
+                          row_block=chunk_map, inv_perm=inv,
+                          n_blocks=_N_BLOCKS, b_r=_B_R, chunk_l=_CHUNK_L,
+                          sigma=8 * _B_R, max_chunks=_GROUP_CHUNKS)
+
+
+@pytest.mark.parametrize("chunk_l", [8, _CHUNK_L])   # 8: half a bf16 tile
+@pytest.mark.parametrize("vdt,idt", _POLICIES)
+def test_pjds_spmv_compiles(on_chip, vdt, idt, chunk_l):
+    def f(val, col, cm, x):
+        return ops.pjds_matvec(_pjds(val, col, cm, chunk_l), x,
+                               backend="auto")
+    _compile(f, on_chip, *_blocked_shapes(vdt, idt, chunk_l),
+             ((_N,), jnp.float32))
+
+
+@pytest.mark.parametrize("vdt,idt", _POLICIES)
+def test_sell_spmv_compiles(on_chip, vdt, idt):
+    def f(val, col, cm, inv, x):
+        return ops.sell_matvec(_sell(val, col, cm, inv), x, backend="auto")
+    _compile(f, on_chip, *_blocked_shapes(vdt, idt),
+             ((_N_PAD,), jnp.int32), ((_N,), jnp.float32))
+
+
+@pytest.mark.parametrize("vdt,idt", _POLICIES)
+def test_fused_iter_compiles(on_chip, vdt, idt):
+    from repro.kernels.fused_iter import fused_matvec_dots
+
+    def f(val, col, cm, inv, x, w1, w2):
+        return fused_matvec_dots(_sell(val, col, cm, inv), x, w1, w2,
+                                 backend=ops.resolve_backend("auto"))
+    vec = ((_N_PAD,), jnp.float32)
+    _compile(f, on_chip, *_blocked_shapes(vdt, idt),
+             ((_N_PAD,), jnp.int32), vec, vec, vec)
+
+
+@pytest.mark.parametrize("vdt,idt", _POLICIES)
+def test_ellr_spmv_compiles(on_chip, vdt, idt):
+    def f(val, col, rowlen, tc, x):
+        dev = ops.ELLDevice(val=val, col_idx=col, rowlen=rowlen,
+                            tile_chunks=tc, chunk_l=_CHUNK_L, tile_r=_B_R)
+        return ops.ell_matvec(dev, x, backend="auto")
+    _compile(f, on_chip, ((_ELL_DEPTH, _N_PAD), vdt),
+             ((_ELL_DEPTH, _N_PAD), idt), ((_N_PAD,), jnp.int32),
+             ((_N_BLOCKS,), jnp.int32), ((_N,), jnp.float32))
+
+
+@pytest.mark.parametrize("vdt,idt", _POLICIES)
+def test_cmrs_spmv_compiles(on_chip, vdt, idt):
+    def f(val, col, ris, cm, x):
+        dev = ops.CMRSDevice(val=val, col_idx=col, row_in_strip=ris,
+                             chunk_map=cm, strip_map=cm, n_strips=_N_BLOCKS,
+                             b_r=_B_R, chunk_l=_CHUNK_L,
+                             max_chunks=_GROUP_CHUNKS)
+        return ops.cmrs_matvec(dev, x, backend="auto")
+    val, col, cm = _blocked_shapes(vdt, idt)
+    _compile(f, on_chip, val, col, ((_TOTAL, _B_R), jnp.int8), cm,
+             ((_N,), jnp.float32))
+
+
+@pytest.mark.parametrize("vdt,idt", _POLICIES)
+def test_pjds_spmm_compiles(on_chip, vdt, idt):
+    def f(val, col, cm, x):
+        return ops.pjds_matmat(_pjds(val, col, cm), x, backend="auto")
+    _compile(f, on_chip, *_blocked_shapes(vdt, idt),
+             ((_N, _N_RHS), jnp.float32))
+
+
+def test_auto_backend_resolves_to_compiled_kernels(on_chip):
+    # what the compile tests lean on: on a TPU the dispatch defaults
+    # pick the Pallas kernels, compiled (not interpreted)
+    assert ops.resolve_backend("auto") == "kernel"
+    assert ops.resolve_interpret(None) is False

@@ -94,6 +94,26 @@ def test_kernel_backend_through_dispatch(rng):
         np.testing.assert_allclose(y_k, y_r, atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("b_r", [32, 64, 200])
+def test_ellpack_r_tile_is_whole_lane_widths(rng, b_r):
+    # an ELLPACK-R tile is a lane slice, which the TPU compiler takes
+    # only in whole 128-lane vregs: any b_r builds the rounded-up tile
+    a = _uniform(rng, 300)
+    m = F.csr_from_dense(a)
+    x = rng.standard_normal(300).astype(np.float32)
+    op = operator(m, format="ellpack_r", b_r=b_r, backend="kernel")
+    assert op.dev.dev.tile_r == ops.ell_tile(b_r)
+    assert op.dev.dev.tile_r % ops.ELL_LANES == 0
+    truth = a.astype(np.float64) @ x
+    scale = max(np.abs(truth).max(), 1.0)
+    np.testing.assert_allclose(np.asarray(op @ x) / scale, truth / scale,
+                               atol=1e-5)
+    e = F.csr_to_ell(m, row_align=b_r)
+    if b_r % ops.ELL_LANES:
+        with pytest.raises(ValueError, match="multiple of 128"):
+            ops.to_device_ell(e, tile_r=b_r)
+
+
 def test_conversion_cache_reuses_device_rep(rng):
     m = F.csr_from_dense(_uniform(rng, 96))
     d1 = ops.as_device(m, "auto", b_r=B_R)

@@ -26,7 +26,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.train.step import (make_train_step, train_state_shardings,
                                   batch_shardings)
     from repro.checkpoint import store
-    from repro._compat import set_mesh, make_mesh
+    from jax import set_mesh
+    from repro.launch.mesh import make_mesh
 
     def mesh_of(dp, tp):
         return make_mesh((dp, tp), ("data", "model"))

@@ -1,6 +1,6 @@
 """Compressed-stream edge cases: int16/int32 index selection at the
 boundary, bf16 value storage vs the f32 reference, accumulator dtypes,
-the padding-sentinel audit, and the column-blocked-x kernel grid."""
+the padding-sentinel audit, and the gathered-RHS kernel path."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -124,47 +124,16 @@ def test_padding_audit_catches_corruption(rng):
             F.PJDSMatrix(**{**p.__dict__, "col_idx": bad_col}))
 
 
-# ------------------------------------------------------- column-blocked x
-@pytest.mark.parametrize("x_tiles", [2, 4])
-@pytest.mark.parametrize("fmt", ["pjds", "sell"])
-def test_x_tiled_kernel_matches_resident(rng, fmt, x_tiles):
-    a, m = _mk(rng, 128, density=0.1)
-    x = rng.standard_normal(128).astype(np.float32)
-    y_res = np.asarray(operator(m, format=fmt, b_r=32, backend="kernel",
-                                x_tiles=1) @ x)
-    y_tiled = np.asarray(operator(m, format=fmt, b_r=32,
-                                  backend="kernel", x_tiles=x_tiles) @ x)
-    np.testing.assert_allclose(y_tiled, y_res, atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(y_tiled, a.astype(np.float64) @ x, atol=1e-3)
-
-
-def test_x_tiles_pad_when_not_divisible(rng):
-    # 130-column x with x_tiles=4: the kernel pads x internally to a
-    # tile multiple and still tiles (no silent resident fallback)
-    a, m = _mk(rng, 130, density=0.1)
-    x = rng.standard_normal(130).astype(np.float32)
-    for fmt in ("pjds", "sell"):
-        y = np.asarray(operator(m, format=fmt, b_r=32, backend="kernel",
-                                x_tiles=4) @ x)
-        np.testing.assert_allclose(y, a.astype(np.float64) @ x, atol=1e-3)
-
-
-def test_choose_x_tiles_budget():
-    assert ops.choose_x_tiles(1024, 4) == 1              # fits: resident
-    assert ops.choose_x_tiles(1024, 4, vmem_limit=1024) == 4
-    assert ops.choose_x_tiles(4096, 2, vmem_limit=1024) == 8
-
-
-def test_auto_format_avoids_resident_kernels_when_x_tiled(rng):
-    # near-constant rows would normally short-circuit to ellpack_r, whose
-    # kernel keeps x resident; with x tiling required, auto must pick a
-    # format whose kernel can column-block the RHS
-    a = np.zeros((256, 256), np.float32)
-    for i in range(256):
-        a[i, rng.integers(0, 256, 8)] = 1.0
-    m = F.csr_from_dense(a)
-    assert ops.select_format(m, b_r=32) == "ellpack_r"
-    assert ops.select_format(m, b_r=32, x_tiles=4) in ("sell", "pjds")
+# ------------------------------------------------- gathered RHS, any length
+@pytest.mark.parametrize("n", [128, 130])      # incl. non-divisible rows
+@pytest.mark.parametrize("fmt", ["ellpack_r", "pjds", "sell", "cmrs"])
+def test_kernel_gathers_rhs_of_any_length(rng, fmt, n):
+    # the RHS gather runs ahead of the kernel for every format: x of a
+    # length no tile divides must still meet every stored column
+    a, m = _mk(rng, n, density=0.1)
+    x = rng.standard_normal(n).astype(np.float32)
+    y = np.asarray(operator(m, format=fmt, b_r=32, backend="kernel") @ x)
+    np.testing.assert_allclose(y, a.astype(np.float64) @ x, atol=1e-3)
 
 
 def test_cache_key_normalizes_index_dtype(rng):

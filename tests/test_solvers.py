@@ -152,7 +152,7 @@ def test_fused_composed_parity_device(method, mk, rng):
     m = mk()
     b = rng.standard_normal(m.n_rows).astype(np.float32)
     bj = jnp.asarray(b)
-    op = operator(m, format="sell", x_tiles=1)
+    op = operator(m, format="sell")
     fused = api._one_solve(op, bj, method=method, strategy="fused",
                            maxiter=3000, tol=1e-7, precond=None)
     comp = api._one_solve(op, bj, method=method, strategy="composed",
@@ -500,3 +500,18 @@ def test_refined_stall_is_typed_and_escalates_to_f32(rng, monkeypatch):
     assert entries.get("bf16->f32") == "converged"
     assert all(s == "diverged" for r, s in entries.items()
                if r != "bf16->f32")
+
+
+def test_kernel_to_ref_rung_warns_with_the_kernel_error(rng):
+    # the ref path is a different program: a solve that falls back to it
+    # must say so, carrying the error the kernel path raised
+    import repro
+    from repro.testing import faults
+    m = M.poisson_2d(8, 8)
+    b = rng.standard_normal(m.n_rows).astype(np.float32)
+    with faults.fail_kernel_backend():
+        with pytest.warns(RuntimeWarning, match="injected kernel-launch"):
+            res = repro.solve(m, b, tune="off", backend="kernel",
+                              fallback="auto")
+    assert res.status == "converged"
+    assert "kernel->ref" in [e["rung"] for e in res.info["ladder"]]

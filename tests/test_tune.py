@@ -181,6 +181,16 @@ def test_enumerate_respects_format_restriction():
     assert T.heuristic_candidate(m, format="pjds") in cands
 
 
+def test_ellpack_r_tiles_are_whole_lane_widths():
+    # ELLPACK-R row tiles are lane slices: the TPU compiler refuses a
+    # tile of fewer than 128 lanes, so the tuner never offers one
+    m = M.samg(scale=0.002)
+    ell = [c for c in T.enumerate_candidates(m) if c.fmt == "ellpack_r"]
+    assert ell and all(c.b_r % 128 == 0 for c in ell)
+    assert any(c.fmt == "pjds" and c.b_r == 32
+               for c in T.enumerate_candidates(m))
+
+
 def test_degenerate_matrix_collapses_to_csr():
     a = np.zeros((8, 8), np.float32)
     m = F.csr_from_dense(a)
@@ -189,7 +199,7 @@ def test_degenerate_matrix_collapses_to_csr():
 
 
 def test_candidate_json_roundtrip():
-    c = T.Candidate(fmt="sell", b_r=64, chunk_l=8, sigma=512, x_tiles=2)
+    c = T.Candidate(fmt="sell", b_r=64, chunk_l=8, sigma=512)
     assert T.Candidate.from_dict(json.loads(json.dumps(c.as_dict()))) == c
 
 
