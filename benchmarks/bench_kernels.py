@@ -74,7 +74,7 @@ def bytes_per_nnz_rows(m, x, truth, mat: str, fmt: str, rows: list,
         ib = sd.index_dtype.itemsize
         # vectors stay f32 whatever the stored width (vec_bytes default)
         pred_s = PM.predicted_spmv_seconds(
-            sd.storage_elements(), n, n_nzr,
+            sd.stored_slots, n, n_nzr,
             perm_bytes=(PM.perm_traffic_bytes(n, 4)
                         if fmt in PM.SORTED_ROW_FORMATS else 0.0),
             value_bytes=vb, index_bytes=ib)

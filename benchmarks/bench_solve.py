@@ -161,7 +161,7 @@ def _iteration_bytes(m, op, method, strategy):
     vb = jnp.dtype(op.dev.value_dtype).itemsize
     ib = jnp.dtype(op.dev.index_dtype).itemsize
     return PM.solver_iteration_bytes(
-        op.dev.storage_elements(), m.n_rows, m.n_nzr, method=method,
+        op.dev.stored_slots, m.n_rows, m.n_nzr, method=method,
         strategy=strategy, value_bytes=vb, index_bytes=ib, vec_bytes=4)
 
 

@@ -64,7 +64,7 @@ def solver_pricing(matrix: str, scale: float, method: str) -> list[dict]:
                            index_dtype="auto")
         vb = jnp.dtype(sd.value_dtype).itemsize
         ib = jnp.dtype(sd.index_dtype).itemsize
-        stored = sd.storage_elements()
+        stored = sd.stored_slots
         spmv_only = PM.SOLVER_SPMV_COUNT[method] * (
             PM.spmvm_bytes(stored, m.n_rows, 0.0, m.n_nzr,
                            value_bytes=vb, index_bytes=ib, vec_bytes=4)

@@ -104,7 +104,7 @@ def phase_matvec(m, ref, x, backend, on_chip):
         y, first_s = timed(prog, op, xj)
         _, warm_s = timed(prog, op, xj)
         err = ref.rel_err(y, y_ref)
-        slots = op.dev.storage_elements()
+        slots = op.dev.stored_slots
         log(f"matvec format={fmt:9s} -> {op.fmt:9s} backend={resolved} "
             f"interpret={ops.resolve_interpret(None)} "
             f"kernel_in_program={in_program} build_s={build_s:.3f} "

@@ -38,18 +38,20 @@ refinement path).
 
 Every call returns :class:`repro.core.solvers.SolveResult`; ``info``
 carries ``strategy``, per-phase wall-clock ``phase_s`` (tune / build /
-solve), the tuner's decision under ``tune`` and per-round refinement
-diagnostics under ``refine``.
+solve: the durations of the host spans ``repro.solve.tune``,
+``repro.solve.build`` and ``repro.solve.iterate``), the tuner's
+decision under ``tune`` and per-round refinement diagnostics under
+``refine``.
 """
 from __future__ import annotations
 
 import math
-import time
 import warnings
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import solvers as S
 from repro.core.solvers import SolveResult
 
@@ -288,80 +290,85 @@ def solve(a, b, *, method: str = "cg", precond=None, tol: float = 1e-6,
                          "for the low-precision operand; use precond="
                          "'jacobi' or None")
     maxiter = _DEFAULT_MAXITER[method] if maxiter is None else maxiter
-    phase_s: dict = {}
-    info_tune = None
-    strategy_pref = None
-    op_lo = None
+    with obs.span("repro.solve"):
+        phase_s: dict = {}
+        info_tune = None
+        strategy_pref = None
+        op_lo = None
 
-    if _is_host_matrix(a):
-        m = a
-        do_refine = (refine is True
-                     or (refine == "auto" and _is_sub_f32(dtype)
-                         and method != "block_cg"))
-        inner_dtype = dtype if _is_sub_f32(dtype) else jnp.bfloat16
-        build_kwargs = dict(convert_kwargs)
-        t0 = time.perf_counter()
-        if tune not in ("off", False, None) and method != "block_cg":
-            from repro import tune as T
-            st = T.tune_solver(m, method=method,
-                               dtype=None if do_refine else dtype,
-                               index_dtype=index_dtype,
-                               force=(tune == "force"))
-            strategy_pref = st.strategy
-            build_kwargs = st.layout.build_kwargs()
-            if "validate" in convert_kwargs:   # admission gate survives
-                build_kwargs["validate"] = convert_kwargs["validate"]
-            info_tune = {"cached": st.cached, "strategy": st.strategy,
-                         "layout": st.layout.label()}
+        if _is_host_matrix(a):
+            m = a
+            do_refine = (refine is True
+                         or (refine == "auto" and _is_sub_f32(dtype)
+                             and method != "block_cg"))
+            inner_dtype = dtype if _is_sub_f32(dtype) else jnp.bfloat16
+            build_kwargs = dict(convert_kwargs)
+            with obs.span("repro.solve.tune") as sp:
+                if tune not in ("off", False, None) and method != "block_cg":
+                    from repro import tune as T
+                    st = T.tune_solver(m, method=method,
+                                       dtype=None if do_refine else dtype,
+                                       index_dtype=index_dtype,
+                                       force=(tune == "force"))
+                    strategy_pref = st.strategy
+                    build_kwargs = st.layout.build_kwargs()
+                    if "validate" in convert_kwargs:  # admission gate survives
+                        build_kwargs["validate"] = convert_kwargs["validate"]
+                    info_tune = {"cached": st.cached, "strategy": st.strategy,
+                                 "layout": st.layout.label()}
+                else:
+                    build_kwargs.setdefault("format", format)
+                    if (build_kwargs["format"] == "auto"
+                            and method in ("cg", "bicgstab")
+                            and precond is None):
+                        build_kwargs["format"] = "sell"  # fused-eligible build
+            phase_s["tune"] = sp.seconds
+
+            from repro.core.operator import operator
+            with obs.span("repro.solve.build") as sp:
+                op = operator(m, dtype=None if do_refine else dtype,
+                              index_dtype=index_dtype, backend=backend,
+                              **build_kwargs)
+                if do_refine:
+                    op_lo = operator(m, dtype=inner_dtype,
+                                     index_dtype=index_dtype, backend=backend,
+                                     **build_kwargs)
+            phase_s["build"] = sp.seconds
         else:
-            build_kwargs.setdefault("format", format)
-            if (build_kwargs["format"] == "auto"
-                    and method in ("cg", "bicgstab") and precond is None):
-                build_kwargs["format"] = "sell"   # fused-eligible build
-        phase_s["tune"] = time.perf_counter() - t0
+            op = a
+            is_operator = hasattr(op, "matvec")
+            do_refine = refine is True
+            if do_refine and not is_operator:
+                raise ValueError("refine=True needs an operator or host "
+                                 "matrix; got a bare closure")
+            if do_refine and _is_sub_f32(getattr(op, "dtype", None)):
+                raise ValueError("refine=True expects a full-precision "
+                                 "operator to refine against; this one is "
+                                 f"already {op.dtype} — pass the host "
+                                 "matrix instead")
+            with obs.span("repro.solve.build") as sp:
+                if do_refine:
+                    op_lo = _cast_low_precision(op)
+            phase_s["build"] = sp.seconds
 
-        from repro.core.operator import operator
-        t0 = time.perf_counter()
-        op = operator(m, dtype=None if do_refine else dtype,
-                      index_dtype=index_dtype, backend=backend,
-                      **build_kwargs)
-        if do_refine:
-            op_lo = operator(m, dtype=inner_dtype, index_dtype=index_dtype,
-                             backend=backend, **build_kwargs)
-        phase_s["build"] = time.perf_counter() - t0
-    else:
-        op = a
-        is_operator = hasattr(op, "matvec")
-        do_refine = refine is True
-        if do_refine and not is_operator:
-            raise ValueError("refine=True needs an operator or host matrix; "
-                             "got a bare closure")
-        if do_refine and _is_sub_f32(getattr(op, "dtype", None)):
-            raise ValueError("refine=True expects a full-precision operator "
-                             "to refine against; this one is already "
-                             f"{op.dtype} — pass the host matrix instead")
-        t0 = time.perf_counter()
-        if do_refine:
-            op_lo = _cast_low_precision(op)
-        phase_s["build"] = time.perf_counter() - t0
+        strategy = ("fused"
+                    if (_fused_eligible(op, method, precond, b)
+                        and strategy_pref != "composed")
+                    else "composed")
 
-    strategy = ("fused"
-                if (_fused_eligible(op, method, precond, b)
-                    and strategy_pref != "composed")
-                else "composed")
+        with obs.span("repro.solve.iterate") as sp:
+            res, ladder = _ladder_solve(op, op_lo, b, method=method,
+                                        strategy=strategy, maxiter=maxiter,
+                                        tol=tol, precond=precond, x0=x0,
+                                        fallback=fallback)
+        phase_s["solve"] = sp.seconds
 
-    t0 = time.perf_counter()
-    res, ladder = _ladder_solve(op, op_lo, b, method=method,
-                                strategy=strategy, maxiter=maxiter, tol=tol,
-                                precond=precond, x0=x0, fallback=fallback)
-    phase_s["solve"] = time.perf_counter() - t0
-
-    res.info["phase_s"] = phase_s
-    if info_tune is not None:
-        res.info["tune"] = info_tune
-    if len(ladder) > 1 or fallback not in ("off", False, None):
-        res.info["ladder"] = ladder
-    return res
+        res.info["phase_s"] = phase_s
+        if info_tune is not None:
+            res.info["tune"] = info_tune
+        if len(ladder) > 1 or fallback not in ("off", False, None):
+            res.info["ladder"] = ladder
+        return res
 
 
 def _build_rungs(op, op_lo, *, method, strategy, precond, fallback):

@@ -624,15 +624,17 @@ def _exchange_halo_full(x_blk, axis: str, n_dev: int, halo_w: int,
                         gc: int = 1):
     """Bulk ring ppermute halo: ext buffer = x slices of the grid-column
     neighbors at ring distances -halo_w .. +halo_w."""
-    parts = []
-    for d in range(halo_w, 0, -1):  # from distance -d (send own slice +d)
-        parts.append(jax.lax.ppermute(
-            x_blk, axis, _col_ring_pairs(n_dev, gc, d)))
-    parts.append(x_blk)
-    for d in range(1, halo_w + 1):  # from distance +d
-        parts.append(jax.lax.ppermute(
-            x_blk, axis, _col_ring_pairs(n_dev, gc, -d)))
-    return jnp.concatenate(parts)
+    with jax.named_scope("repro.halo"):
+        parts = []
+        # from distance -d (send own slice +d)
+        for d in range(halo_w, 0, -1):
+            parts.append(jax.lax.ppermute(
+                x_blk, axis, _col_ring_pairs(n_dev, gc, d)))
+        parts.append(x_blk)
+        for d in range(1, halo_w + 1):  # from distance +d
+            parts.append(jax.lax.ppermute(
+                x_blk, axis, _col_ring_pairs(n_dev, gc, -d)))
+        return jnp.concatenate(parts)
 
 
 # Backwards-compatible alias (pre-gathered name).
@@ -649,17 +651,19 @@ def _exchange_halo_gathered(x_blk, send_idx, recv_idx, axis: str, n_dev: int,
     columns never point there), so ``rem_col`` is identical either way.
     Distances whose measured halo is empty ship nothing at all.
     """
-    n_loc = x_blk.shape[0]
-    ext = jnp.zeros(((2 * halo_w + 1) * n_loc,) + x_blk.shape[1:],
-                    x_blk.dtype)
-    for i, d in enumerate(halo_distances(halo_w)):
-        h = halo_lens[i]
-        if h == 0:
-            continue
-        buf = x_blk[send_idx[i, :h]]
-        buf = jax.lax.ppermute(buf, axis, _col_ring_pairs(n_dev, gc, -d))
-        ext = ext.at[recv_idx[i, :h]].set(buf, mode="drop")
-    return ext
+    with jax.named_scope("repro.halo"):
+        n_loc = x_blk.shape[0]
+        ext = jnp.zeros(((2 * halo_w + 1) * n_loc,) + x_blk.shape[1:],
+                        x_blk.dtype)
+        for i, d in enumerate(halo_distances(halo_w)):
+            h = halo_lens[i]
+            if h == 0:
+                continue
+            buf = x_blk[send_idx[i, :h]]
+            buf = jax.lax.ppermute(buf, axis,
+                                   _col_ring_pairs(n_dev, gc, -d))
+            ext = ext.at[recv_idx[i, :h]].set(buf, mode="drop")
+        return ext
 
 
 def _reduce_partials(dist: DistPJDS, y, seg_pos, red_send_pos, red_recv_idx,
@@ -671,33 +675,35 @@ def _reduce_partials(dist: DistPJDS, y, seg_pos, red_send_pos, red_recv_idx,
     partial rows directly from it (no dense unpermute), ships the
     partials along the grid-row ring, and scatter-adds what arrives.
     """
-    gr, gc = dist.grid_eff
-    red_dists = halo_distances(dist.red_w)
-    if halo == "full":
-        # bulk baseline: ship whole partial segments.  Distances whose
-        # measured coupling is empty must still be SKIPPED: on an even
-        # ring, +gc/2 and -gc/2 are the same partner and the wrap
-        # convention parks all coupling on +gc/2 — shipping the empty
-        # mirror distance would double-count the shared segment.
-        y_own = y[seg_pos[0]]
+    with jax.named_scope("repro.halo"):
+        gr, gc = dist.grid_eff
+        red_dists = halo_distances(dist.red_w)
+        if halo == "full":
+            # bulk baseline: ship whole partial segments.  Distances
+            # whose measured coupling is empty must still be SKIPPED: on
+            # an even ring, +gc/2 and -gc/2 are the same partner and the
+            # wrap convention parks all coupling on +gc/2 — shipping the
+            # empty mirror distance would double-count the shared
+            # segment.
+            y_own = y[seg_pos[0]]
+            for kk, t in enumerate(red_dists):
+                if dist.red_lens[kk] == 0:
+                    continue
+                buf = y[seg_pos[t % gc]]
+                buf = jax.lax.ppermute(buf, axis,
+                                       _row_ring_pairs(dist.n_dev, gc, t))
+                y_own = y_own + buf
+            return y_own
+        y_own, bufs = R.partial_reduce_epilogue_ref(
+            y, seg_pos[0], red_send_pos, dist.red_lens)
         for kk, t in enumerate(red_dists):
             if dist.red_lens[kk] == 0:
                 continue
-            buf = y[seg_pos[t % gc]]
-            buf = jax.lax.ppermute(buf, axis,
+            buf = jax.lax.ppermute(bufs[kk], axis,
                                    _row_ring_pairs(dist.n_dev, gc, t))
-            y_own = y_own + buf
+            h = dist.red_lens[kk]
+            y_own = y_own.at[red_recv_idx[kk, :h]].add(buf, mode="drop")
         return y_own
-    y_own, bufs = R.partial_reduce_epilogue_ref(
-        y, seg_pos[0], red_send_pos, dist.red_lens)
-    for kk, t in enumerate(red_dists):
-        if dist.red_lens[kk] == 0:
-            continue
-        buf = jax.lax.ppermute(bufs[kk], axis,
-                               _row_ring_pairs(dist.n_dev, gc, t))
-        h = dist.red_lens[kk]
-        y_own = y_own.at[red_recv_idx[kk, :h]].add(buf, mode="drop")
-    return y_own
 
 
 def dist_matvec_local(dist: DistPJDS, x_blk: jax.Array, *, axis: str,
@@ -765,7 +771,8 @@ def dist_matvec_local(dist: DistPJDS, x_blk: jax.Array, *, axis: str,
 
     if gc == 1:
         # 1-D: the device owns its whole row block — just undo the sort.
-        y = y[sq(dist.seg_pos)[0]]
+        with jax.named_scope("repro.unpermute"):
+            y = y[sq(dist.seg_pos)[0]]
     else:
         y = _reduce_partials(dist, y, sq(dist.seg_pos),
                              sq(dist.red_send_pos), sq(dist.red_recv_idx),
@@ -802,12 +809,13 @@ def _pipeline_body(dist: DistPJDS, x_blk, loc_spmv, loc_args, *, axis: str,
     for d in dist.stage_dists:
         k = dists.index(d)
         pairs = _col_ring_pairs(dist.n_dev, gc, -d)
-        if halo == "gathered":
-            h = dist.halo_lens[k]
-            buf = x_blk[send_idx[k, :h]]
-        else:
-            buf = x_blk
-        bufs.append(jax.lax.ppermute(buf, axis, pairs))
+        with jax.named_scope("repro.halo"):
+            if halo == "gathered":
+                h = dist.halo_lens[k]
+                buf = x_blk[send_idx[k, :h]]
+            else:
+                buf = x_blk
+            bufs.append(jax.lax.ppermute(buf, axis, pairs))
 
     y = loc_spmv(*loc_args, x_blk)
     for s, d in enumerate(dist.stage_dists):

@@ -37,6 +37,8 @@ from typing import Tuple
 
 import numpy as np
 
+from repro import obs
+
 __all__ = [
     "CSRMatrix",
     "ELLMatrix",
@@ -751,6 +753,7 @@ def csr_diagonal(m: CSRMatrix) -> np.ndarray:
 # --------------------------------------------------------------------------
 # Structural fingerprint (the autotuner's cache key component)
 # --------------------------------------------------------------------------
+@obs.span("repro.fingerprint")
 def structural_fingerprint(m: CSRMatrix) -> str:
     """sha1 digest of the matrix STRUCTURE: shape + indptr + indices,
     deliberately excluding the stored values.

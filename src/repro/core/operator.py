@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import formats as F
 from repro.core import dist_spmv as D
 from repro.core import perf_model as PM
@@ -225,6 +226,11 @@ class DeviceOperator(SparseOperator):
         return self.values.dtype
 
     @property
+    def stored_slots(self) -> int:
+        """Value slots one forward apply streams, padding included."""
+        return self.dev.stored_slots
+
+    @property
     def values(self) -> jax.Array:
         """The stored value leaf (the differentiable parameters)."""
         d = self.dev.dev
@@ -392,6 +398,12 @@ class DistOperator(SparseOperator):
     def dtype(self):
         return self.dist.loc_val.dtype
 
+    @property
+    def stored_slots(self) -> int:
+        """Value slots one forward apply streams, padding included,
+        summed over the devices: the local and the remote operand."""
+        return int(self.dist.loc_val.size + self.dist.rem_val.size)
+
     # -- application -------------------------------------------------------
     def _fwd(self, dist, multi_rhs):
         # Memoized per instance: the shard_map closure is built once per
@@ -407,12 +419,7 @@ class DistOperator(SparseOperator):
         return fn
 
     def _sandwich(self, apply, v):
-        """Run ``apply`` in the stored (reordered) basis: gather v into
-        it, gather the result back out.  B = P A P^T is
-        symmetric-permuted, so the SAME sandwich serves A and A^T."""
-        if self.pre_perm is None:
-            return apply(v)
-        return apply(v[self.pre_perm])[self.pre_inv]
+        return ops.sandwich(self.pre_perm, self.pre_inv, apply, v)
 
     def matvec(self, x):
         fwd = self._fwd(self.dist, multi_rhs=False)
@@ -470,6 +477,14 @@ class DistOperator(SparseOperator):
 # --------------------------------------------------------------------------
 # Factories
 # --------------------------------------------------------------------------
+def _built(op):
+    """``op``, with its stored slots noted (``obs.gauge``) as those of
+    the operator built last."""
+    obs.gauge("repro.stored_slots", op.stored_slots)
+    return op
+
+
+@obs.span("repro.operator.build")
 def operator(
     a: Union[F.CSRMatrix, np.ndarray, ops.SparseDevice, SparseOperator],
     format: ops.FormatName = "auto",
@@ -518,7 +533,7 @@ def operator(
         if transpose != "ref":
             raise ValueError(f"transpose must be 'ref' or 'device'; "
                              f"got {transpose!r}")
-        return DeviceOperator(a, backend=backend)
+        return _built(DeviceOperator(a, backend=backend))
     if isinstance(a, np.ndarray):
         a = ops._dense_to_csr_cached(a)
     if not isinstance(a, F.CSRMatrix):
@@ -530,9 +545,10 @@ def operator(
     elif transpose != "ref":
         raise ValueError(f"transpose must be 'ref' or 'device'; "
                          f"got {transpose!r}")
-    return DeviceOperator(dev, t_dev=t_dev, backend=backend)
+    return _built(DeviceOperator(dev, t_dev=t_dev, backend=backend))
 
 
+@obs.span("repro.operator.build")
 def dist_operator(
     m: Union[F.CSRMatrix, D.DistPJDS],
     mesh,
@@ -609,8 +625,9 @@ def dist_operator(
         if halo == "auto":
             halo = PM.choose_halo(m, mode=mode,
                                   value_bytes=m.loc_val.dtype.itemsize)
-        return DistOperator(D.place_on_mesh(m, mesh, axis), mesh, axis=axis,
-                            mode=mode, backend=backend, halo=halo)
+        return _built(DistOperator(D.place_on_mesh(m, mesh, axis), mesh,
+                                   axis=axis, mode=mode, backend=backend,
+                                   halo=halo))
     n_dev = mesh.shape[axis]
     if tune not in ("off", "auto", "force"):
         raise ValueError(f"tune must be 'off', 'auto' or 'force'; "
@@ -658,32 +675,35 @@ def dist_operator(
                                index_dtype=index_dtype, rem_chunk_l=rclb,
                                grid=g, build_stages=build_stages)
 
-    if grid == "auto":
-        # No measured sweep available: price every grid shape with the
-        # (calibrated) perf model and keep the cheapest partition.
-        cands = [_build(m, g if g != (n_dev, 1) else None, cl, rcl, halo_w)
-                 for g in D.grid_shapes(n_dev)]
-        hs = ("gathered", "full") if halo == "auto" else (halo,)
-        cost = [min(PM.predicted_dist_spmv_seconds(
-                        d, halo=h, mode=mode,
-                        value_bytes=d.loc_val.dtype.itemsize)
-                    for h in hs) for d in cands]
-        dist = cands[int(np.argmin(cost))]
-    else:
-        dist = _build(m, grid, cl, rcl, halo_w)
-    if halo == "auto":
-        halo = PM.choose_halo(dist, mode=mode,
-                              value_bytes=dist.loc_val.dtype.itemsize)
+    with obs.span("repro.convert"):
+        if grid == "auto":
+            # No measured sweep available: price every grid shape with
+            # the (calibrated) perf model and keep the cheapest partition.
+            cands = [_build(m, g if g != (n_dev, 1) else None, cl, rcl,
+                            halo_w)
+                     for g in D.grid_shapes(n_dev)]
+            hs = ("gathered", "full") if halo == "auto" else (halo,)
+            cost = [min(PM.predicted_dist_spmv_seconds(
+                            d, halo=h, mode=mode,
+                            value_bytes=d.loc_val.dtype.itemsize)
+                        for h in hs) for d in cands]
+            dist = cands[int(np.argmin(cost))]
+        else:
+            dist = _build(m, grid, cl, rcl, halo_w)
+        if halo == "auto":
+            halo = PM.choose_halo(dist, mode=mode,
+                                  value_bytes=dist.loc_val.dtype.itemsize)
 
-    t_dist = None
-    if transpose == "device":
-        mt = F.csr_transpose(m)
-        cl_t, rcl_t, _ = _chunks(mt)
-        g = dist.grid
-        t_dist = _build(mt, (g[1], g[0]) if g else None, cl_t, rcl_t, None)
-    elif transpose is not None:
-        raise ValueError(f"transpose must be 'device' or None; "
-                         f"got {transpose!r}")
+        t_dist = None
+        if transpose == "device":
+            mt = F.csr_transpose(m)
+            cl_t, rcl_t, _ = _chunks(mt)
+            g = dist.grid
+            t_dist = _build(mt, (g[1], g[0]) if g else None, cl_t, rcl_t,
+                            None)
+        elif transpose is not None:
+            raise ValueError(f"transpose must be 'device' or None; "
+                             f"got {transpose!r}")
     dg = np.zeros(dist.n_global_pad, dtype=m.data.dtype)
     dg[: m.n_rows] = diag_host
     pre_perm = pre_inv = None
@@ -695,8 +715,10 @@ def dist_operator(
             np.concatenate([perm_host, tail]).astype(np.int32))
         pre_inv = jnp.asarray(
             np.concatenate([inv_host, tail]).astype(np.int32))
-    dist, t_dist, diag = D.place_on_mesh((dist, t_dist, jnp.asarray(dg)),
-                                         mesh, axis)
-    return DistOperator(dist, mesh, t_dist=t_dist, diag=diag,
-                        axis=axis, mode=mode, backend=backend, halo=halo,
-                        pre_perm=pre_perm, pre_inv=pre_inv)
+    with obs.span("repro.transfer"):
+        dist, t_dist, diag = obs.settled(D.place_on_mesh(
+            (dist, t_dist, jnp.asarray(dg)), mesh, axis))
+    return _built(DistOperator(dist, mesh, t_dist=t_dist, diag=diag,
+                               axis=axis, mode=mode, backend=backend,
+                               halo=halo, pre_perm=pre_perm,
+                               pre_inv=pre_inv))

@@ -85,9 +85,11 @@ def gather_rhs(col_idx: jax.Array, x: jax.Array) -> jax.Array:
     shape (n_cols, k) gathers into ``(k,) + col_idx.shape``, RHS columns
     leading, so each column keeps the matrix's tile layout.  Padding
     slots hold ``formats.PAD_COL`` (column 0), so they gather x[0] and
-    meet a zero value in the kernel."""
-    idx = col_idx.astype(jnp.int32)
-    return x[idx] if x.ndim == 1 else x.T[:, idx]
+    meet a zero value in the kernel.  Runs under the device scope
+    ``repro.gather_rhs``, the index cast included."""
+    with jax.named_scope("repro.gather_rhs"):
+        idx = col_idx.astype(jnp.int32)
+        return x[idx] if x.ndim == 1 else x.T[:, idx]
 
 
 def group_max_chunks(chunk_map) -> int:
@@ -132,7 +134,9 @@ def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
     tile and skip compute.  ``max_chunks`` is the static group ceiling
     (:func:`group_max_chunks`); None falls back to the total chunk count.
     Returns y: ``([n_rhs,] n_blocks * b_r)`` in storage row order, dtype
-    ``dt``.
+    ``dt``.  The ``pallas_call`` and its output slice run under the
+    device scope ``repro.kernel``; the grid extents stay outside it,
+    as the ELLPACK-R grid's preparation does.
     """
     total, b_r = streams[0].shape
     if total % chunk_l:
@@ -172,16 +176,17 @@ def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
         out_specs=pl.BlockSpec(lead + (OUT_BLOCKS, b_r),
                                lambda g, c, s, n, sl: pre + (g, 0)),
     )
-    y = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(lead + (n_groups * OUT_BLOCKS, b_r),
-                                       dt),
-        compiler_params=compiler_params(),
-        interpret=resolve_interpret(interpret),
-        name=name,
-    )(start, cnt, slot, *streams)
-    return y.reshape(lead + (-1,))[..., : n_blocks * b_r]
+    with jax.named_scope("repro.kernel"):
+        y = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                lead + (n_groups * OUT_BLOCKS, b_r), dt),
+            compiler_params=compiler_params(),
+            interpret=resolve_interpret(interpret),
+            name=name,
+        )(start, cnt, slot, *streams)
+        return y.reshape(lead + (-1,))[..., : n_blocks * b_r]
 
 
 def row_sum(dt):

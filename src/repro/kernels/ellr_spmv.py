@@ -106,12 +106,15 @@ def ell_matvec_kernel_call(
         out_specs=pl.BlockSpec((OUT_BLOCKS, tile_r),
                                lambda g, s, j, tc: (g, 0)),
     )
-    y = pl.pallas_call(
-        _ellr_spmv_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_groups * OUT_BLOCKS, tile_r), dt),
-        compiler_params=compiler_params(),
-        interpret=resolve_interpret(interpret),
-        name="ellr_spmv",
-    )(tile_chunks, val, gather_rhs(col_idx, x))
-    return y.reshape(-1)[:n_pad]
+    xg = gather_rhs(col_idx, x)
+    with jax.named_scope("repro.kernel"):
+        y = pl.pallas_call(
+            _ellr_spmv_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_groups * OUT_BLOCKS, tile_r),
+                                           dt),
+            compiler_params=compiler_params(),
+            interpret=resolve_interpret(interpret),
+            name="ellr_spmv",
+        )(tile_chunks, val, xg)
+        return y.reshape(-1)[:n_pad]

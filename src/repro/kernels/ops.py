@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import formats as F
 from repro.core import perf_model as PM
 from . import ref as R
@@ -353,10 +354,12 @@ def sell_matvec(a: SELLDevice, x: jax.Array,
     entries.  The kernel path is the pJDS kernel over the SELL chunks
     (same storage layout) followed by the window-local unpermute."""
     if resolve_backend(backend) == "kernel":
-        return pjds_matvec_kernel_call(
+        y = pjds_matvec_kernel_call(
             a.val, a.col_idx, a.chunk_map, x,
             n_blocks=a.n_blocks, chunk_l=a.chunk_l, max_chunks=a.max_chunks,
-        )[a.inv_perm]
+        )
+        with jax.named_scope("repro.unpermute"):
+            return y[a.inv_perm]
     return R.sell_matvec_ref(a.val, a.col_idx, a.row_block, a.inv_perm, x,
                              a.n_blocks)
 
@@ -463,6 +466,21 @@ def select_format(
     return min(candidates, key=candidates.get)
 
 
+def sandwich(pre_perm, pre_inv, apply, v):
+    """``apply`` in a reordered basis: ``v`` gathered into it by
+    ``pre_perm``, the result back out by ``pre_inv``, both under the
+    device scope ``repro.unpermute``; ``apply(v)`` where there is no
+    permutation.  B = P A P^T is symmetric-permuted, so A^T wears the
+    same sandwich as A."""
+    if pre_perm is None:
+        return apply(v)
+    with jax.named_scope("repro.unpermute"):
+        v = v[pre_perm]
+    y = apply(v)
+    with jax.named_scope("repro.unpermute"):
+        return y[pre_inv]
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class SparseDevice:
@@ -514,12 +532,8 @@ class SparseDevice:
         if x.ndim == 2:
             return self.matmat(x, backend)
         self._check_cols(x)
-        if self.pre_perm is not None:
-            x = x[self.pre_perm]
-        y = self._matvec_stored(x, backend)
-        if self.pre_inv is not None:
-            y = y[self.pre_inv]
-        return y
+        return sandwich(self.pre_perm, self.pre_inv,
+                        lambda v: self._matvec_stored(v, backend), x)
 
     def _matvec_stored(self, x: jax.Array, backend: str) -> jax.Array:
         if self.fmt == "csr":
@@ -530,7 +544,8 @@ class SparseDevice:
             return sell_matvec(self.dev, x, backend)[: self.n_rows]
         if self.fmt == "pjds":
             y_p = pjds_matvec(self.dev, x, backend)
-            return y_p[self.inv_perm][: self.n_rows]
+            with jax.named_scope("repro.unpermute"):
+                return y_p[self.inv_perm][: self.n_rows]
         if self.fmt == "cmrs":
             return cmrs_matvec(self.dev, x, backend)[: self.n_rows]
         raise ValueError(f"unknown format {self.fmt!r}")
@@ -546,12 +561,8 @@ class SparseDevice:
         """
         backend = resolve_backend(backend)
         self._check_cols(x)
-        if self.pre_perm is not None:
-            x = x[self.pre_perm]
-        y = self._matmat_stored(x, backend)
-        if self.pre_inv is not None:
-            y = y[self.pre_inv]
-        return y
+        return sandwich(self.pre_perm, self.pre_inv,
+                        lambda v: self._matmat_stored(v, backend), x)
 
     def _matmat_stored(self, x: jax.Array, backend: str) -> jax.Array:
         if self.fmt == "csr":
@@ -568,7 +579,8 @@ class SparseDevice:
                 chunk_l=d.chunk_l, max_chunks=d.max_chunks)
             y_p = pjds_matmat(a, x, backend)
             inv = d.inv_perm if self.fmt == "sell" else self.inv_perm
-            return y_p[inv][: self.n_rows]
+            with jax.named_scope("repro.unpermute"):
+                return y_p[inv][: self.n_rows]
         if self.fmt == "cmrs":
             d = self.dev
             return R.cmrs_matvec_ref(d.val, d.col_idx, d.row_in_strip,
@@ -592,14 +604,8 @@ class SparseDevice:
         """X = A^T Y, original basis: (shape[0][, k]) -> (shape[1][, k])."""
         del backend    # scatter path only; see operator(transpose="device")
         self._check_rows(y)
-        # A^T = P^T B^T P, so the transpose wears the SAME sandwich as
-        # the forward (B = P A P^T is symmetric-permuted).
-        if self.pre_perm is not None:
-            y = y[self.pre_perm]
-        z = self._rmatmat_stored(y)
-        if self.pre_inv is not None:
-            z = z[self.pre_inv]
-        return z
+        return sandwich(self.pre_perm, self.pre_inv,
+                        self._rmatmat_stored, y)
 
     def _rmatmat_stored(self, y: jax.Array) -> jax.Array:
         n_cols = self.shape[1]
@@ -648,7 +654,9 @@ class SparseDevice:
             raise ValueError(
                 f"y has {y.shape[0]} entries; matrix has {self.shape[0]} rows")
 
-    def storage_elements(self) -> int:
+    @property
+    def stored_slots(self) -> int:
+        """Value slots the apply streams, padding included."""
         if self.fmt == "csr":
             return int(self.dev.data.size)
         return int(self.dev.val.size)
@@ -840,37 +848,44 @@ def as_device(
     # the selection pricing sees the same padding the builders produce.
     da = max(diag_align, chunk_l)
 
-    fmt = format
-    if fmt == "auto":
-        fmt = select_format(a, b_r=b_r, diag_align=da, sigma=sigma,
-                            value_dtype=dtype, index_dtype=index_dtype)
+    with obs.span("repro.convert"):
+        fmt = format
+        if fmt == "auto":
+            fmt = select_format(a, b_r=b_r, diag_align=da, sigma=sigma,
+                                value_dtype=dtype, index_dtype=index_dtype)
+        if fmt == "csr":
+            stored = a
+        elif fmt == "ellpack_r":
+            stored = F.csr_to_ell(a, row_align=ell_tile(b_r), diag_align=da,
+                                  index_dtype=index_dtype)
+        elif fmt == "sell":
+            stored = F.csr_to_sell(a, c=b_r, sigma=sigma, diag_align=da,
+                                   permuted_cols=False,
+                                   index_dtype=index_dtype)
+        elif fmt == "pjds":
+            stored = F.csr_to_pjds(a, b_r=b_r, diag_align=da,
+                                   permuted_cols=False,
+                                   index_dtype=index_dtype)
+        elif fmt == "cmrs":
+            stored = F.csr_to_cmrs(a, b_r=b_r, diag_align=da,
+                                   index_dtype=index_dtype)
+        else:
+            raise ValueError(f"unknown format {fmt!r}")
 
-    inv_perm = None
-    if fmt == "csr":
-        dev = to_device_csr(a, dtype=dtype)
-    elif fmt == "ellpack_r":
-        e = F.csr_to_ell(a, row_align=ell_tile(b_r), diag_align=da,
-                         index_dtype=index_dtype)
-        dev = to_device_ell(e, chunk_l=chunk_l, tile_r=ell_tile(b_r),
-                            dtype=dtype)
-    elif fmt == "sell":
-        s = F.csr_to_sell(a, c=b_r, sigma=sigma, diag_align=da,
-                          permuted_cols=False, index_dtype=index_dtype)
-        dev = to_device_sell(s, chunk_l=chunk_l, dtype=dtype)
-    elif fmt == "pjds":
-        p = F.csr_to_pjds(a, b_r=b_r, diag_align=da, permuted_cols=False,
-                          index_dtype=index_dtype)
-        dev = to_device_pjds(p, chunk_l=chunk_l, dtype=dtype)
-        inv_perm = jnp.asarray(p.inv_perm)
-    elif fmt == "cmrs":
-        c = F.csr_to_cmrs(a, b_r=b_r, diag_align=da,
-                          index_dtype=index_dtype)
-        dev = to_device_cmrs(c, chunk_l=chunk_l, dtype=dtype)
-    else:
-        raise ValueError(f"unknown format {fmt!r}")
-
-    sd = SparseDevice(fmt=fmt, shape=a.shape, dev=dev, inv_perm=inv_perm,
-                      pre_perm=pre_perm, pre_inv=pre_inv)
+    with obs.span("repro.transfer"):
+        if fmt == "csr":
+            dev = to_device_csr(stored, dtype=dtype)
+        elif fmt == "ellpack_r":
+            dev = to_device_ell(stored, chunk_l=chunk_l,
+                                tile_r=ell_tile(b_r), dtype=dtype)
+        else:
+            to_device = {"sell": to_device_sell, "pjds": to_device_pjds,
+                         "cmrs": to_device_cmrs}[fmt]
+            dev = to_device(stored, chunk_l=chunk_l, dtype=dtype)
+        inv_perm = jnp.asarray(stored.inv_perm) if fmt == "pjds" else None
+        sd = obs.settled(SparseDevice(
+            fmt=fmt, shape=a.shape, dev=dev, inv_perm=inv_perm,
+            pre_perm=pre_perm, pre_inv=pre_inv))
     _cache_put(key, a_orig, sd)
     return sd
 
