@@ -58,6 +58,12 @@ __all__ = [
     "ell_to_dense",
     "pjds_to_dense",
     "sell_to_dense",
+    "WindowedSELLMatrix",
+    "WindowPlan",
+    "window_plan",
+    "csr_to_wsell",
+    "wsell_to_dense",
+    "WINDOW_UNIT",
     "cmrs_to_dense",
     "format_nbytes",
     "storage_elements",
@@ -600,6 +606,274 @@ def sell_to_dense(s: SELLMatrix) -> np.ndarray:
 
 
 # --------------------------------------------------------------------------
+# Windowed SELL-C-sigma: in-window columns as window-local offsets
+# --------------------------------------------------------------------------
+# x entries per step of a window's start: 8 sublane rows of 128 lanes,
+# the tile the TPU compiler must be able to prove a window start aligned
+# to (DESIGN.md §2).
+WINDOW_UNIT = 1024
+# Widest window, in units: the kernel gathers a (chunk_l, 128) tile with
+# one lane gather and select per 128-entry row of the window, 8 per
+# unit, so past 4 units (32 rows, 4096 entries) the in-tile gather costs
+# more than the kernel's grid step itself.
+WINDOW_UNITS_MAX = 4
+# A window one unit wider must serve at least this share of the
+# non-zeros more, or the narrower window is kept.
+WINDOW_SHARE_TOL = 1e-3
+
+
+@dataclasses.dataclass
+class WindowPlan:
+    """Where each sigma-window's x window lies, and which non-zeros it
+    serves (:func:`window_plan`).  Columns ascend within a CSR row, so a
+    row's in-window non-zeros are one run, ``[row_lo, row_lo + rowlen)``
+    in the CSR arrays."""
+
+    units: int              # window width in WINDOW_UNITs
+    start: np.ndarray       # (n_sigma_windows,) int64 window start, units
+    row_lo: np.ndarray      # (n_rows,) int64 first in-window non-zero
+    rowlen: np.ndarray      # (n_rows,) int64 in-window non-zeros per row
+    nnz: int
+    x_len: int              # x padded to whole units, at least one window
+
+    @property
+    def window(self) -> int:
+        """Window width in x entries."""
+        return self.units * WINDOW_UNIT
+
+    @property
+    def share(self) -> float:
+        """Share of the non-zeros served from a window."""
+        return float(self.rowlen.sum()) / self.nnz if self.nnz else 0.0
+
+
+def window_plan(m: CSRMatrix, sigma: int) -> WindowPlan:
+    """Give every sigma-window of rows one x window: ``units`` aligned
+    WINDOW_UNITs, the same width for the whole matrix, placed where it
+    covers most of the window's non-zeros.
+
+    The width is the smallest past which one more unit serves less than
+    :data:`WINDOW_SHARE_TOL` more of the non-zeros, at most
+    :data:`WINDOW_UNITS_MAX`.  Each window is anchored on the unit that
+    holds its middle row and may start up to ``units - 1`` units before
+    it, clamped to x; one histogram of the non-zeros' unit offsets from
+    that anchor prices every width and start.  The non-zeros are counted
+    in runs of one row and one unit (columns ascend within a row), so
+    the per-non-zero work is a few vectorised passes."""
+    n_rows, n_cols = m.shape
+    u_max = WINDOW_UNITS_MAX
+    n_win = max(-(-n_rows // sigma), 1)
+    x_units = max(-(-n_cols // WINDOW_UNIT), 1)
+    x_len = lambda u: max(x_units, u) * WINDOW_UNIT        # noqa: E731
+    nnz = m.nnz
+    if nnz == 0:
+        zero = np.zeros(n_rows, np.int64)
+        return WindowPlan(units=1, start=np.zeros(n_win, np.int64),
+                          row_lo=zero, rowlen=zero, nnz=0, x_len=x_len(1))
+    unit = m.indices // WINDOW_UNIT
+    rl = np.diff(m.indptr)
+    run0 = np.union1d(np.flatnonzero(unit[1:] != unit[:-1]) + 1,
+                      m.indptr[:-1][rl > 0])
+    run_len = np.diff(np.append(run0, nnz))
+    run_row = np.searchsorted(m.indptr, run0, side="right") - 1
+    run_win = run_row // sigma
+    anchor = np.minimum((np.arange(n_win, dtype=np.int64) * sigma
+                         + sigma // 2) // WINDOW_UNIT, x_units - 1)
+    # bin u_max + (unit - anchor) for unit offsets within +-(u_max - 1),
+    # bins 0 and 2 * u_max for those further below and above
+    nb = 2 * u_max + 1
+    rel = np.clip(unit[run0] - anchor[run_win] + u_max, 0, nb - 1)
+    hist = np.zeros(n_win * nb, np.int64)
+    np.add.at(hist, run_win * nb + rel, run_len)
+    cum = np.zeros((n_win, nb + 1), np.int64)
+    np.cumsum(hist.reshape(n_win, nb), axis=1, out=cum[:, 1:])
+    wins = np.arange(n_win)
+    best = []
+    for u in range(1, u_max + 1):
+        top = max(x_units, u) - u
+        served = np.full(n_win, -1, np.int64)
+        start = np.zeros(n_win, np.int64)
+        for d in range(1 - u, 1):
+            s = np.clip(anchor + d, 0, top)
+            a = s - anchor + u_max
+            got = cum[wins, a + u] - cum[wins, a]
+            better = got > served
+            served = np.where(better, got, served)
+            start = np.where(better, s, start)
+        best.append((u, start, int(served.sum())))
+    pick = best[-1]
+    for (u, start, got), (_, _, wider) in zip(best, best[1:]):
+        if (wider - got) / nnz < WINDOW_SHARE_TOL:
+            pick = (u, start, got)
+            break
+    u, start, _ = pick
+    lo = (start - anchor + u_max)[run_win]
+    inside = (rel >= lo) & (rel < lo + u)
+    rowlen = np.zeros(n_rows, np.int64)
+    np.add.at(rowlen, run_row[inside], run_len[inside])
+    # a row's in-window runs are consecutive: its run ends where the last
+    # of them ends
+    row_hi = m.indptr[:-1].astype(np.int64)
+    row_hi[run_row[inside]] = run0[inside] + run_len[inside]
+    return WindowPlan(units=u, start=start, row_lo=row_hi - rowlen,
+                      rowlen=rowlen, nnz=nnz, x_len=x_len(u))
+
+
+@dataclasses.dataclass
+class WindowedSELLMatrix:
+    """SELL-C-sigma whose row blocks each read one window of x.
+
+    Rows are sorted by their IN-WINDOW length inside sigma-windows and
+    blocked as in :class:`PJDSMatrix` (``(total, b_r)`` chunks, rows on
+    lanes), with ``permuted_cols=False``.  Every row block reads the
+    x window of its sigma-window: ``window`` entries from
+    ``wbase[b] * WINDOW_UNIT``.  A stored slot keeps its column as an
+    int16 offset into that window (``col_off``); padding slots store
+    offset 0 and value 0, as the ``PAD_COL`` contract has it.  Non-zeros
+    outside their block's window are not stored in the slots: they form
+    the remainder, ``(rem_row, rem_col, rem_val)`` with ``rem_row`` the
+    storage (sorted) row position, sorted by row, no padding.
+    """
+
+    val: np.ndarray         # (total, b_r)
+    col_off: np.ndarray     # (total, b_r) int16, window-local
+    block_start: np.ndarray # (n_blocks + 1,) int32
+    block_len: np.ndarray   # (n_blocks,) int32
+    wbase: np.ndarray       # (n_blocks,) int32, window start in units
+    rowlen: np.ndarray      # (n_rows_pad,) int32 in-window, sorted order
+    perm: np.ndarray        # (n_rows_pad,) int32
+    inv_perm: np.ndarray    # (n_rows_pad,) int32
+    rem_row: np.ndarray     # (n_rem,) int32, storage row, non-decreasing
+    rem_col: np.ndarray     # (n_rem,) global column
+    rem_val: np.ndarray     # (n_rem,)
+    shape: Tuple[int, int]
+    b_r: int
+    n_rows_pad: int
+    sigma: int
+    window: int             # x entries per window
+    x_len: int              # x padded to this length before the apply
+
+    @property
+    def n_blocks(self) -> int:
+        return len(self.block_len)
+
+    @property
+    def total_jds(self) -> int:
+        return self.val.shape[0]
+
+    @property
+    def window_share(self) -> float:
+        """Share of the stored non-zeros served from a window."""
+        nnz = int(self.rowlen.sum()) + len(self.rem_row)
+        return int(self.rowlen.sum()) / nnz if nnz else 0.0
+
+
+def csr_to_wsell(
+    m: CSRMatrix,
+    c: int = _DEFAULT_BR,
+    sigma: int | None = None,
+    diag_align: int = _DEFAULT_DIAG_ALIGN,
+    index_dtype="auto",
+    plan: WindowPlan | None = None,
+) -> WindowedSELLMatrix:
+    """Windowed SELL-C-sigma from CSR (vectorised numpy).  ``sigma``
+    defaults to ``8 * c`` and must be a multiple of ``c``, so that every
+    row block lies in one sigma-window; ``plan`` (from
+    :func:`window_plan` with the same sigma) skips recomputing it.  The
+    window offsets are int16 whatever ``index_dtype``; ``index_dtype``
+    sets the remainder's global column dtype."""
+    if sigma is None:
+        sigma = 8 * c
+    if sigma % c:
+        raise ValueError(f"windowed SELL needs sigma ({sigma}) a multiple "
+                         f"of the block height c ({c})")
+    if plan is None:
+        plan = window_plan(m, sigma)
+    n_rows = m.n_rows
+    n_pad = _pad_to(max(n_rows, 1), c)
+    n_blocks = n_pad // c
+    rl = np.diff(m.indptr)
+    rl_in = np.zeros(n_pad, dtype=np.int64)
+    rl_in[:n_rows] = plan.rowlen
+    perm = windowed_sort_perm(rl_in, sigma)
+    inv_perm = np.empty_like(perm)
+    inv_perm[perm] = np.arange(n_pad, dtype=np.int32)
+    sorted_rl = rl_in[perm]
+    blk_max = np.maximum(sorted_rl.reshape(n_blocks, c).max(axis=1), 1)
+    block_len = (-(-blk_max // diag_align) * diag_align).astype(np.int32)
+    block_start = np.zeros(n_blocks + 1, dtype=np.int32)
+    np.cumsum(block_len, out=block_start[1:])
+    wbase = plan.start[np.arange(n_blocks) * c // sigma].astype(np.int32)
+
+    # in-window slots: the row's j-th in-window non-zero goes to jagged
+    # diagonal j of its block, on the lane of its sorted row position:
+    # flat slot row0 + j * c, row0 = block_start * c + lane.  Within a
+    # row the slot advances by c; a cumulative sum of those steps, with
+    # each row's first step jumping to its row0, lays out every row.
+    rl_row = plan.rowlen
+    p = inv_perm[:n_rows].astype(np.int64)
+    total = int(block_start[-1])
+    n_in = int(rl_row.sum())
+    idx = np.int32 if total * c < 2 ** 31 else np.int64
+    row0 = block_start[p // c].astype(np.int64) * c + p % c
+    has = np.flatnonzero(rl_row)
+    first = np.cumsum(rl_row) - rl_row          # in-window entries before
+    step = np.full(n_in, c, idx)
+    last = np.append(0, (row0 + (rl_row - 1) * c)[has[:-1]])
+    step[first[has]] = row0[has] - last
+    flat = np.cumsum(step, dtype=idx)
+    # +1 where a row's run starts, -1 after it ends: runs of different
+    # rows are disjoint, so no index gets two starts or two ends
+    edge = np.zeros(m.nnz + 1, np.int8)
+    edge[plan.row_lo[has]] += 1
+    edge[(plan.row_lo + rl_row)[has]] -= 1
+    inside = np.cumsum(edge[:-1], dtype=np.int8).view(bool)
+    val = np.zeros(total * c, dtype=m.data.dtype)
+    col_off = np.full(total * c, PAD_COL, dtype=np.int16)
+    val[flat] = m.data[inside]
+    col_off[flat] = m.indices[inside] - np.repeat(
+        (wbase[p // c] * WINDOW_UNIT).astype(np.int32), rl_row)
+
+    # the remainder: the rows' non-zeros before and after their run
+    part = np.flatnonzero(rl_row < rl)
+    cnt = rl[part]
+    e_row = np.repeat(part, cnt)
+    e = (np.repeat(m.indptr[part] - (np.cumsum(cnt) - cnt), cnt)
+         + np.arange(int(cnt.sum()), dtype=np.int64))
+    lo = plan.row_lo[e_row]
+    keep = (e < lo) | (e >= lo + rl_row[e_row])
+    out, rem_pos = e[keep], inv_perm[e_row[keep]]
+    order = np.argsort(rem_pos, kind="stable")   # CSR keeps columns sorted
+    idt = resolve_index_dtype(index_dtype, m.shape[1])
+    w = WindowedSELLMatrix(
+        val=val.reshape(total, c), col_off=col_off.reshape(total, c),
+        block_start=block_start, block_len=block_len, wbase=wbase,
+        rowlen=sorted_rl.astype(np.int32), perm=perm.astype(np.int32),
+        inv_perm=inv_perm.astype(np.int32),
+        rem_row=rem_pos[order].astype(np.int32),
+        rem_col=m.indices[out[order]].astype(idt),
+        rem_val=m.data[out[order]],
+        shape=m.shape, b_r=c, n_rows_pad=n_pad, sigma=sigma,
+        window=plan.window, x_len=plan.x_len)
+    if PAD_AUDIT:
+        assert_padding_invariant(w)
+    return w
+
+
+def wsell_to_dense(w: WindowedSELLMatrix) -> np.ndarray:
+    """Densify in the ORIGINAL basis: the slots at their window's
+    columns, plus the remainder."""
+    a = np.zeros(w.shape, dtype=w.val.dtype)
+    blk = np.repeat(np.arange(w.n_blocks), w.block_len)
+    pos = blk[:, None] * w.b_r + np.arange(w.b_r)[None, :]
+    cols = w.wbase[blk][:, None].astype(np.int64) * WINDOW_UNIT + w.col_off
+    keep = w.val != 0
+    np.add.at(a, (w.perm[pos[keep]], cols[keep]), w.val[keep])
+    np.add.at(a, (w.perm[w.rem_row], w.rem_col.astype(np.int64)), w.rem_val)
+    return a
+
+
+# --------------------------------------------------------------------------
 # CMRS — Compressed Multi-Row Storage (arXiv:1203.2946), TPU-blocked
 # --------------------------------------------------------------------------
 @dataclasses.dataclass
@@ -836,6 +1110,13 @@ def assert_padding_invariant(fmt) -> None:
             _check_pad(f"PJDSMatrix block {b}", fmt.val[s:e][pad],
                        fmt.col_idx[s:e][pad])
         return
+    if isinstance(fmt, WindowedSELLMatrix):
+        blk = np.repeat(np.arange(fmt.n_blocks), fmt.block_len)
+        j = np.arange(fmt.total_jds) - fmt.block_start[blk]
+        lane_rl = fmt.rowlen.reshape(fmt.n_blocks, fmt.b_r)[blk]
+        pad = j[:, None] >= lane_rl
+        _check_pad("WindowedSELLMatrix", fmt.val[pad], fmt.col_off[pad])
+        return
     if isinstance(fmt, CMRSMatrix):
         for s in range(fmt.n_strips):
             s0, su = int(fmt.strip_start[s]), int(fmt.strip_len[s])
@@ -871,6 +1152,8 @@ def storage_elements(fmt) -> int:
         return int(fmt.pjds.val.size)
     if isinstance(fmt, CMRSMatrix):
         return int(fmt.val.size)
+    if isinstance(fmt, WindowedSELLMatrix):
+        return int(fmt.val.size) + len(fmt.rem_val)
     raise TypeError(type(fmt))
 
 
@@ -884,6 +1167,15 @@ def format_nbytes(fmt, value_bytes: int | None = None,
     precision instead."""
     if isinstance(fmt, SELLMatrix):
         return format_nbytes(fmt.pjds, value_bytes, index_bytes)
+    if isinstance(fmt, WindowedSELLMatrix):
+        vb = fmt.val.dtype.itemsize if value_bytes is None else value_bytes
+        ib = fmt.col_off.dtype.itemsize if index_bytes is None \
+            else index_bytes
+        # slots + remainder (value, global column, row) + block offsets,
+        # window starts and the row permutation
+        return (fmt.val.size * (vb + ib)
+                + len(fmt.rem_val) * (vb + fmt.rem_col.dtype.itemsize + 4)
+                + (2 * fmt.n_blocks + 1) * 4 + fmt.n_rows_pad * 4)
     if value_bytes is None:
         value_bytes = (fmt.data if isinstance(fmt, CSRMatrix)
                        else fmt.val).dtype.itemsize

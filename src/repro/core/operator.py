@@ -231,6 +231,12 @@ class DeviceOperator(SparseOperator):
         return self.dev.stored_slots
 
     @property
+    def window_share(self) -> float:
+        """Share of the non-zeros served from a window of x inside the
+        kernel (``ops.SparseDevice.window_share``)."""
+        return self.dev.window_share
+
+    @property
     def values(self) -> jax.Array:
         """The stored value leaf (the differentiable parameters)."""
         d = self.dev.dev
@@ -320,6 +326,19 @@ def _device_diagonal_stored(sd: ops.SparseDevice) -> jax.Array:
         blk = jax.ops.segment_sum(keep, d.row_block,
                                   num_segments=int(n_pad // b_r))
         return blk.reshape(n_pad)[inv][:n]
+    if sd.fmt == "wsell":
+        n_pad = d.n_rows_pad
+        orig = jnp.zeros(n_pad, jnp.int32).at[d.inv_perm].set(
+            jnp.arange(n_pad, dtype=jnp.int32))
+        pos = d.row_block[:, None] * d.b_r + jnp.arange(d.b_r,
+                                                        dtype=jnp.int32)[None]
+        keep = jnp.where(d.columns() == orig[pos], d.slot_val, 0)
+        dg = jax.ops.segment_sum(keep, d.row_block,
+                                 num_segments=d.n_blocks).reshape(n_pad)
+        rem = jnp.where(d.rem_col.astype(jnp.int32) == orig[d.rem_row],
+                        d.rem_val, 0)
+        dg = dg + jax.ops.segment_sum(rem, d.rem_row, num_segments=n_pad)
+        return dg[d.inv_perm][:n]
     if sd.fmt == "cmrs":
         b_r = d.val.shape[1]
         rows = d.strip_map[:, None] * b_r + d.row_in_strip.astype(jnp.int32)
@@ -404,6 +423,11 @@ class DistOperator(SparseOperator):
         summed over the devices: the local and the remote operand."""
         return int(self.dist.loc_val.size + self.dist.rem_val.size)
 
+    @property
+    def window_share(self) -> float:
+        """The partition gathers every slot in XLA: no windows."""
+        return 0.0
+
     # -- application -------------------------------------------------------
     def _fwd(self, dist, multi_rhs):
         # Memoized per instance: the shard_map closure is built once per
@@ -478,9 +502,10 @@ class DistOperator(SparseOperator):
 # Factories
 # --------------------------------------------------------------------------
 def _built(op):
-    """``op``, with its stored slots noted (``obs.gauge``) as those of
-    the operator built last."""
+    """``op``, with its stored slots and its window share noted
+    (``obs.gauge``) as those of the operator built last."""
     obs.gauge("repro.stored_slots", op.stored_slots)
+    obs.gauge("repro.window_share", op.window_share)
     return op
 
 

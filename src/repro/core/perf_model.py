@@ -8,11 +8,17 @@ Paper (Fermi GPU)                    ->  here (TPU v5e target)
 Eq. (1): worst-case code balance of the ELLPACK/pJDS kernel,
     B_W^DP = (6 + 4*alpha + 8/N_nzr_max) bytes/flop
 with alpha in [1/N_nzr, 1] the RHS cache-reuse parameter.  On TPU the
-RHS is gathered ahead of the kernels, in XLA (the TPU compiler has no
-in-kernel 1-D gather): every stored slot reads x once and the kernel
-streams the gathered copy, so the program prices its RHS with
-:func:`gathered_rhs_bytes` and :func:`spmvm_bytes` stays the paper's
-minimum (DESIGN.md §2).
+RHS of most formats is gathered ahead of the kernels, in XLA (the TPU
+compiler has no in-kernel gather from a large array): every stored slot
+reads x once and the kernel streams the gathered copy, so the program
+prices its RHS with :func:`gathered_rhs_bytes` and :func:`spmvm_bytes`
+stays the paper's minimum (DESIGN.md §2).  That gather is bound by its
+element count, not its bytes: XLA:TPU's scalar gather reads one element
+in ``TPUSpec.gather_s`` seconds, so dispatch adds
+:func:`gather_seconds` for every slot a format gathers in XLA.  The
+windowed SELL-C-sigma format gathers in-window slots inside the kernel
+from a VMEM window of x (:func:`window_rhs_bytes`) and only its
+out-of-window remainder in XLA.
 
 Eq. (2)-(4): device-vs-link time model.  The paper derives the range of
 N_nzr for which accelerator spMVM is worthwhile given the ratio
@@ -52,6 +58,8 @@ __all__ = [
     "spmvm_flops",
     "spmvm_bytes",
     "gathered_rhs_bytes",
+    "gather_seconds",
+    "window_rhs_bytes",
     "perm_traffic_bytes",
     "SORTED_ROW_FORMATS",
     "CMRS_RIS_BYTES",
@@ -74,6 +82,7 @@ class TPUSpec:
     hbm_bw: float            # bytes/s per chip
     ici_bw: float            # bytes/s per link
     hbm_bytes: int
+    gather_s: float          # seconds per element of an XLA gather
 
 
 TPU_V5E = TPUSpec(
@@ -83,6 +92,9 @@ TPU_V5E = TPUSpec(
     hbm_bw=819e9,
     ici_bw=50e9,
     hbm_bytes=16 * 2 ** 30,
+    # ~116M gathered slots/s, the same in every format and for a 1.1 MB
+    # as for a 13.6 MB x (PERF.md §5, the RHS gather on one v5e chip)
+    gather_s=8.6e-9,
 )
 
 
@@ -328,9 +340,25 @@ def gathered_rhs_bytes(stored_elements: int, vec_bytes: int = 4) -> float:
     return 3.0 * float(stored_elements) * vec_bytes
 
 
+def gather_seconds(gathered_elements: int, spec: TPUSpec = TPU_V5E) -> float:
+    """Time of an XLA gather of ``gathered_elements`` scalars on
+    ``spec``: bound by the element count, not by HBM bytes, at
+    ``spec.gather_s`` per element (PERF.md §5)."""
+    return float(gathered_elements) * spec.gather_s
+
+
+def window_rhs_bytes(n_blocks: int, window: int, vec_bytes: int = 4) -> float:
+    """HBM traffic of the RHS on the windowed SELL-C-sigma path: the
+    kernel fetches one ``window``-entry slice of x per row block into
+    VMEM and gathers from it there (``kernels.wsell_spmv``), so no
+    gathered copy is written.  Consecutive blocks that share a window
+    skip the fetch; this prices one fetch per block, the most."""
+    return float(n_blocks) * window * vec_bytes
+
+
 # Formats whose kernel writes y in a sorted row order (pJDS globally,
 # SELL-C-sigma within sigma windows): y is unpermuted after the kernel.
-SORTED_ROW_FORMATS = ("pjds", "sell")
+SORTED_ROW_FORMATS = ("pjds", "sell", "wsell")
 
 
 def perm_traffic_bytes(n_rows: int, value_bytes: int = 4,
@@ -367,11 +395,17 @@ def predicted_spmv_seconds(stored_elements: int, n_rows: int, n_nzr: float,
                            index_bytes: int = 4,
                            vec_bytes: int | None = None,
                            fmt: str | None = None,
-                           calibration="default") -> float:
-    """Memory-bound time estimate of one spMVM in a candidate format —
-    the quantity ``kernels.ops.select_format`` minimises.  The RHS is
-    priced as the program moves it (:func:`gathered_rhs_bytes`: read
-    per stored slot, written and read back as the gathered stream);
+                           calibration="default",
+                           gathered: int = 0,
+                           rhs_bytes: float | None = None) -> float:
+    """Time estimate of one spMVM in a candidate format — the quantity
+    ``kernels.ops.select_format`` minimises.  The RHS is priced as the
+    program moves it: by default :func:`gathered_rhs_bytes` (read per
+    stored slot, written and read back as the gathered stream), or
+    ``rhs_bytes`` where the format moves it otherwise
+    (:func:`window_rhs_bytes`); ``gathered`` XLA gather elements add
+    :func:`gather_seconds`, which bounds a gathered format on the chip
+    (0, the default, prices bytes alone);
     ``irregular_factor`` derates formats without a blocked kernel (CSR's
     scalar gather stream cannot saturate HBM).  ``value_bytes`` /
     ``index_bytes`` are the STORED stream widths, ``vec_bytes`` the
@@ -384,11 +418,13 @@ def predicted_spmv_seconds(stored_elements: int, n_rows: int, n_nzr: float,
     data-sheet estimate)."""
     if vec_bytes is None:
         vec_bytes = max(4, value_bytes)
-    # alpha = 0: the gathered stream below carries every RHS read
+    if rhs_bytes is None:
+        rhs_bytes = gathered_rhs_bytes(stored_elements, vec_bytes)
+    # alpha = 0: rhs_bytes carries every RHS read
     b = (spmvm_bytes(stored_elements, n_rows, 0.0, n_nzr,
-                     value_bytes, index_bytes, vec_bytes)
-         + gathered_rhs_bytes(stored_elements, vec_bytes))
-    t = (b * irregular_factor + perm_bytes) / spec.hbm_bw
+                     value_bytes, index_bytes, vec_bytes) + rhs_bytes)
+    t = ((b * irregular_factor + perm_bytes) / spec.hbm_bw
+         + gather_seconds(gathered, spec))
     if calibration == "default":
         calibration = _CALIBRATION
     if calibration is not None:

@@ -9,11 +9,15 @@ Two rules of the TPU compiler shape every kernel here (DESIGN.md §2b):
 
 * **No gather from a large VMEM array.**  Mosaic lowers a gather only
   between 2-D arrays of one shape (a shuffle inside a tile), so
-  ``x[col_idx]`` cannot run inside a kernel.  :func:`gather_rhs` runs it
-  ahead of the kernel in XLA instead, and the kernels stream the
-  gathered operand ``xg`` tile for tile beside ``val``.  That costs one
-  write and one read of ``xg`` (the RHS width per stored slot) on top of
-  the value and index streams.
+  ``x[col_idx]`` over the whole of x cannot run inside a kernel.
+  :func:`gather_rhs` runs it ahead of the kernel in XLA instead, and the
+  kernels stream the gathered operand ``xg`` tile for tile beside
+  ``val``.  That costs one write and one read of ``xg`` (the RHS width
+  per stored slot) on top of the value and index streams, and XLA's
+  scalar gather is slow per element.  The windowed SELL-C-sigma kernel
+  is the exception: it fetches a short window of x per row block and
+  gathers from it with in-tile shuffles (``window=`` below,
+  ``wsell_spmv.py``).
 * **Blocks are multiples of (8, 128) or whole arrays.**  One row block's
   output is a single ``(1, b_r)`` row, so the blocked kernels write
   groups of :data:`OUT_BLOCKS` row blocks: the grid walks every chunk of
@@ -29,12 +33,17 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.formats import WINDOW_UNIT
+
 __all__ = ["resolve_interpret", "acc_dtype", "chunk_clamp", "gather_rhs",
-           "OUT_BLOCKS", "VMEM_LIMIT_BYTES", "compiler_params",
+           "OUT_BLOCKS", "LANES", "VMEM_LIMIT_BYTES", "compiler_params",
            "group_max_chunks", "grouped_matvec_call", "row_sum"]
 
 # Row blocks per kernel output block: the sublane height of one f32 tile.
 OUT_BLOCKS = 8
+
+# Lanes of one vector register: the width of a row of x's window.
+LANES = 128
 
 # Scoped VMEM the kernels may use.  A step holds a few (chunk_l, b_r)
 # tiles and one output block, double-buffered: well under a MiB for
@@ -118,7 +127,8 @@ def _group_extents(chunk_map: jax.Array, n_groups: int):
 
 def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
                         chunk_l: int, max_chunks: int | None, dt,
-                        interpret: bool | None, name: str) -> jax.Array:
+                        interpret: bool | None, name: str,
+                        window=None) -> jax.Array:
     """The grouped Pallas grid shared by the pJDS/SELL, CMRS and pJDS
     multi-RHS kernels.
 
@@ -137,6 +147,15 @@ def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
     ``dt``.  The ``pallas_call`` and its output slice run under the
     device scope ``repro.kernel``; the grid extents stay outside it,
     as the ELLPACK-R grid's preparation does.
+
+    ``window``, for the windowed SELL-C-sigma kernel, is ``(wbase, xw,
+    wrows)``: each row block's window start in ``formats.WINDOW_UNIT``s,
+    x viewed as ``(rows, LANES)``, and the static window height in rows.
+    ``wbase`` is scalar-prefetched beside the extents, and the chunk's
+    block's ``(wrows, LANES)`` window of ``xw`` rides as one more input,
+    fetched by element offset and passed to ``reduce_rows`` after the
+    tiles.  Pallas skips the fetch while consecutive steps share a
+    window.
     """
     total, b_r = streams[0].shape
     if total % chunk_l:
@@ -147,8 +166,10 @@ def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
     n_groups = max(-(-n_blocks // OUT_BLOCKS), 1)
     start, cnt, slot = _group_extents(chunk_map, n_groups)
 
+    n_pre = 3 if window is None else 4
+
     def kernel(start_ref, cnt_ref, slot_ref, *refs):
-        *in_refs, y_ref = refs
+        *in_refs, y_ref = refs[n_pre - 3:]
         g = pl.program_id(0)
         c = pl.program_id(1)
 
@@ -166,15 +187,31 @@ def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
         pre = (0,) * (a.ndim - 2)
         return pl.BlockSpec(
             a.shape[:-2] + (chunk_l, b_r),
-            lambda g, c, s, n, sl: pre + (s[g] + chunk_clamp(c, n[g]), 0))
+            lambda g, c, s, n, sl, *_: pre + (s[g] + chunk_clamp(c, n[g]),
+                                              0))
 
+    prefetch = [start, cnt, slot]
+    in_specs = [spec(a) for a in streams]
+    inputs = list(streams)
+    if window is not None:
+        wbase, xw, wrows = window
+
+        def window_at(g, c, s, n, sl, wb):
+            blk = g * OUT_BLOCKS + sl[s[g] + chunk_clamp(c, n[g])]
+            # the product lets the compiler prove the start aligned
+            return wb[blk] * (WINDOW_UNIT // LANES), 0
+
+        prefetch.append(wbase)
+        in_specs.append(pl.BlockSpec(
+            (pl.Element(wrows), pl.Element(LANES)), window_at))
+        inputs.append(xw)
     pre = (0,) * len(lead)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=n_pre,
         grid=(n_groups, n_chunks if max_chunks is None else max_chunks),
-        in_specs=[spec(a) for a in streams],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec(lead + (OUT_BLOCKS, b_r),
-                               lambda g, c, s, n, sl: pre + (g, 0)),
+                               lambda g, c, s, n, sl, *_: pre + (g, 0)),
     )
     with jax.named_scope("repro.kernel"):
         y = pl.pallas_call(
@@ -185,7 +222,7 @@ def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
             compiler_params=compiler_params(),
             interpret=resolve_interpret(interpret),
             name=name,
-        )(start, cnt, slot, *streams)
+        )(*prefetch, *inputs)
         return y.reshape(lead + (-1,))[..., : n_blocks * b_r]
 
 

@@ -4,11 +4,13 @@ dispatch layer.
 Two levels of API live here:
 
 * **Per-format containers and matvecs** — ``to_device_pjds`` /
-  ``to_device_ell`` / ``to_device_sell`` / ``to_device_csr`` move a
+  ``to_device_ell`` / ``to_device_sell`` / ``to_device_wsell`` /
+  ``to_device_csr`` move a
   host-side format (``repro.core.formats``) onto the device with the
   kernel-side metadata (chunk maps, tile chunk counts, window inverse
   permutations) precomputed; ``pjds_matvec`` / ``ell_matvec`` /
-  ``sell_matvec`` / ``csr_matvec`` / ``pjds_matmat`` dispatch to either
+  ``sell_matvec`` / ``wsell_matvec`` / ``csr_matvec`` / ``pjds_matmat``
+  dispatch to either
   the Pallas kernel (``backend='kernel'``, interpret-mode on CPU) or the
   pure-jnp oracle (``backend='ref'``, fast on CPU and used inside the
   distributed layer).
@@ -38,16 +40,18 @@ from repro import obs
 from repro.core import formats as F
 from repro.core import perf_model as PM
 from . import ref as R
-from ._backend import group_max_chunks, resolve_interpret
+from ._backend import LANES, group_max_chunks, resolve_interpret
 from .pjds_spmv import pjds_matvec_kernel_call
 from .pjds_spmm import pjds_matmat_kernel_call
 from .ellr_spmv import ell_matvec_kernel_call
 from .cmrs_spmv import cmrs_matvec_kernel_call
+from .wsell_spmv import wsell_matvec_kernel_call
 
 __all__ = [
     "PJDSDevice",
     "ELLDevice",
     "SELLDevice",
+    "WSELLDevice",
     "CSRDevice",
     "CMRSDevice",
     "SparseDevice",
@@ -55,12 +59,14 @@ __all__ = [
     "to_device_ell",
     "ell_tile",
     "to_device_sell",
+    "to_device_wsell",
     "to_device_csr",
     "to_device_cmrs",
     "pjds_matvec",
     "pjds_matmat",
     "ell_matvec",
     "sell_matvec",
+    "wsell_matvec",
     "csr_matvec",
     "cmrs_matvec",
     "select_format",
@@ -72,7 +78,8 @@ __all__ = [
 ]
 
 Backend = Literal["auto", "kernel", "ref"]
-FormatName = Literal["auto", "csr", "ellpack_r", "pjds", "sell", "cmrs"]
+FormatName = Literal["auto", "csr", "ellpack_r", "pjds", "sell", "wsell",
+                     "cmrs"]
 Tune = Literal["off", "auto", "force"]
 
 
@@ -157,6 +164,55 @@ class SELLDevice:
     @property
     def n_rows_pad(self) -> int:
         return self.n_blocks * self.b_r
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class WSELLDevice:
+    """Device-resident windowed SELL-C-sigma operand
+    (``formats.WindowedSELLMatrix``): the SELL chunk layout with int16
+    window-local column offsets, each row block's window start, and the
+    out-of-window remainder.  ``val`` holds the slots' values in its
+    first ``col_off.shape[0]`` rows and the remainder's values after
+    them, row-major and zero-padded, so that one value leaf carries
+    every stored value (``DeviceOperator.values``)."""
+
+    val: jax.Array                     # (total + tail, b_r)
+    col_off: jax.Array                 # (total, b_r) int16, window-local
+    chunk_map: jax.Array               # (total // chunk_l,) int32
+    row_block: jax.Array               # (total,) int32 (for the ref)
+    wbase: jax.Array                   # (n_blocks,) int32, WINDOW_UNITs
+    inv_perm: jax.Array                # (n_blocks * b_r,) int32
+    rem_row: jax.Array                 # (n_rem,) int32 storage row, sorted
+    rem_col: jax.Array                 # (n_rem,) global column
+    n_blocks: int = dataclasses.field(metadata=dict(static=True))
+    b_r: int = dataclasses.field(metadata=dict(static=True))
+    chunk_l: int = dataclasses.field(metadata=dict(static=True))
+    sigma: int = dataclasses.field(metadata=dict(static=True))
+    window: int = dataclasses.field(metadata=dict(static=True))
+    x_len: int = dataclasses.field(metadata=dict(static=True))
+    window_share: float = dataclasses.field(metadata=dict(static=True))
+    max_chunks: Optional[int] = dataclasses.field(
+        default=None, metadata=dict(static=True))
+
+    @property
+    def n_rows_pad(self) -> int:
+        return self.n_blocks * self.b_r
+
+    @property
+    def slot_val(self) -> jax.Array:
+        """The slots' values, ``(total, b_r)``."""
+        return self.val[: self.col_off.shape[0]]
+
+    @property
+    def rem_val(self) -> jax.Array:
+        """The remainder's values, ``(n_rem,)``."""
+        tail = self.val[self.col_off.shape[0]:].reshape(-1)
+        return tail[: self.rem_row.shape[0]]
+
+    def columns(self) -> jax.Array:
+        """The slots' global columns, rebuilt in XLA (not stored)."""
+        return R.window_columns(self.col_off, self.wbase, self.row_block)
 
 
 @jax.tree_util.register_dataclass
@@ -281,6 +337,41 @@ def to_device_sell(s: F.SELLMatrix, chunk_l: int = 8,
     )
 
 
+def to_device_wsell(w: F.WindowedSELLMatrix, chunk_l: int = 8,
+                    dtype=None) -> WSELLDevice:
+    if np.any(w.block_len % chunk_l):
+        raise ValueError(
+            f"chunk_l={chunk_l} must divide every chunk length; rebuild the "
+            f"windowed SELL matrix with diag_align a multiple of chunk_l")
+    row_block, chunk_map = _blocked_maps(w.block_len, chunk_l, w.n_blocks)
+    # the remainder's values ride in whole chunks after the slots'
+    n_rem = len(w.rem_val)
+    tail = -(-n_rem // (chunk_l * w.b_r)) * chunk_l
+    rem = np.zeros(tail * w.b_r, w.val.dtype)
+    rem[:n_rem] = w.rem_val
+    val = np.concatenate([w.val, rem.reshape(tail, w.b_r)])
+    if dtype is not None:
+        val = val.astype(dtype)
+    return WSELLDevice(
+        val=jnp.asarray(val),
+        col_off=jnp.asarray(w.col_off),
+        chunk_map=jnp.asarray(chunk_map),
+        row_block=jnp.asarray(row_block),
+        wbase=jnp.asarray(w.wbase),
+        inv_perm=jnp.asarray(w.inv_perm),
+        rem_row=jnp.asarray(w.rem_row),
+        rem_col=jnp.asarray(w.rem_col),
+        n_blocks=w.n_blocks,
+        b_r=w.b_r,
+        chunk_l=chunk_l,
+        sigma=w.sigma,
+        window=w.window,
+        x_len=w.x_len,
+        window_share=w.window_share,
+        max_chunks=group_max_chunks(chunk_map),
+    )
+
+
 def to_device_csr(m: F.CSRMatrix, dtype=None) -> CSRDevice:
     data = m.data if dtype is None else m.data.astype(dtype)
     row_ids = np.repeat(np.arange(m.n_rows, dtype=np.int32),
@@ -364,6 +455,72 @@ def sell_matvec(a: SELLDevice, x: jax.Array,
                              a.n_blocks)
 
 
+def _window_x(x: jax.Array, x_len: int) -> jax.Array:
+    """x cut or zero-padded to the windowed operand's ``x_len`` (entries
+    past the matrix's columns meet only padding slots)."""
+    n = x.shape[0]
+    if n >= x_len:
+        return x[:x_len]
+    return jnp.pad(x, [(0, x_len - n)] + [(0, 0)] * (x.ndim - 1))
+
+
+def _add_remainder(a: WSELLDevice, y: jax.Array, x: jax.Array) -> jax.Array:
+    """y (storage order) plus the remainder's products, under the device
+    scope ``repro.gather_rhs``: the one XLA gather of x left."""
+    if not a.rem_row.shape[0]:
+        return y
+    with jax.named_scope("repro.gather_rhs"):
+        return y + R.remainder_matvec_ref(a.rem_val, a.rem_row, a.rem_col,
+                                          x, a.n_rows_pad).astype(y.dtype)
+
+
+def wsell_matvec(a: WSELLDevice, x: jax.Array,
+                 backend: Backend = "ref") -> jax.Array:
+    """y = A x with rows back in the ORIGINAL order; y has n_rows_pad
+    entries.  The kernel path gathers the in-window slots inside the
+    kernel (``wsell_spmv``), adds the remainder in storage order, then
+    runs SELL's window-local unpermute.  The ref path rebuilds the
+    slots' global columns and runs the pJDS ref."""
+    xp = _window_x(x, a.x_len)
+    if resolve_backend(backend) == "kernel":
+        xw = xp.astype(jnp.promote_types(xp.dtype, jnp.float32))
+        y = wsell_matvec_kernel_call(
+            a.val, a.col_off, a.chunk_map, a.wbase,
+            xw.reshape(-1, LANES), n_blocks=a.n_blocks,
+            window=a.window, chunk_l=a.chunk_l, max_chunks=a.max_chunks)
+    else:
+        y = R.pjds_matvec_ref(a.slot_val, a.columns(), a.row_block, xp,
+                              a.n_blocks)
+    y = _add_remainder(a, y, x)
+    with jax.named_scope("repro.unpermute"):
+        return y[a.inv_perm]
+
+
+def wsell_matmat(a: WSELLDevice, x: jax.Array,
+                 backend: Backend = "ref") -> jax.Array:
+    """Y = A X, rows in the ORIGINAL order: the multi-RHS pJDS path on
+    the slots' rebuilt global columns, plus the remainder."""
+    pj = PJDSDevice(val=a.slot_val, col_idx=a.columns(),
+                    chunk_map=a.chunk_map, row_block=a.row_block,
+                    n_blocks=a.n_blocks, b_r=a.b_r, chunk_l=a.chunk_l,
+                    max_chunks=a.max_chunks)
+    y = _add_remainder(a, pjds_matmat(pj, _window_x(x, a.x_len), backend), x)
+    with jax.named_scope("repro.unpermute"):
+        return y[a.inv_perm]
+
+
+def wsell_rmatmat(a: WSELLDevice, y_p: jax.Array, n_cols: int) -> jax.Array:
+    """X = A^T Y with ``y_p`` in the storage row order: the blocked
+    scatter-accumulate over the rebuilt global columns plus the
+    remainder's."""
+    x = R.blocked_rmatvec_ref(a.slot_val, a.columns(), a.row_block, y_p,
+                              a.x_len)[:n_cols]
+    if a.rem_row.shape[0]:
+        x = x + R.remainder_rmatvec_ref(a.rem_val, a.rem_row, a.rem_col,
+                                        y_p, n_cols).astype(x.dtype)
+    return x
+
+
 def csr_matvec(a: CSRDevice, x: jax.Array,
                backend: Backend = "ref") -> jax.Array:
     # No Pallas kernel for CSR — the ref path IS the implementation.
@@ -391,6 +548,40 @@ _CSR_IRREGULAR_FACTOR = 4.0    # scalar gather stream can't saturate HBM
 _ELL_OVERHEAD_TOL = 0.05       # near-constant rows: skip sorting entirely
 
 
+def windows_fit(b_r: int, sigma: Optional[int]) -> bool:
+    """Whether a windowed SELL-C-sigma operand can be built at these
+    statics: rows on the 128 lanes, and sigma-windows of whole blocks."""
+    return b_r == LANES and (sigma is None or sigma % b_r == 0)
+
+
+def wsell_seconds(m: F.CSRMatrix, plan: F.WindowPlan, *, b_r: int,
+                  diag_align: int, sigma: Optional[int],
+                  spec: PM.TPUSpec = PM.TPU_V5E, value_bytes: int = 4,
+                  index_bytes: int = 4, vec_bytes: int = 4,
+                  calibration="default") -> float:
+    """Predicted time of one windowed SELL-C-sigma apply: the in-window
+    slots' values and int16 offsets, one x window per row block, the
+    window-local unpermute, and the remainder, whose gather of x and
+    sorted scatter-add into y are two XLA indexed accesses per entry
+    (``perf_model.gather_seconds``).  ``index_bytes`` is the width of
+    the remainder's global columns."""
+    if sigma is None:
+        sigma = 8 * b_r
+    n = m.n_rows
+    n_rem = m.nnz - int(plan.rowlen.sum())
+    elems = int(F.windowed_block_lengths(plan.rowlen, b_r, diag_align,
+                                         sigma).sum()) * b_r
+    rem_bytes = n_rem * (value_bytes + index_bytes + 4)
+    return PM.predicted_spmv_seconds(
+        elems, n, m.n_nzr,
+        perm_bytes=PM.perm_traffic_bytes(n, vec_bytes) + rem_bytes,
+        spec=spec, value_bytes=value_bytes, index_bytes=2,
+        vec_bytes=vec_bytes, fmt="wsell", calibration=calibration,
+        gathered=2 * n_rem,
+        rhs_bytes=PM.window_rhs_bytes(-(-n // b_r), plan.window,
+                                      vec_bytes))
+
+
 def select_format(
     m: F.CSRMatrix,
     *,
@@ -400,14 +591,25 @@ def select_format(
     spec: PM.TPUSpec = PM.TPU_V5E,
     value_dtype=None,
     index_dtype="auto",
+    plan: Optional[F.WindowPlan] = None,
 ) -> str:
-    """Pick a storage format from row-length statistics alone.
+    """Pick a storage format from row-length statistics and, for the
+    windowed SELL-C-sigma, from where the columns lie.
 
     Deterministic for a fixed matrix: prices each candidate's predicted
-    memory-bound spMVM time (``perf_model.predicted_spmv_seconds``) from
-    its estimated padded storage (``formats.estimate_storage_elements``)
+    spMVM time (``perf_model.predicted_spmv_seconds``) from its
+    estimated padded storage (``formats.estimate_storage_elements``)
     plus the HBM cost of any out-of-kernel permutation, then takes the
-    first minimum in the fixed order ellpack_r < sell < pjds < cmrs.
+    first minimum in the fixed order ellpack_r < sell < pjds < cmrs <
+    wsell.  Every format but the windowed SELL gathers x in XLA, once
+    per stored slot, which on the chip costs far more than the slot's
+    bytes (``perf_model.gather_seconds``); the windowed SELL gathers
+    inside the kernel and pays that cost only for the non-zeros outside
+    their block's window (:func:`wsell_seconds`, ``plan`` from
+    ``formats.window_plan``, computed here when not given).  So it wins
+    where rows are local, a banded matrix, and a matrix whose columns
+    scatter keeps a gathered format.  It is a candidate only where
+    :func:`windows_fit`.
     CSR wins only for degenerate inputs (empty, or too few rows to fill
     blocks).  CMRS is priced as ``max(memory, compute)``: its densely
     packed strips store ~nnz elements regardless of row-length skew —
@@ -440,29 +642,37 @@ def select_format(
     if ell_elems / m.nnz - 1.0 <= _ELL_OVERHEAD_TOL:
         return "ellpack_r"    # rows (nearly) constant: no sort, no perm
 
+    sell_elems = F.estimate_storage_elements(rl, "sell", b_r, diag_align,
+                                             sigma)
+    pjds_elems = F.estimate_storage_elements(rl, "pjds", b_r, diag_align)
     candidates = {
         "ellpack_r": PM.predicted_spmv_seconds(
             ell_elems, n, n_nzr, spec=spec, value_bytes=vb, index_bytes=ib,
-            vec_bytes=vecb, fmt="ellpack_r"),
+            vec_bytes=vecb, fmt="ellpack_r", gathered=ell_elems),
         "sell": PM.predicted_spmv_seconds(
-            F.estimate_storage_elements(rl, "sell", b_r, diag_align, sigma),
-            n, n_nzr,
+            sell_elems, n, n_nzr,
             perm_bytes=PM.perm_traffic_bytes(n, vecb),
             spec=spec, value_bytes=vb, index_bytes=ib, vec_bytes=vecb,
-            fmt="sell"),
+            fmt="sell", gathered=sell_elems),
         "pjds": PM.predicted_spmv_seconds(
-            F.estimate_storage_elements(rl, "pjds", b_r, diag_align),
-            n, n_nzr,
+            pjds_elems, n, n_nzr,
             perm_bytes=PM.perm_traffic_bytes(n, vecb),
             spec=spec, value_bytes=vb, index_bytes=ib, vec_bytes=vecb,
-            fmt="pjds"),
+            fmt="pjds", gathered=pjds_elems),
     }
     cmrs_elems = F.estimate_storage_elements(rl, "cmrs", b_r, diag_align)
     candidates["cmrs"] = max(
         PM.predicted_spmv_seconds(
             cmrs_elems, n, n_nzr, spec=spec, value_bytes=vb,
-            index_bytes=ib + PM.CMRS_RIS_BYTES, vec_bytes=vecb, fmt="cmrs"),
+            index_bytes=ib + PM.CMRS_RIS_BYTES, vec_bytes=vecb, fmt="cmrs",
+            gathered=cmrs_elems),
         PM.cmrs_reduce_seconds(cmrs_elems, b_r, spec))
+    if windows_fit(b_r, sigma):
+        if plan is None:
+            plan = F.window_plan(m, sigma)
+        candidates["wsell"] = wsell_seconds(
+            m, plan, b_r=b_r, diag_align=diag_align, sigma=sigma, spec=spec,
+            value_bytes=vb, index_bytes=ib, vec_bytes=vecb)
     return min(candidates, key=candidates.get)
 
 
@@ -499,7 +709,8 @@ class SparseDevice:
 
     fmt: str = dataclasses.field(metadata=dict(static=True))
     shape: Tuple[int, int] = dataclasses.field(metadata=dict(static=True))
-    dev: Union[PJDSDevice, ELLDevice, SELLDevice, CSRDevice, CMRSDevice]
+    dev: Union[PJDSDevice, ELLDevice, SELLDevice, WSELLDevice, CSRDevice,
+               CMRSDevice]
     inv_perm: Optional[jax.Array]      # pjds only: undo the global row sort
     # Preprocessing (reorder=) permutation: the stored matrix is
     # B = P A P^T with perm[k] = old index at new position k
@@ -521,9 +732,12 @@ class SparseDevice:
 
     @property
     def index_dtype(self):
-        """Dtype of the stored column-index stream (int16 or int32)."""
+        """Dtype of the stored column-index stream (int16 or int32; the
+        window-local offsets of the windowed SELL)."""
         if self.fmt == "csr":
             return self.dev.indices.dtype
+        if self.fmt == "wsell":
+            return self.dev.col_off.dtype
         return self.dev.col_idx.dtype
 
     def matvec(self, x: jax.Array, backend: Backend = "auto") -> jax.Array:
@@ -542,6 +756,8 @@ class SparseDevice:
             return ell_matvec(self.dev, x, backend)[: self.n_rows]
         if self.fmt == "sell":
             return sell_matvec(self.dev, x, backend)[: self.n_rows]
+        if self.fmt == "wsell":
+            return wsell_matvec(self.dev, x, backend)[: self.n_rows]
         if self.fmt == "pjds":
             y_p = pjds_matvec(self.dev, x, backend)
             with jax.named_scope("repro.unpermute"):
@@ -581,6 +797,8 @@ class SparseDevice:
             inv = d.inv_perm if self.fmt == "sell" else self.inv_perm
             with jax.named_scope("repro.unpermute"):
                 return y_p[inv][: self.n_rows]
+        if self.fmt == "wsell":
+            return wsell_matmat(self.dev, x, backend)[: self.n_rows]
         if self.fmt == "cmrs":
             d = self.dev
             return R.cmrs_matvec_ref(d.val, d.col_idx, d.row_in_strip,
@@ -622,6 +840,9 @@ class SparseDevice:
             y_p = self._scatter_to_storage(y, inv)
             return R.blocked_rmatvec_ref(d.val, d.col_idx, d.row_block,
                                          y_p, n_cols)
+        if self.fmt == "wsell":
+            y_p = self._scatter_to_storage(y, self.dev.inv_perm)
+            return wsell_rmatmat(self.dev, y_p, n_cols)
         if self.fmt == "cmrs":
             d = self.dev
             y_pad = self._pad_rows(y, d.n_rows_pad)
@@ -660,6 +881,12 @@ class SparseDevice:
         if self.fmt == "csr":
             return int(self.dev.data.size)
         return int(self.dev.val.size)
+
+    @property
+    def window_share(self) -> float:
+        """Share of the matrix's non-zeros the apply serves from a window
+        of x inside the kernel: 0 for every operand without windows."""
+        return self.dev.window_share if self.fmt == "wsell" else 0.0
 
 
 # Conversion cache: host matrix -> device representation.  Keyed by the
@@ -850,9 +1077,13 @@ def as_device(
 
     with obs.span("repro.convert"):
         fmt = format
+        plan = None
+        if fmt in ("auto", "wsell") and windows_fit(b_r, sigma):
+            plan = F.window_plan(a, 8 * b_r if sigma is None else sigma)
         if fmt == "auto":
             fmt = select_format(a, b_r=b_r, diag_align=da, sigma=sigma,
-                                value_dtype=dtype, index_dtype=index_dtype)
+                                value_dtype=dtype, index_dtype=index_dtype,
+                                plan=plan)
         if fmt == "csr":
             stored = a
         elif fmt == "ellpack_r":
@@ -866,6 +1097,13 @@ def as_device(
             stored = F.csr_to_pjds(a, b_r=b_r, diag_align=da,
                                    permuted_cols=False,
                                    index_dtype=index_dtype)
+        elif fmt == "wsell":
+            if plan is None:
+                raise ValueError(
+                    f"the windowed SELL needs b_r={LANES} and sigma a "
+                    f"multiple of it; got b_r={b_r}, sigma={sigma}")
+            stored = F.csr_to_wsell(a, c=b_r, sigma=sigma, diag_align=da,
+                                    index_dtype=index_dtype, plan=plan)
         elif fmt == "cmrs":
             stored = F.csr_to_cmrs(a, b_r=b_r, diag_align=da,
                                    index_dtype=index_dtype)
@@ -880,6 +1118,7 @@ def as_device(
                                 tile_r=ell_tile(b_r), dtype=dtype)
         else:
             to_device = {"sell": to_device_sell, "pjds": to_device_pjds,
+                         "wsell": to_device_wsell,
                          "cmrs": to_device_cmrs}[fmt]
             dev = to_device(stored, chunk_l=chunk_l, dtype=dtype)
         inv_perm = jnp.asarray(stored.inv_perm) if fmt == "pjds" else None

@@ -14,6 +14,11 @@ stream (DESIGN.md §2/§2b):
   (``_backend.gather_rhs``): the TPU compiler has no gather from a large
   VMEM array.  The kernel streams ``val`` and the gathered ``xg`` in
   ``(chunk_l, b_r)`` tiles and reduces each chunk over its sublanes.
+  That XLA gather costs ~8.6 ns per slot on a v5e and bounds this
+  kernel's apply; an operand whose rows are local is built as the
+  windowed SELL-C-sigma instead, whose kernel (``wsell_spmv.py``)
+  gathers from a VMEM window of x and leaves XLA only the non-zeros
+  outside it.
 * The grid is ``(group, chunk)`` over groups of ``OUT_BLOCKS`` row
   blocks (``_backend.grouped_matvec_call``): the group's
   ``(OUT_BLOCKS, b_r)`` output block stays pinned in VMEM and is written

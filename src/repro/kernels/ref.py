@@ -15,10 +15,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.formats import WINDOW_UNIT
+
 from ._backend import acc_dtype as _acc_dtype
 
 __all__ = ["pjds_matvec_ref", "pjds_matmat_ref", "ell_matvec_ref",
-           "sell_matvec_ref", "csr_matvec_ref",
+           "sell_matvec_ref", "csr_matvec_ref", "window_columns",
+           "remainder_matvec_ref", "remainder_rmatvec_ref",
            "csr_rmatvec_ref", "ell_rmatvec_ref", "blocked_rmatvec_ref",
            "cmrs_matvec_ref", "cmrs_rmatvec_ref",
            "partial_reduce_epilogue_ref"]
@@ -58,6 +61,43 @@ def sell_matvec_ref(val: jax.Array, col_idx: jax.Array, row_block: jax.Array,
     back to the original row order (y[i] = y_sorted[inv_perm[i]])."""
     y_sorted = pjds_matvec_ref(val, col_idx, row_block, x, n_blocks)
     return y_sorted[inv_perm]
+
+
+def window_columns(col_off: jax.Array, wbase: jax.Array,
+                   row_block: jax.Array) -> jax.Array:
+    """Global columns of a windowed SELL operand's slots, rebuilt from
+    the window-local offsets: ``wbase[block] * WINDOW_UNIT + offset``.
+    Padding slots land on their window's first entry, inside the padded
+    x, and meet a zero value."""
+    return (wbase[row_block][:, None] * WINDOW_UNIT
+            + col_off.astype(jnp.int32))
+
+
+def remainder_matvec_ref(rem_val: jax.Array, rem_row: jax.Array,
+                         rem_col: jax.Array, x: jax.Array,
+                         n_rows_pad: int) -> jax.Array:
+    """The windowed SELL remainder's y = A_out x: a gather of x and a
+    sorted segment sum into the storage rows.  x: (n,) or (n, k);
+    returns (n_rows_pad[, k])."""
+    dt = _acc_dtype(rem_val.dtype, x.dtype)
+    xg = x[rem_col.astype(jnp.int32)].astype(dt)
+    v = rem_val.astype(dt)
+    contrib = v[:, None] * xg if xg.ndim == 2 else v * xg
+    return jax.ops.segment_sum(contrib, rem_row, num_segments=n_rows_pad,
+                               indices_are_sorted=True)
+
+
+def remainder_rmatvec_ref(rem_val: jax.Array, rem_row: jax.Array,
+                          rem_col: jax.Array, y: jax.Array,
+                          n_cols: int) -> jax.Array:
+    """Transpose of :func:`remainder_matvec_ref`: y in the storage row
+    order, (n_rows_pad[, k]) -> (n_cols[, k])."""
+    dt = _acc_dtype(rem_val.dtype, y.dtype)
+    yg = y[rem_row].astype(dt)
+    v = rem_val.astype(dt)
+    contrib = v[:, None] * yg if yg.ndim == 2 else v * yg
+    return jax.ops.segment_sum(contrib, rem_col.astype(jnp.int32),
+                               num_segments=n_cols)
 
 
 def partial_reduce_epilogue_ref(y_sorted: jax.Array, own_pos: jax.Array,

@@ -252,8 +252,8 @@ def price_candidate(
     spec: PM.TPUSpec = PM.TPU_V5E,
     calibration="default",
 ) -> float:
-    """Predicted memory-bound spMVM seconds of candidate ``c`` on ``m``
-    — the same ``perf_model`` pricing ``select_format`` uses, extended
+    """Predicted spMVM seconds of candidate ``c`` on ``m`` — the same
+    ``perf_model`` pricing ``select_format`` uses, extended
     over the full static space.  ``calibration=None`` forces the
     uncalibrated data-sheet model (what the calibration fit needs as
     its regressor); the default picks up any installed calibration."""
@@ -261,16 +261,24 @@ def price_candidate(
     vecb = max(4, m.data.dtype.itemsize)
     if c.fmt == "csr":
         vb = m.data.dtype.itemsize if dtype is None else np.dtype(dtype).itemsize
-        # CSRDevice streams indices AND row ids per nnz (8 index bytes).
+        # CSRDevice streams indices AND row ids per nnz (8 index bytes),
+        # and gathers x and scatter-adds into y in XLA: two indexed
+        # accesses per nnz.
         return PM.predicted_spmv_seconds(
             m.nnz, n, n_nzr, irregular_factor=ops._CSR_IRREGULAR_FACTOR,
             spec=spec, value_bytes=vb, index_bytes=8, vec_bytes=vecb,
-            fmt="csr", calibration=calibration)
+            fmt="csr", calibration=calibration, gathered=2 * m.nnz)
     rl = m.row_lengths()
     vb = np.dtype(dtype).itemsize if dtype is not None \
         else m.data.dtype.itemsize
     ib = F.resolve_index_dtype(index_dtype, m.shape[1]).itemsize
     da = max(_DEFAULT_DIAG_ALIGN, c.chunk_l)
+    if c.fmt == "wsell":
+        sigma = 8 * c.b_r if c.sigma is None else c.sigma
+        return ops.wsell_seconds(
+            m, F.window_plan(m, sigma), b_r=c.b_r, diag_align=da,
+            sigma=sigma, spec=spec, value_bytes=vb, index_bytes=ib,
+            vec_bytes=vecb, calibration=calibration)
     elems = F.estimate_storage_elements(rl, c.fmt, c.b_r, da, c.sigma)
     perm_bytes = 0.0
     if c.fmt in PM.SORTED_ROW_FORMATS:
@@ -283,7 +291,7 @@ def price_candidate(
     t = PM.predicted_spmv_seconds(
         elems, n, n_nzr, perm_bytes=perm_bytes, spec=spec,
         value_bytes=vb, index_bytes=ib, vec_bytes=vecb,
-        fmt=c.fmt, calibration=calibration)
+        fmt=c.fmt, calibration=calibration, gathered=elems)
     if c.fmt == "cmrs":
         t = max(t, PM.cmrs_reduce_seconds(elems, c.b_r, spec))
     return t

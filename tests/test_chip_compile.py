@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import formats as F
 from repro.core import matrices as M
 from repro.kernels import ops
 from repro.kernels._backend import OUT_BLOCKS
@@ -35,6 +36,14 @@ _TOTAL = _N_BLOCKS * _CHUNK_L
 _ELL_DEPTH = 32
 _GROUP_CHUNKS = 2 * OUT_BLOCKS
 _N_RHS = 8
+# The windowed SELL at the same size: the sAMG configuration's rows take
+# windows of 3 units (24 rows of 128 lanes; ``formats.window_plan`` on
+# its generator, 95.6% of the non-zeros in window) and leave ~4.4% of
+# its ~22.6M non-zeros, 1M, to the XLA remainder.
+_WINDOW = 3 * F.WINDOW_UNIT
+_X_LEN = -(-_N // F.WINDOW_UNIT) * F.WINDOW_UNIT
+_N_REM = 1_000_000
+_REM_TAIL = -(-_N_REM // (_CHUNK_L * _B_R)) * _CHUNK_L
 
 _POLICIES = [
     pytest.param(jnp.float32, jnp.int32, id="f32+int32"),
@@ -114,6 +123,31 @@ def test_sell_spmv_compiles(on_chip, vdt, idt):
         return ops.sell_matvec(_sell(val, col, cm, inv), x, backend="auto")
     _compile(f, on_chip, *_blocked_shapes(vdt, idt),
              ((_N_PAD,), jnp.int32), ((_N,), jnp.float32))
+
+
+@pytest.mark.parametrize("vdt,idt", _POLICIES)
+def test_wsell_spmv_compiles(on_chip, vdt, idt):
+    del idt
+    def f(val, off, cm, wbase, inv, rem_row, rem_col, x):
+        dev = ops.WSELLDevice(
+            val=val, col_off=off, chunk_map=cm, row_block=cm, wbase=wbase,
+            inv_perm=inv, rem_row=rem_row, rem_col=rem_col,
+            n_blocks=_N_BLOCKS, b_r=_B_R, chunk_l=_CHUNK_L, sigma=8 * _B_R,
+            window=_WINDOW, x_len=_X_LEN, window_share=0.956,
+            max_chunks=_GROUP_CHUNKS)
+        return ops.wsell_matvec(dev, x, backend="auto")
+    compiled = _compile(
+        f, on_chip, ((_TOTAL + _REM_TAIL, _B_R), vdt),
+        ((_TOTAL, _B_R), jnp.int16), ((_TOTAL // _CHUNK_L,), jnp.int32),
+        ((_N_BLOCKS,), jnp.int32), ((_N_PAD,), jnp.int32),
+        # 3.4M columns need int32 remainder columns under either policy;
+        # the window offsets are int16 under both
+        ((_N_REM,), jnp.int32), ((_N_REM,), jnp.int32), ((_N,), jnp.float32))
+    # the in-window slots gather nothing in XLA: no gathered stream
+    text = compiled.as_text()
+    assert "wsell_spmv" in text
+    assert f"f32[{_TOTAL * _B_R}]" not in text
+    assert f"f32[{_TOTAL},{_B_R}]" not in text
 
 
 @pytest.mark.parametrize("vdt,idt", _POLICIES)

@@ -86,13 +86,17 @@ def test_cmrs_diagonal(rng):
 
 def test_select_format_offers_cmrs(rng):
     _, m = _hub_matrix(rng)
-    pick = ops.select_format(m)
-    assert pick == "cmrs"
+    # x fits one window: the windowed SELL gathers nothing in XLA
+    assert ops.select_format(m) == "wsell"
+    # without windows (a sigma of part blocks builds none), CMRS wins
+    # every format that gathers x in XLA
+    assert ops.select_format(m, sigma=1000) == "cmrs"
 
 
 def test_select_format_still_prefers_ell_for_uniform():
     m = M.poisson_2d(24, 24)
-    assert ops.select_format(m) == "ellpack_r"
+    assert ops.select_format(m) == "wsell"
+    assert ops.select_format(m, sigma=1000) == "ellpack_r"
 
 
 def test_cmrs_in_tuner_space(rng):

@@ -167,12 +167,14 @@ def test_operator_build_notes_its_stored_slots():
     obs.reset()
     try:
         op = operator(M.power_law(700), format="pjds")
-        assert obs.gauges() == {"repro.stored_slots": op.stored_slots}
+        assert obs.gauges() == {"repro.stored_slots": op.stored_slots,
+                                "repro.window_share": 0.0}
         assert set(obs.totals()) == {"repro.convert", "repro.transfer",
                                      "repro.operator.build"}
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
         from repro.core.operator import dist_operator
         dop = dist_operator(M.poisson_2d(12, 12), mesh, transpose=None)
         assert obs.gauges()["repro.stored_slots"] == dop.stored_slots
+        assert obs.gauges()["repro.window_share"] == 0.0
     finally:
         obs.reset()

@@ -198,3 +198,20 @@ def test_spmvm_bytes_model():
     b = PM.spmvm_bytes(stored_elements=1000, n_rows=100, alpha=1.0,
                        n_nzr=10, value_bytes=8)
     assert b == 1000 * 12 + 1.0 * 10 * 100 * 8 + 2 * 100 * 8
+
+
+def test_gathered_elements_priced_per_element():
+    """An XLA gather costs ``gather_s`` per element, whatever its bytes;
+    a windowed format's RHS bytes replace the gathered stream's."""
+    kw = dict(stored_elements=10_000, n_rows=1_000, n_nzr=10.0,
+              calibration=None)
+    base = PM.predicted_spmv_seconds(**kw)
+    assert PM.predicted_spmv_seconds(**kw, gathered=10_000) - base == \
+        pytest.approx(PM.gather_seconds(10_000))
+    assert PM.gather_seconds(10_000) == pytest.approx(
+        10_000 * PM.TPU_V5E.gather_s)
+    win = PM.window_rhs_bytes(n_blocks=8, window=3072)
+    assert win == 8 * 3072 * 4
+    assert PM.predicted_spmv_seconds(**kw, rhs_bytes=win) - base == \
+        pytest.approx((win - PM.gathered_rhs_bytes(10_000))
+                      / PM.TPU_V5E.hbm_bw)
