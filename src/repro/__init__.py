@@ -5,6 +5,7 @@ heavy submodules load on first attribute access::
 
     import repro
     res = repro.solve(m, b, method="cg")         # the solver front door
+    ranks = repro.pagerank(adj)                  # Graphalytics PageRank
     op = repro.operator(m)                       # y = op @ x
     dop = repro.dist_operator(m, mesh)           # mesh-distributed
 
@@ -14,13 +15,14 @@ dispatch), ``repro.tune`` (autotuner), ``repro.serve`` (engines).
 """
 from __future__ import annotations
 
-__all__ = ["solve", "SolveResult", "SolveFailure", "operator",
+__all__ = ["solve", "SolveResult", "SolveFailure", "pagerank", "operator",
            "dist_operator", "load_mm", "save_mm", "preprocess"]
 
 _LAZY = {
     "solve": "repro.api",
     "SolveResult": "repro.core.solvers",
     "SolveFailure": "repro.api",
+    "pagerank": "repro.api",
     "operator": "repro.core.operator",
     "dist_operator": "repro.core.operator",
     "load_mm": "repro.core.io_mm",

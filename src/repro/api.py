@@ -52,10 +52,11 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.core import graph as G
 from repro.core import solvers as S
 from repro.core.solvers import SolveResult
 
-__all__ = ["solve", "SolveFailure"]
+__all__ = ["solve", "SolveFailure", "pagerank"]
 
 _METHODS = ("cg", "bicgstab", "block_cg")
 _DEFAULT_MAXITER = {"cg": 500, "bicgstab": 1000, "block_cg": 500}
@@ -509,3 +510,25 @@ def _ladder_solve(op, op_lo, b, *, method, strategy, maxiter, tol, precond,
             f"(last: {last}); see .ladder / .result for diagnostics",
             result=res, ladder=ladder)
     return res, ladder
+
+
+def pagerank(adj, damping: float = 0.85, iterations: int = 10, x0=None):
+    """PageRank of the graph ``adj`` as LDBC Graphalytics defines it:
+    ``iterations`` fixed iterations from ``x0`` (``1/|V|`` each by
+    default) with damping ``damping``, the dangling vertices' rank
+    spread over every vertex.  ``adj`` is a host CSR whose stored entry
+    ``(u, v)`` is the edge ``u -> v`` (:mod:`repro.core.graph`); the
+    transition matrix goes through ``operator()`` with format "auto".
+    Returns the ranks, float32, one per row of ``adj``."""
+    pr = G.pagerank_operator(adj)
+    if x0 is None:
+        x = jnp.full(pr.n, 1.0 / pr.n, jnp.float32)
+    else:
+        x = jnp.asarray(x0, jnp.float32)
+        if x.shape != (pr.n,):
+            raise ValueError(f"x0 has shape {x.shape}; the graph has "
+                             f"{pr.n} vertices")
+    return _pagerank_steps(pr, x, int(iterations), float(damping))
+
+
+_pagerank_steps = jax.jit(G.pagerank_steps, static_argnums=(2,))

@@ -61,6 +61,7 @@ __all__ = [
     "WindowedSELLMatrix",
     "WindowPlan",
     "window_plan",
+    "window_reach",
     "csr_to_wsell",
     "wsell_to_dense",
     "WINDOW_UNIT",
@@ -204,9 +205,9 @@ def csr_from_coo(
     Sorted-per-row invariant: the ``lexsort((cols, rows))`` below runs
     BEFORE the ``sum_duplicates`` branch, so the output's within-row
     column indices are ascending on BOTH paths.  Callers that pass
-    ``sum_duplicates=False`` (``csr_transpose``,
-    ``reorder.permute_symmetric``) therefore still satisfy the sorted
-    invariant that ``validate_csr`` enforces and that int16 span
+    ``sum_duplicates=False`` (``reorder.permute_symmetric``) therefore
+    still satisfy the sorted invariant that ``validate_csr`` enforces
+    and that int16 span
     compression (``resolve_index_dtype``) assumes — they merely skip
     deduplication, not the sort.  (On the dedup path the invariant also
     follows from ``np.unique`` of the ``row * n_cols + col`` key.)"""
@@ -647,6 +648,36 @@ class WindowPlan:
         return float(self.rowlen.sum()) / self.nnz if self.nnz else 0.0
 
 
+def _window_anchors(n_win: int, sigma: int, x_units: int) -> np.ndarray:
+    """Each sigma-window's anchor: the unit holding its middle row,
+    clamped to x."""
+    return np.minimum((np.arange(n_win, dtype=np.int64) * sigma
+                       + sigma // 2) // WINDOW_UNIT, x_units - 1)
+
+
+def window_reach(m: CSRMatrix, sigma: int) -> int:
+    """The most non-zeros any :func:`window_plan` of ``m`` can serve:
+    those whose unit lies within ``WINDOW_UNITS_MAX - 1`` units of their
+    sigma-window's anchor, the span every width and start the plan
+    weighs stays inside (clamped at x's ends too).  One pass over the
+    non-zeros and no runs, so dispatch can tell cheaply that windows
+    cannot pay before it makes the plan."""
+    if m.nnz == 0:
+        return 0
+    n_rows, n_cols = m.shape
+    u_max = WINDOW_UNITS_MAX
+    n_win = max(-(-n_rows // sigma), 1)
+    anchor = _window_anchors(n_win, sigma, max(-(-n_cols // WINDOW_UNIT), 1))
+    win_nnz = np.diff(m.indptr[np.minimum(np.arange(n_win + 1) * sigma,
+                                          n_rows)])
+    # unit - (anchor - (u_max - 1)) lies in [0, 2 u_max - 2] within the
+    # span; below it, negative, it wraps past that as unsigned
+    off = m.indices // WINDOW_UNIT
+    off -= np.repeat((anchor - (u_max - 1)).astype(off.dtype), win_nnz)
+    return int(np.count_nonzero(off.view(f"u{off.itemsize}")
+                                < 2 * u_max - 1))
+
+
 def window_plan(m: CSRMatrix, sigma: int) -> WindowPlan:
     """Give every sigma-window of rows one x window: ``units`` aligned
     WINDOW_UNITs, the same width for the whole matrix, placed where it
@@ -671,20 +702,26 @@ def window_plan(m: CSRMatrix, sigma: int) -> WindowPlan:
         return WindowPlan(units=1, start=np.zeros(n_win, np.int64),
                           row_lo=zero, rowlen=zero, nnz=0, x_len=x_len(1))
     unit = m.indices // WINDOW_UNIT
-    rl = np.diff(m.indptr)
-    run0 = np.union1d(np.flatnonzero(unit[1:] != unit[:-1]) + 1,
-                      m.indptr[:-1][rl > 0])
+    rows = np.flatnonzero(np.diff(m.indptr))        # rows with non-zeros
+    row_first = np.zeros(nnz, bool)
+    row_first[m.indptr[rows]] = True
+    brk = row_first.copy()
+    np.not_equal(unit[1:], unit[:-1], out=brk[1:])
+    brk |= row_first
+    run0 = np.flatnonzero(brk)
+    del brk
     run_len = np.diff(np.append(run0, nnz))
-    run_row = np.searchsorted(m.indptr, run0, side="right") - 1
+    run_row = rows[np.cumsum(row_first[run0]) - 1]
+    del row_first
     run_win = run_row // sigma
-    anchor = np.minimum((np.arange(n_win, dtype=np.int64) * sigma
-                         + sigma // 2) // WINDOW_UNIT, x_units - 1)
+    anchor = _window_anchors(n_win, sigma, x_units)
     # bin u_max + (unit - anchor) for unit offsets within +-(u_max - 1),
     # bins 0 and 2 * u_max for those further below and above
     nb = 2 * u_max + 1
-    rel = np.clip(unit[run0] - anchor[run_win] + u_max, 0, nb - 1)
-    hist = np.zeros(n_win * nb, np.int64)
-    np.add.at(hist, run_win * nb + rel, run_len)
+    rel = unit[run0] - (anchor - u_max)[run_win]
+    np.clip(rel, 0, nb - 1, out=rel)
+    hist = np.bincount(run_win * nb + rel, weights=run_len,
+                       minlength=n_win * nb).astype(np.int64)
     cum = np.zeros((n_win, nb + 1), np.int64)
     np.cumsum(hist.reshape(n_win, nb), axis=1, out=cum[:, 1:])
     wins = np.arange(n_win)
@@ -709,8 +746,8 @@ def window_plan(m: CSRMatrix, sigma: int) -> WindowPlan:
     u, start, _ = pick
     lo = (start - anchor + u_max)[run_win]
     inside = (rel >= lo) & (rel < lo + u)
-    rowlen = np.zeros(n_rows, np.int64)
-    np.add.at(rowlen, run_row[inside], run_len[inside])
+    rowlen = np.bincount(run_row[inside], weights=run_len[inside],
+                         minlength=n_rows).astype(np.int64)
     # a row's in-window runs are consecutive: its run ends where the last
     # of them ends
     row_hi = m.indptr[:-1].astype(np.int64)
@@ -1000,14 +1037,26 @@ def csr_transpose(m: CSRMatrix) -> CSRMatrix:
     transpose path reuses the gather-structured spMVM instead of a
     scatter (DESIGN.md §8).
 
-    The ``sum_duplicates=False`` fast path is safe here: duplicates in
-    ``m`` stay duplicates in the transpose (matvec sums them either
-    way), and ``csr_from_coo`` sorts within rows before that branch, so
-    the result still satisfies the sorted-per-row invariant.
-    """
-    rows = np.repeat(np.arange(m.n_rows, dtype=np.int64), m.row_lengths())
-    return csr_from_coo(m.indices.astype(np.int64), rows, m.data,
-                        (m.n_cols, m.n_rows), sum_duplicates=False)
+    Each non-zero moves to its column's row of A^T in its row order (a
+    stable order by column), so the rows of A^T keep their columns
+    ascending and a duplicate of ``m`` stays a duplicate, which matvec
+    sums either way.  The stable order is one sort of the int64 keys
+    ``column << b | position``, which a 31-bit column and a position
+    below 2**32 fit; it is several times quicker than a stable argsort
+    or a 16-bit radix one.  Each column's row of A^T starts at its
+    count's running sum."""
+    n_rows, n_cols = m.shape
+    nnz = len(m.indices)
+    shift = max((nnz - 1).bit_length(), 1)
+    order = m.indices.astype(np.int64)
+    order <<= shift
+    order |= np.arange(nnz, dtype=np.int64)
+    order.sort()
+    order &= (1 << shift) - 1
+    indptr = np.zeros(n_cols + 1, np.int64)
+    np.cumsum(np.bincount(m.indices, minlength=n_cols), out=indptr[1:])
+    rows = np.repeat(np.arange(n_rows, dtype=np.int32), m.row_lengths())
+    return CSRMatrix(indptr, rows[order], m.data[order], (n_cols, n_rows))
 
 
 def csr_diagonal(m: CSRMatrix) -> np.ndarray:

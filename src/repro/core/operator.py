@@ -51,6 +51,7 @@ from repro.core import formats as F
 from repro.core import dist_spmv as D
 from repro.core import perf_model as PM
 from repro.kernels import ops
+from repro.kernels._backend import OUT_BLOCKS
 
 __all__ = [
     "SparseOperator",
@@ -235,6 +236,12 @@ class DeviceOperator(SparseOperator):
         """Share of the non-zeros served from a window of x inside the
         kernel (``ops.SparseDevice.window_share``)."""
         return self.dev.window_share
+
+    @property
+    def grid_fill(self) -> float:
+        """Share of the kernel grid's steps that compute a chunk
+        (``ops.SparseDevice.grid_fill``)."""
+        return self.dev.grid_fill
 
     @property
     def values(self) -> jax.Array:
@@ -428,6 +435,19 @@ class DistOperator(SparseOperator):
         """The partition gathers every slot in XLA: no windows."""
         return 0.0
 
+    @property
+    def grid_fill(self) -> float:
+        """Share of the local and remote kernels' grid steps that compute
+        a chunk, per device.  Every device's operands are padded to one
+        extent, and the padding chunks are computed, so each device reads
+        the same share and this is also their mean."""
+        d = self.dist
+        n_groups = max(-(-d.n_blocks // OUT_BLOCKS), 1)
+        n_loc, n_rem = d.loc_chunk_map.shape[1], d.rem_chunk_map.shape[1]
+        steps = n_groups * ((d.loc_max_chunks or n_loc)
+                            + (d.rem_max_chunks or n_rem))
+        return (n_loc + n_rem) / max(steps, 1)
+
     # -- application -------------------------------------------------------
     def _fwd(self, dist, multi_rhs):
         # Memoized per instance: the shard_map closure is built once per
@@ -502,10 +522,11 @@ class DistOperator(SparseOperator):
 # Factories
 # --------------------------------------------------------------------------
 def _built(op):
-    """``op``, with its stored slots and its window share noted
-    (``obs.gauge``) as those of the operator built last."""
+    """``op``, with its stored slots, its window share and its grid fill
+    noted (``obs.gauge``) as those of the operator built last."""
     obs.gauge("repro.stored_slots", op.stored_slots)
     obs.gauge("repro.window_share", op.window_share)
+    obs.gauge("repro.grid_fill", op.grid_fill)
     return op
 
 

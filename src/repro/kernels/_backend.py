@@ -94,11 +94,19 @@ def gather_rhs(col_idx: jax.Array, x: jax.Array) -> jax.Array:
     shape (n_cols, k) gathers into ``(k,) + col_idx.shape``, RHS columns
     leading, so each column keeps the matrix's tile layout.  Padding
     slots hold ``formats.PAD_COL`` (column 0), so they gather x[0] and
-    meet a zero value in the kernel.  Runs under the device scope
-    ``repro.gather_rhs``, the index cast included."""
+    meet a zero value in the kernel.  A single ``x`` is gathered from a
+    copy padded to whole ``(8, 128)`` tiles, which XLA:TPU places in
+    VMEM where it fits; gathered straight from x in HBM, a Graph500
+    graph's scattered columns took 15.6 ns a slot on a TPU v5e against
+    8.6, varying by ~10% from one apply to the next (PERF.md §6);
+    ``tests/test_chip_compile.py`` fails if that copy leaves VMEM.  Runs
+    under the device scope ``repro.gather_rhs``, the index cast and the
+    pad included."""
     with jax.named_scope("repro.gather_rhs"):
         idx = col_idx.astype(jnp.int32)
-        return x[idx] if x.ndim == 1 else x.T[:, idx]
+        if x.ndim == 1:
+            return jnp.pad(x, (0, -x.shape[0] % (OUT_BLOCKS * LANES)))[idx]
+        return x.T[:, idx]
 
 
 def group_max_chunks(chunk_map) -> int:

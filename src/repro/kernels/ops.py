@@ -40,7 +40,7 @@ from repro import obs
 from repro.core import formats as F
 from repro.core import perf_model as PM
 from . import ref as R
-from ._backend import LANES, group_max_chunks, resolve_interpret
+from ._backend import LANES, OUT_BLOCKS, group_max_chunks, resolve_interpret
 from .pjds_spmv import pjds_matvec_kernel_call
 from .pjds_spmm import pjds_matmat_kernel_call
 from .ellr_spmv import ell_matvec_kernel_call
@@ -567,10 +567,20 @@ def wsell_seconds(m: F.CSRMatrix, plan: F.WindowPlan, *, b_r: int,
     the remainder's global columns."""
     if sigma is None:
         sigma = 8 * b_r
-    n = m.n_rows
-    n_rem = m.nnz - int(plan.rowlen.sum())
     elems = int(F.windowed_block_lengths(plan.rowlen, b_r, diag_align,
                                          sigma).sum()) * b_r
+    return _wsell_price(m, m.nnz - int(plan.rowlen.sum()), elems,
+                        plan.window, b_r=b_r, spec=spec,
+                        value_bytes=value_bytes, index_bytes=index_bytes,
+                        vec_bytes=vec_bytes, calibration=calibration)
+
+
+def _wsell_price(m, n_rem: int, elems: int, window: int, *, b_r, spec,
+                 value_bytes, index_bytes, vec_bytes,
+                 calibration="default") -> float:
+    """:func:`wsell_seconds` from the remainder's size, the stored slots
+    and the window width; it grows with each of them."""
+    n = m.n_rows
     rem_bytes = n_rem * (value_bytes + index_bytes + 4)
     return PM.predicted_spmv_seconds(
         elems, n, m.n_nzr,
@@ -578,11 +588,10 @@ def wsell_seconds(m: F.CSRMatrix, plan: F.WindowPlan, *, b_r: int,
         spec=spec, value_bytes=value_bytes, index_bytes=2,
         vec_bytes=vec_bytes, fmt="wsell", calibration=calibration,
         gathered=2 * n_rem,
-        rhs_bytes=PM.window_rhs_bytes(-(-n // b_r), plan.window,
-                                      vec_bytes))
+        rhs_bytes=PM.window_rhs_bytes(-(-n // b_r), window, vec_bytes))
 
 
-def select_format(
+def _select(
     m: F.CSRMatrix,
     *,
     b_r: int = 128,
@@ -592,42 +601,12 @@ def select_format(
     value_dtype=None,
     index_dtype="auto",
     plan: Optional[F.WindowPlan] = None,
-) -> str:
-    """Pick a storage format from row-length statistics and, for the
-    windowed SELL-C-sigma, from where the columns lie.
-
-    Deterministic for a fixed matrix: prices each candidate's predicted
-    spMVM time (``perf_model.predicted_spmv_seconds``) from its
-    estimated padded storage (``formats.estimate_storage_elements``)
-    plus the HBM cost of any out-of-kernel permutation, then takes the
-    first minimum in the fixed order ellpack_r < sell < pjds < cmrs <
-    wsell.  Every format but the windowed SELL gathers x in XLA, once
-    per stored slot, which on the chip costs far more than the slot's
-    bytes (``perf_model.gather_seconds``); the windowed SELL gathers
-    inside the kernel and pays that cost only for the non-zeros outside
-    their block's window (:func:`wsell_seconds`, ``plan`` from
-    ``formats.window_plan``, computed here when not given).  So it wins
-    where rows are local, a banded matrix, and a matrix whose columns
-    scatter keeps a gathered format.  It is a candidate only where
-    :func:`windows_fit`.
-    CSR wins only for degenerate inputs (empty, or too few rows to fill
-    blocks).  CMRS is priced as ``max(memory, compute)``: its densely
-    packed strips store ~nnz elements regardless of row-length skew —
-    where ELLPACK/pJDS pad — but every slot costs ``2 * b_r`` MXU flops
-    in the kernel's one-hot segment reduction
-    (``perf_model.cmrs_reduce_seconds``), so it wins exactly when the
-    padding bytes it saves outweigh that compute floor (power-law /
-    hub-dominated patterns).
-    The pricing sees the byte widths that will actually be STORED —
-    ``value_dtype`` (bf16 storage halves the value stream) and
-    ``index_dtype`` (int16 when the column span fits halves the index
-    stream) — so compressed variants are priced correctly; RHS/LHS
-    traffic stays priced at the uncompressed vector width (the vectors
-    do not shrink with the matrix).  The full rationale is DESIGN.md §5.
-    """
+) -> tuple:
+    """:func:`select_format`, and the window plan it made (None where
+    it made none, given none)."""
     n = m.n_rows
     if m.nnz == 0 or n < _CSR_MIN_ROWS_FACTOR * b_r:
-        return "csr"
+        return "csr", plan
     rl = m.row_lengths()
     n_nzr = m.n_nzr
     if sigma is None:
@@ -640,7 +619,7 @@ def select_format(
     ell_elems = F.estimate_storage_elements(rl, "ellpack_r", ell_tile(b_r),
                                             diag_align)
     if ell_elems / m.nnz - 1.0 <= _ELL_OVERHEAD_TOL:
-        return "ellpack_r"    # rows (nearly) constant: no sort, no perm
+        return "ellpack_r", plan   # rows (nearly) constant: no sort, no perm
 
     sell_elems = F.estimate_storage_elements(rl, "sell", b_r, diag_align,
                                              sigma)
@@ -668,12 +647,74 @@ def select_format(
             gathered=cmrs_elems),
         PM.cmrs_reduce_seconds(cmrs_elems, b_r, spec))
     if windows_fit(b_r, sigma):
-        if plan is None:
-            plan = F.window_plan(m, sigma)
-        candidates["wsell"] = wsell_seconds(
-            m, plan, b_r=b_r, diag_align=diag_align, sigma=sigma, spec=spec,
-            value_bytes=vb, index_bytes=ib, vec_bytes=vecb)
-    return min(candidates, key=candidates.get)
+        kw = dict(b_r=b_r, spec=spec, value_bytes=vb, index_bytes=ib,
+                  vec_bytes=vecb)
+        # The plan costs passes over every non-zero's run.  The most any
+        # plan can serve prices the windowed SELL from below (fewest
+        # slots, narrowest window); where that is no cheaper than the
+        # best gathered format, ties going to those, no plan is made.
+        # Either way the choice is the plan's.
+        hopeless = (
+            plan is None
+            and _wsell_price(m, m.nnz - F.window_reach(m, sigma),
+                             -(-n // b_r) * diag_align * b_r,
+                             F.WINDOW_UNIT, **kw)
+            >= min(candidates.values()))
+        if not hopeless:
+            if plan is None:
+                plan = F.window_plan(m, sigma)
+            candidates["wsell"] = wsell_seconds(
+                m, plan, diag_align=diag_align, sigma=sigma, **kw)
+    return min(candidates, key=candidates.get), plan
+
+
+def select_format(
+    m: F.CSRMatrix,
+    *,
+    b_r: int = 128,
+    diag_align: int = 8,
+    sigma: Optional[int] = None,
+    spec: PM.TPUSpec = PM.TPU_V5E,
+    value_dtype=None,
+    index_dtype="auto",
+    plan: Optional[F.WindowPlan] = None,
+) -> str:
+    """Pick a storage format from row-length statistics and, for the
+    windowed SELL-C-sigma, from where the columns lie.
+
+    Deterministic for a fixed matrix: prices each candidate's predicted
+    spMVM time (``perf_model.predicted_spmv_seconds``) from its
+    estimated padded storage (``formats.estimate_storage_elements``)
+    plus the HBM cost of any out-of-kernel permutation, then takes the
+    first minimum in the fixed order ellpack_r < sell < pjds < cmrs <
+    wsell.  Every format but the windowed SELL gathers x in XLA, once
+    per stored slot, which on the chip costs far more than the slot's
+    bytes (``perf_model.gather_seconds``); the windowed SELL gathers
+    inside the kernel and pays that cost only for the non-zeros outside
+    their block's window (:func:`wsell_seconds`, ``plan`` from
+    ``formats.window_plan``, computed here when not given, and not at all
+    where ``formats.window_reach`` shows that no plan can win).  So it wins
+    where rows are local, a banded matrix, and a matrix whose columns
+    scatter keeps a gathered format.  It is a candidate only where
+    :func:`windows_fit`.
+    CSR wins only for degenerate inputs (empty, or too few rows to fill
+    blocks).  CMRS is priced as ``max(memory, compute)``: its densely
+    packed strips store ~nnz elements regardless of row-length skew —
+    where ELLPACK/pJDS pad — but every slot costs ``2 * b_r`` MXU flops
+    in the kernel's one-hot segment reduction
+    (``perf_model.cmrs_reduce_seconds``), so it wins exactly when the
+    padding bytes it saves outweigh that compute floor (power-law /
+    hub-dominated patterns).
+    The pricing sees the byte widths that will actually be STORED —
+    ``value_dtype`` (bf16 storage halves the value stream) and
+    ``index_dtype`` (int16 when the column span fits halves the index
+    stream) — so compressed variants are priced correctly; RHS/LHS
+    traffic stays priced at the uncompressed vector width (the vectors
+    do not shrink with the matrix).  The full rationale is DESIGN.md §5.
+    """
+    return _select(m, b_r=b_r, diag_align=diag_align, sigma=sigma,
+                   spec=spec, value_dtype=value_dtype,
+                   index_dtype=index_dtype, plan=plan)[0]
 
 
 def sandwich(pre_perm, pre_inv, apply, v):
@@ -888,6 +929,27 @@ class SparseDevice:
         of x inside the kernel: 0 for every operand without windows."""
         return self.dev.window_share if self.fmt == "wsell" else 0.0
 
+    @property
+    def grid_fill(self) -> float:
+        """Share of the kernel grid's steps that compute a chunk: the
+        stored ``(chunk_l, b_r)`` chunks over the grouped grid's
+        ``n_groups * max_chunks`` steps (ELLPACK-R: each tile's chunks
+        over its ``n_groups * OUT_BLOCKS * n_chunks`` steps), the rest
+        being steps past a group's extent that fetch nothing and skip
+        their compute; 1.0 for CSR, which has no grid."""
+        d = self.dev
+        if self.fmt == "csr":
+            return 1.0
+        if self.fmt == "ellpack_r":
+            n_tiles = d.tile_chunks.shape[0]
+            steps = (-(-n_tiles // OUT_BLOCKS) * OUT_BLOCKS
+                     * (d.val.shape[0] // d.chunk_l))
+            return int(np.asarray(d.tile_chunks).sum()) / max(steps, 1)
+        n_chunks = d.chunk_map.shape[0]
+        n_blocks = d.n_strips if self.fmt == "cmrs" else d.n_blocks
+        n_groups = max(-(-n_blocks // OUT_BLOCKS), 1)
+        return n_chunks / (n_groups * (d.max_chunks or n_chunks) or 1)
+
 
 # Conversion cache: host matrix -> device representation.  Keyed by the
 # host object's id and the build parameters; a weakref callback evicts
@@ -1076,14 +1138,12 @@ def as_device(
     da = max(diag_align, chunk_l)
 
     with obs.span("repro.convert"):
-        fmt = format
-        plan = None
-        if fmt in ("auto", "wsell") and windows_fit(b_r, sigma):
-            plan = F.window_plan(a, 8 * b_r if sigma is None else sigma)
+        fmt, plan = format, None
         if fmt == "auto":
-            fmt = select_format(a, b_r=b_r, diag_align=da, sigma=sigma,
-                                value_dtype=dtype, index_dtype=index_dtype,
-                                plan=plan)
+            fmt, plan = _select(a, b_r=b_r, diag_align=da, sigma=sigma,
+                                value_dtype=dtype, index_dtype=index_dtype)
+        elif fmt == "wsell" and windows_fit(b_r, sigma):
+            plan = F.window_plan(a, 8 * b_r if sigma is None else sigma)
         if fmt == "csr":
             stored = a
         elif fmt == "ellpack_r":

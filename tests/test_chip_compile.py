@@ -13,6 +13,8 @@ and ``jax.default_backend`` is steered to ``"tpu"`` inside each test so
 that ``backend="auto"`` and ``interpret=None`` resolve as they do on
 the chip.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -21,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import formats as F
 from repro.core import matrices as M
 from repro.kernels import ops
-from repro.kernels._backend import OUT_BLOCKS
+from repro.kernels._backend import LANES, OUT_BLOCKS
 
 # sAMG at its published size, stored the way ``ops.as_device`` builds
 # it by default (b_r=128, chunk_l=16): every row block holds one
@@ -199,3 +201,53 @@ def test_auto_backend_resolves_to_compiled_kernels(on_chip):
     # pick the Pallas kernels, compiled (not interpreted)
     assert ops.resolve_backend("auto") == "kernel"
     assert ops.resolve_interpret(None) is False
+
+
+# Graph500 scale 22 as the graph500.pagerank cell draws it: 2,395,575
+# live vertices; P stored as CMRS in 1,152,976 rows of 128 slots
+# (147.6M slots for 128.3M non-zeros), one strip group 116 chunks deep.
+_G500_N = 2_395_575
+_G500_ROWS = 1_152_976
+_G500_GROUP_CHUNKS = 116
+_HLO_LINE = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) [\w\-]+\(([^)]*)\)")
+
+
+def test_pagerank_gathers_x_from_vmem(on_chip):
+    """The gathered formats' RHS gather reads a copy of x padded to whole
+    tiles, and XLA:TPU places that copy in VMEM (memory space S(1)): at
+    graph500-22 a gather from x in HBM took 15.6 ns a slot on a v5e
+    against 8.6, the price ``select_format`` gives every gathered slot."""
+    from repro.core import graph as G
+    from repro.core.operator import DeviceOperator
+
+    def f(val, col, ris, cm, sm, dangling, x):
+        dev = ops.CMRSDevice(val=val, col_idx=col, row_in_strip=ris,
+                             chunk_map=cm, strip_map=sm,
+                             n_strips=-(-_G500_N // _B_R), b_r=_B_R,
+                             chunk_l=_CHUNK_L,
+                             max_chunks=_G500_GROUP_CHUNKS)
+        op = DeviceOperator(ops.SparseDevice(
+            fmt="cmrs", shape=(_G500_N, _G500_N), dev=dev, inv_perm=None))
+        return G.pagerank_steps(G.PageRankOperator(op=op, dangling=dangling),
+                                x, 1)
+    compiled = _compile(
+        f, on_chip, ((_G500_ROWS, _B_R), jnp.float32),
+        ((_G500_ROWS, _B_R), jnp.int32), ((_G500_ROWS, _B_R), jnp.int8),
+        ((_G500_ROWS // _CHUNK_L,), jnp.int32), ((_G500_ROWS,), jnp.int32),
+        ((_G500_N,), jnp.bool_), ((_G500_N,), jnp.float32))
+    shape, operands = {}, {}
+    for line in compiled.as_text().splitlines():
+        hit = _HLO_LINE.match(line)
+        if hit:
+            shape[hit[1]] = hit[2]
+            if "repro.gather_rhs/gather" in line:
+                operands[hit[1]] = [o.strip() for o in hit[3].split(",")]
+    x_pad = -(-_G500_N // (OUT_BLOCKS * LANES)) * OUT_BLOCKS * LANES
+    gathers = [args for name, args in operands.items()
+               if shape[name].startswith(f"f32[{_G500_ROWS * _B_R}]")]
+    assert gathers, "no gather of the slots under repro.gather_rhs"
+    xs = [o for args in gathers for o in args
+          if shape.get(o, "").startswith((f"f32[{_G500_N}]",
+                                          f"f32[{x_pad}]"))]
+    assert xs, "the gather reads no x"
+    assert all("S(1)" in shape[o] for o in xs), [shape[o] for o in xs]

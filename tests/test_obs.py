@@ -168,7 +168,8 @@ def test_operator_build_notes_its_stored_slots():
     try:
         op = operator(M.power_law(700), format="pjds")
         assert obs.gauges() == {"repro.stored_slots": op.stored_slots,
-                                "repro.window_share": 0.0}
+                                "repro.window_share": 0.0,
+                                "repro.grid_fill": op.grid_fill}
         assert set(obs.totals()) == {"repro.convert", "repro.transfer",
                                      "repro.operator.build"}
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
