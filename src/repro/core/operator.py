@@ -238,6 +238,12 @@ class DeviceOperator(SparseOperator):
         return self.dev.window_share
 
     @property
+    def fused_unpermute(self) -> float:
+        """Share of the rows whose unpermute runs inside the kernel
+        (``ops.SparseDevice.fused_unpermute``)."""
+        return self.dev.fused_unpermute
+
+    @property
     def grid_fill(self) -> float:
         """Share of the kernel grid's steps that compute a chunk
         (``ops.SparseDevice.grid_fill``)."""
@@ -335,7 +341,8 @@ def _device_diagonal_stored(sd: ops.SparseDevice) -> jax.Array:
         return blk.reshape(n_pad)[inv][:n]
     if sd.fmt == "wsell":
         n_pad = d.n_rows_pad
-        orig = jnp.zeros(n_pad, jnp.int32).at[d.inv_perm].set(
+        inv = d.row_inv_perm
+        orig = jnp.zeros(n_pad, jnp.int32).at[inv].set(
             jnp.arange(n_pad, dtype=jnp.int32))
         pos = d.row_block[:, None] * d.b_r + jnp.arange(d.b_r,
                                                         dtype=jnp.int32)[None]
@@ -345,7 +352,7 @@ def _device_diagonal_stored(sd: ops.SparseDevice) -> jax.Array:
         rem = jnp.where(d.rem_col.astype(jnp.int32) == orig[d.rem_row],
                         d.rem_val, 0)
         dg = dg + jax.ops.segment_sum(rem, d.rem_row, num_segments=n_pad)
-        return dg[d.inv_perm][:n]
+        return dg[inv][:n]
     if sd.fmt == "cmrs":
         b_r = d.val.shape[1]
         rows = d.strip_map[:, None] * b_r + d.row_in_strip.astype(jnp.int32)
@@ -436,6 +443,11 @@ class DistOperator(SparseOperator):
         return 0.0
 
     @property
+    def fused_unpermute(self) -> float:
+        """The partition unsorts y in XLA, outside its kernels."""
+        return 0.0
+
+    @property
     def grid_fill(self) -> float:
         """Share of the local and remote kernels' grid steps that compute
         a chunk, per device.  Every device's operands are padded to one
@@ -522,10 +534,12 @@ class DistOperator(SparseOperator):
 # Factories
 # --------------------------------------------------------------------------
 def _built(op):
-    """``op``, with its stored slots, its window share and its grid fill
-    noted (``obs.gauge``) as those of the operator built last."""
+    """``op``, with its stored slots, its window share, the share of its
+    rows unpermuted inside the kernel and its grid fill noted
+    (``obs.gauge``) as those of the operator built last."""
     obs.gauge("repro.stored_slots", op.stored_slots)
     obs.gauge("repro.window_share", op.window_share)
+    obs.gauge("repro.fused_unpermute", op.fused_unpermute)
     obs.gauge("repro.grid_fill", op.grid_fill)
     return op
 

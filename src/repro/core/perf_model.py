@@ -356,18 +356,20 @@ def window_rhs_bytes(n_blocks: int, window: int, vec_bytes: int = 4) -> float:
     return float(n_blocks) * window * vec_bytes
 
 
-# Formats whose kernel writes y in a sorted row order (pJDS globally,
-# SELL-C-sigma within sigma windows): y is unpermuted after the kernel.
+# Formats whose rows are stored in a sorted order (pJDS globally,
+# SELL-C-sigma within sigma windows), priced with an unpermute pass.
 SORTED_ROW_FORMATS = ("pjds", "sell", "wsell")
 
 
 def perm_traffic_bytes(n_rows: int, value_bytes: int = 4,
                        index_bytes: int = 4) -> float:
     """Extra HBM traffic of undoing a row sort OUTSIDE the kernel: the
-    permutation index stream plus a read+write pass over y.  Both sorted
-    formats (:data:`SORTED_ROW_FORMATS`) pay it: the TPU compiler has no
-    in-kernel gather, so even SELL-C-sigma's window-local unpermute runs
-    in XLA after the kernel (DESIGN.md §5)."""
+    permutation index stream plus a read+write pass over y.  Every sorted
+    format (:data:`SORTED_ROW_FORMATS`) is charged it; pJDS and SELL
+    unpermute in XLA after the kernel, and the windowed SELL's kernel
+    restores its rows' order itself where sigma divides an output group
+    (``ops.wsell_matvec``), so its charge is an upper bound
+    (DESIGN.md §3, §5)."""
     return float(n_rows) * (2 * value_bytes + index_bytes)
 
 

@@ -24,6 +24,12 @@ Two rules of the TPU compiler shape every kernel here (DESIGN.md §2b):
   a group while the ``(OUT_BLOCKS, b_r)`` output block stays pinned in
   VMEM, and each chunk adds its row sum into its block's sublane
   (``slot``).
+
+Both rules leave one gather that does run inside a kernel: from a
+``(R, LANES)`` array into a tile of one shape, by ``R`` lane gathers and
+selects (:func:`tile_gather`).  The windowed SELL-C-sigma kernel reads
+x that way, and restores its output rows' order that way too
+(``out_perm=`` below).
 """
 from __future__ import annotations
 
@@ -37,7 +43,8 @@ from repro.core.formats import WINDOW_UNIT
 
 __all__ = ["resolve_interpret", "acc_dtype", "chunk_clamp", "gather_rhs",
            "OUT_BLOCKS", "LANES", "VMEM_LIMIT_BYTES", "compiler_params",
-           "group_max_chunks", "grouped_matvec_call", "row_sum"]
+           "group_max_chunks", "grouped_matvec_call", "row_sum",
+           "tile_gather"]
 
 # Row blocks per kernel output block: the sublane height of one f32 tile.
 OUT_BLOCKS = 8
@@ -109,6 +116,21 @@ def gather_rhs(col_idx: jax.Array, x: jax.Array) -> jax.Array:
         return x.T[:, idx]
 
 
+def tile_gather(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table.reshape(-1)[idx]`` inside a kernel, for a ``(R, LANES)``
+    ``table`` and an int32 tile ``idx`` of flat positions in it: for each
+    row r of ``table``, one lane gather of row r broadcast to ``idx``'s
+    shape, kept where ``idx`` lies in row r.  ``R`` in-tile shuffles and
+    selects, the only gather Mosaic lowers (DESIGN.md §2)."""
+    lane = idx & (LANES - 1)
+    row = idx >> 7                         # LANES == 2 ** 7
+    out = jnp.zeros(idx.shape, table.dtype)
+    for r in range(table.shape[0]):
+        tr = jnp.broadcast_to(table[r:r + 1, :], idx.shape)
+        out = jnp.where(row == r, jnp.take_along_axis(tr, lane, axis=1), out)
+    return out
+
+
 def group_max_chunks(chunk_map) -> int:
     """Static chunk ceiling of any output group (``OUT_BLOCKS`` row blocks
     sharing one output block): the chunk-axis extent of the grouped
@@ -136,7 +158,7 @@ def _group_extents(chunk_map: jax.Array, n_groups: int):
 def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
                         chunk_l: int, max_chunks: int | None, dt,
                         interpret: bool | None, name: str,
-                        window=None) -> jax.Array:
+                        window=None, out_perm=None) -> jax.Array:
     """The grouped Pallas grid shared by the pJDS/SELL, CMRS and pJDS
     multi-RHS kernels.
 
@@ -164,6 +186,17 @@ def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
     fetched by element offset and passed to ``reduce_rows`` after the
     tiles.  Pallas skips the fetch while consecutive steps share a
     window.
+
+    ``out_perm``, for a single RHS whose rows are sorted only inside
+    windows that never cross an output group, is ``(inv_perm, addend)``:
+    two ``(n_groups * OUT_BLOCKS * b_r,)`` arrays, the storage position
+    of each original row (identity past the rows) and a dtype-``dt``
+    start for y in storage order.  Each rides one ``(OUT_BLOCKS, b_r)``
+    block per group, fetched once.  A group's output block starts from
+    its ``addend`` block instead of zeros, and its last grid step
+    (``c == max_chunks - 1``, which every group runs) rewrites the block
+    in original row order by :func:`tile_gather`, so y leaves the kernel
+    in original order.  Without it the call is the plain grouped grid.
     """
     total, b_r = streams[0].shape
     if total % chunk_l:
@@ -175,21 +208,33 @@ def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
     start, cnt, slot = _group_extents(chunk_map, n_groups)
 
     n_pre = 3 if window is None else 4
+    group_rows = OUT_BLOCKS * b_r
 
     def kernel(start_ref, cnt_ref, slot_ref, *refs):
         *in_refs, y_ref = refs[n_pre - 3:]
+        if out_perm is not None:
+            *in_refs, perm_ref, add_ref = in_refs
         g = pl.program_id(0)
         c = pl.program_id(1)
 
         @pl.when(c == 0)
         def _init():
-            y_ref[...] = jnp.zeros_like(y_ref)
+            if out_perm is None:
+                y_ref[...] = jnp.zeros_like(y_ref)
+            else:
+                y_ref[...] = add_ref[...]
 
         @pl.when(c < cnt_ref[g])
         def _body():
             s = slot_ref[start_ref[g] + c]
             y_ref[..., pl.ds(s, 1), :] += reduce_rows(
                 *(r[...] for r in in_refs))
+
+        if out_perm is not None:
+            @pl.when(c == pl.num_programs(1) - 1)
+            def _unpermute():
+                y_ref[...] = tile_gather(y_ref[...],
+                                         perm_ref[...] - g * group_rows)
 
     def spec(a):
         pre = (0,) * (a.ndim - 2)
@@ -213,6 +258,18 @@ def grouped_matvec_call(reduce_rows, streams, chunk_map, *, n_blocks: int,
         in_specs.append(pl.BlockSpec(
             (pl.Element(wrows), pl.Element(LANES)), window_at))
         inputs.append(xw)
+    if out_perm is not None:
+        if lead or b_r != LANES:
+            raise ValueError(f"out_perm needs a single RHS and b_r == "
+                             f"{LANES}")
+        for a in out_perm:
+            if a.shape != (n_groups * group_rows,):
+                raise ValueError(f"out_perm arrays must hold "
+                                 f"{n_groups * group_rows} rows; got "
+                                 f"{a.shape}")
+            in_specs.append(pl.BlockSpec((OUT_BLOCKS, b_r),
+                                         lambda g, c, *_: (g, 0)))
+            inputs.append(a.reshape(-1, b_r))
     pre = (0,) * len(lead)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pre,

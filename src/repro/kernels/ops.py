@@ -40,7 +40,8 @@ from repro import obs
 from repro.core import formats as F
 from repro.core import perf_model as PM
 from . import ref as R
-from ._backend import LANES, OUT_BLOCKS, group_max_chunks, resolve_interpret
+from ._backend import (LANES, OUT_BLOCKS, acc_dtype, group_max_chunks,
+                       resolve_interpret)
 from .pjds_spmv import pjds_matvec_kernel_call
 from .pjds_spmm import pjds_matmat_kernel_call
 from .ellr_spmv import ell_matvec_kernel_call
@@ -175,14 +176,16 @@ class WSELLDevice:
     out-of-window remainder.  ``val`` holds the slots' values in its
     first ``col_off.shape[0]`` rows and the remainder's values after
     them, row-major and zero-padded, so that one value leaf carries
-    every stored value (``DeviceOperator.values``)."""
+    every stored value (``DeviceOperator.values``).  ``inv_perm`` runs
+    on to whole kernel output groups (identity past ``n_rows_pad``), as
+    the kernel's in-group unpermute reads it."""
 
     val: jax.Array                     # (total + tail, b_r)
     col_off: jax.Array                 # (total, b_r) int16, window-local
     chunk_map: jax.Array               # (total // chunk_l,) int32
     row_block: jax.Array               # (total,) int32 (for the ref)
     wbase: jax.Array                   # (n_blocks,) int32, WINDOW_UNITs
-    inv_perm: jax.Array                # (n_blocks * b_r,) int32
+    inv_perm: jax.Array                # (n_groups * OUT_BLOCKS * b_r,) int32
     rem_row: jax.Array                 # (n_rem,) int32 storage row, sorted
     rem_col: jax.Array                 # (n_rem,) global column
     n_blocks: int = dataclasses.field(metadata=dict(static=True))
@@ -209,6 +212,18 @@ class WSELLDevice:
         """The remainder's values, ``(n_rem,)``."""
         tail = self.val[self.col_off.shape[0]:].reshape(-1)
         return tail[: self.rem_row.shape[0]]
+
+    @property
+    def row_inv_perm(self) -> jax.Array:
+        """``inv_perm`` over the padded rows alone, ``(n_rows_pad,)``."""
+        return self.inv_perm[: self.n_rows_pad]
+
+    @property
+    def fuses_unpermute(self) -> bool:
+        """Whether the kernel restores the row order itself: every
+        sigma-window lies in one output group of ``OUT_BLOCKS * b_r``
+        rows."""
+        return (OUT_BLOCKS * self.b_r) % self.sigma == 0
 
     def columns(self) -> jax.Array:
         """The slots' global columns, rebuilt in XLA (not stored)."""
@@ -352,13 +367,17 @@ def to_device_wsell(w: F.WindowedSELLMatrix, chunk_l: int = 8,
     val = np.concatenate([w.val, rem.reshape(tail, w.b_r)])
     if dtype is not None:
         val = val.astype(dtype)
+    group = OUT_BLOCKS * w.b_r
+    n_out = max(-(-w.n_rows_pad // group), 1) * group
+    inv_perm = np.concatenate([w.inv_perm, np.arange(
+        w.n_rows_pad, n_out, dtype=w.inv_perm.dtype)])
     return WSELLDevice(
         val=jnp.asarray(val),
         col_off=jnp.asarray(w.col_off),
         chunk_map=jnp.asarray(chunk_map),
         row_block=jnp.asarray(row_block),
         wbase=jnp.asarray(w.wbase),
-        inv_perm=jnp.asarray(w.inv_perm),
+        inv_perm=jnp.asarray(inv_perm),
         rem_row=jnp.asarray(w.rem_row),
         rem_col=jnp.asarray(w.rem_col),
         n_blocks=w.n_blocks,
@@ -464,36 +483,55 @@ def _window_x(x: jax.Array, x_len: int) -> jax.Array:
     return jnp.pad(x, [(0, x_len - n)] + [(0, 0)] * (x.ndim - 1))
 
 
+def _remainder(a: WSELLDevice, x: jax.Array, n_rows: int, dt) -> jax.Array:
+    """The remainder's products in storage order, ``(n_rows[, k])``, under
+    the device scope ``repro.gather_rhs``: the one XLA gather of x
+    left."""
+    with jax.named_scope("repro.gather_rhs"):
+        return R.remainder_matvec_ref(a.rem_val, a.rem_row, a.rem_col, x,
+                                      n_rows).astype(dt)
+
+
 def _add_remainder(a: WSELLDevice, y: jax.Array, x: jax.Array) -> jax.Array:
-    """y (storage order) plus the remainder's products, under the device
-    scope ``repro.gather_rhs``: the one XLA gather of x left."""
+    """y (storage order) plus the remainder's products."""
     if not a.rem_row.shape[0]:
         return y
-    with jax.named_scope("repro.gather_rhs"):
-        return y + R.remainder_matvec_ref(a.rem_val, a.rem_row, a.rem_col,
-                                          x, a.n_rows_pad).astype(y.dtype)
+    return y + _remainder(a, x, a.n_rows_pad, y.dtype)
 
 
 def wsell_matvec(a: WSELLDevice, x: jax.Array,
                  backend: Backend = "ref") -> jax.Array:
     """y = A x with rows back in the ORIGINAL order; y has n_rows_pad
     entries.  The kernel path gathers the in-window slots inside the
-    kernel (``wsell_spmv``), adds the remainder in storage order, then
-    runs SELL's window-local unpermute.  The ref path rebuilds the
+    kernel (``wsell_spmv``).  Where every sigma-window lies in one
+    output group (``fuses_unpermute``), each group starts from the
+    remainder's rows and the kernel restores the row order itself;
+    otherwise the remainder is added in storage order and SELL's
+    window-local unpermute runs in XLA.  The ref path rebuilds the
     slots' global columns and runs the pJDS ref."""
     xp = _window_x(x, a.x_len)
     if resolve_backend(backend) == "kernel":
         xw = xp.astype(jnp.promote_types(xp.dtype, jnp.float32))
+        out_perm = None
+        if a.fuses_unpermute:
+            dt = acc_dtype(a.val.dtype, xw.dtype)
+            n_out = a.inv_perm.shape[0]
+            addend = (_remainder(a, x, n_out, dt) if a.rem_row.shape[0]
+                      else jnp.zeros(n_out, dt))
+            out_perm = (a.inv_perm, addend)
         y = wsell_matvec_kernel_call(
             a.val, a.col_off, a.chunk_map, a.wbase,
             xw.reshape(-1, LANES), n_blocks=a.n_blocks,
-            window=a.window, chunk_l=a.chunk_l, max_chunks=a.max_chunks)
+            window=a.window, chunk_l=a.chunk_l, max_chunks=a.max_chunks,
+            out_perm=out_perm)
+        if out_perm is not None:
+            return y
     else:
         y = R.pjds_matvec_ref(a.slot_val, a.columns(), a.row_block, xp,
                               a.n_blocks)
     y = _add_remainder(a, y, x)
     with jax.named_scope("repro.unpermute"):
-        return y[a.inv_perm]
+        return y[a.row_inv_perm]
 
 
 def wsell_matmat(a: WSELLDevice, x: jax.Array,
@@ -506,7 +544,7 @@ def wsell_matmat(a: WSELLDevice, x: jax.Array,
                     max_chunks=a.max_chunks)
     y = _add_remainder(a, pjds_matmat(pj, _window_x(x, a.x_len), backend), x)
     with jax.named_scope("repro.unpermute"):
-        return y[a.inv_perm]
+        return y[a.row_inv_perm]
 
 
 def wsell_rmatmat(a: WSELLDevice, y_p: jax.Array, n_cols: int) -> jax.Array:
@@ -882,7 +920,7 @@ class SparseDevice:
             return R.blocked_rmatvec_ref(d.val, d.col_idx, d.row_block,
                                          y_p, n_cols)
         if self.fmt == "wsell":
-            y_p = self._scatter_to_storage(y, self.dev.inv_perm)
+            y_p = self._scatter_to_storage(y, self.dev.row_inv_perm)
             return wsell_rmatmat(self.dev, y_p, n_cols)
         if self.fmt == "cmrs":
             d = self.dev
@@ -928,6 +966,13 @@ class SparseDevice:
         """Share of the matrix's non-zeros the apply serves from a window
         of x inside the kernel: 0 for every operand without windows."""
         return self.dev.window_share if self.fmt == "wsell" else 0.0
+
+    @property
+    def fused_unpermute(self) -> float:
+        """Share of the rows whose unpermute the kernel path runs inside
+        the kernel: 1 for a windowed SELL whose sigma-windows lie in
+        single output groups, 0 for every other operand."""
+        return float(self.fmt == "wsell" and self.dev.fuses_unpermute)
 
     @property
     def grid_fill(self) -> float:

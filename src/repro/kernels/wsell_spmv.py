@@ -20,6 +20,11 @@ local (``formats.WindowedSELLMatrix``):
   in-tile shuffles per chunk, no gathered operand in HBM.
 * Then the pJDS chunk reduction (``_backend.row_sum``) runs as before,
   on pJDS's grouped grid.
+* Given ``out_perm``, each output group starts from the remainder's
+  rows and ends by restoring its rows' original order with the same
+  in-tile shuffles (``_backend.grouped_matvec_call(out_perm=)``): the
+  rows are sorted only inside sigma-windows, and where sigma divides
+  the group's ``OUT_BLOCKS * b_r`` rows no row leaves its group.
 
 Non-zeros outside their block's window are not in the slots; the caller
 adds them as an XLA remainder (``ops.wsell_matvec``).  Padding slots
@@ -32,26 +37,19 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ._backend import LANES, acc_dtype, grouped_matvec_call, row_sum
+from ._backend import (LANES, acc_dtype, grouped_matvec_call, row_sum,
+                       tile_gather)
 
 __all__ = ["wsell_matvec_kernel_call"]
 
 
-def _window_row_sum(dt, wrows: int):
+def _window_row_sum(dt):
     """``reduce_rows`` of the windowed kernel: gather each slot's x from
     the block's ``(wrows, LANES)`` window, then reduce over sublanes."""
     reduce = row_sum(dt)
 
     def reduce_rows(off, val, xw):
-        idx = off.astype(jnp.int32)
-        lane = idx & (LANES - 1)
-        row = idx >> 7                     # LANES == 2 ** 7
-        xg = jnp.zeros(idx.shape, xw.dtype)
-        for r in range(wrows):
-            xr = jnp.broadcast_to(xw[r:r + 1, :], idx.shape)
-            xg = jnp.where(row == r, jnp.take_along_axis(xr, lane, axis=1),
-                           xg)
-        return reduce(val, xg)
+        return reduce(val, tile_gather(xw, off.astype(jnp.int32)))
     return reduce_rows
 
 
@@ -70,8 +68,10 @@ def wsell_matvec_kernel_call(
     chunk_l: int = 8,
     max_chunks: int | None = None,
     interpret: bool | None = None,
+    out_perm: tuple[jax.Array, jax.Array] | None = None,
 ) -> jax.Array:
-    """y = A_in x over the in-window slots, in storage row order.
+    """y = A_in x over the in-window slots, in storage row order, or
+    given ``out_perm``, ``addend + A_in x`` in original row order.
 
     val:       (total[+ tail], b_r) values; rows past ``col_off``'s are
                not read (the operand keeps its remainder's values there).
@@ -84,6 +84,10 @@ def wsell_matvec_kernel_call(
                long enough for every window.
     window:    static window width in entries, a multiple of
                ``formats.WINDOW_UNIT``.
+    out_perm:  ``(inv_perm, addend)``, each ``(n_groups * OUT_BLOCKS *
+               b_r,)``: every original row's storage position, sorted
+               only inside its output group, and y's start in storage
+               order, in the accumulator dtype.
     Returns y: (n_blocks * b_r,) in the accumulator dtype.
     """
     if col_off.shape[1] != LANES:
@@ -92,6 +96,7 @@ def wsell_matvec_kernel_call(
     dt = acc_dtype(val.dtype, xw.dtype)
     wrows = window // LANES
     return grouped_matvec_call(
-        _window_row_sum(dt, wrows), (col_off, val), chunk_map,
+        _window_row_sum(dt), (col_off, val), chunk_map,
         n_blocks=n_blocks, chunk_l=chunk_l, max_chunks=max_chunks, dt=dt,
-        interpret=interpret, name="wsell_spmv", window=(wbase, xw, wrows))
+        interpret=interpret, name="wsell_spmv", window=(wbase, xw, wrows),
+        out_perm=out_perm)

@@ -46,6 +46,8 @@ _WINDOW = 3 * F.WINDOW_UNIT
 _X_LEN = -(-_N // F.WINDOW_UNIT) * F.WINDOW_UNIT
 _N_REM = 1_000_000
 _REM_TAIL = -(-_N_REM // (_CHUNK_L * _B_R)) * _CHUNK_L
+# its inverse permutation runs on to whole output groups of 8 row blocks
+_N_OUT = -(-_N_BLOCKS // OUT_BLOCKS) * OUT_BLOCKS * _B_R
 
 _POLICIES = [
     pytest.param(jnp.float32, jnp.int32, id="f32+int32"),
@@ -89,6 +91,20 @@ def _compile(fn, sharding, *shapes):
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
     return compiled
+
+
+def _entry_root(text):
+    """The ROOT instruction of a compiled program's entry computation."""
+    body = text[text.index("\nENTRY "):]
+    return next(line for line in body.splitlines()
+                if line.lstrip().startswith("ROOT "))
+
+
+def _kernel_operands(text, name):
+    """The operands of the Pallas kernel ``name``'s custom call."""
+    hit = re.search(rf"%{name}[\w.]* = \S+ custom-call\(([^)]*)\)", text)
+    assert hit, f"no {name} kernel in the program"
+    return re.sub(r"/\*[^*]*\*/", "", hit[1]).split(",")
 
 
 def _blocked_shapes(vdt, idt, chunk_l=_CHUNK_L):
@@ -141,7 +157,7 @@ def test_wsell_spmv_compiles(on_chip, vdt, idt):
     compiled = _compile(
         f, on_chip, ((_TOTAL + _REM_TAIL, _B_R), vdt),
         ((_TOTAL, _B_R), jnp.int16), ((_TOTAL // _CHUNK_L,), jnp.int32),
-        ((_N_BLOCKS,), jnp.int32), ((_N_PAD,), jnp.int32),
+        ((_N_BLOCKS,), jnp.int32), ((_N_OUT,), jnp.int32),
         # 3.4M columns need int32 remainder columns under either policy;
         # the window offsets are int16 under both
         ((_N_REM,), jnp.int32), ((_N_REM,), jnp.int32), ((_N,), jnp.float32))
@@ -150,6 +166,12 @@ def test_wsell_spmv_compiles(on_chip, vdt, idt):
     assert "wsell_spmv" in text
     assert f"f32[{_TOTAL * _B_R}]" not in text
     assert f"f32[{_TOTAL},{_B_R}]" not in text
+    # y leaves the kernel in original row order: the program ends in the
+    # kernel's output slice, and no XLA gather makes a y after it
+    root = _entry_root(text)
+    assert re.search(r"\(%wsell_spmv[\w.]*\)", root), root
+    assert not [line for line in text.splitlines()
+                if f"= f32[{_N_PAD}]" in line and "gather" in line]
 
 
 @pytest.mark.parametrize("vdt,idt", _POLICIES)
@@ -251,3 +273,21 @@ def test_pagerank_gathers_x_from_vmem(on_chip):
                                           f"f32[{x_pad}]"))]
     assert xs, "the gather reads no x"
     assert all("S(1)" in shape[o] for o in xs), [shape[o] for o in xs]
+
+
+def test_cmrs_kernel_takes_no_epilogue(on_chip):
+    """The grouped grid's row-order epilogue is opt-in: at graph500-22's
+    shapes the CMRS kernel takes its three scalar-prefetched extents and
+    its three streams (values, gathered x, row routing), nothing more."""
+    def f(val, col, ris, cm, x):
+        dev = ops.CMRSDevice(val=val, col_idx=col, row_in_strip=ris,
+                             chunk_map=cm, strip_map=cm,
+                             n_strips=-(-_G500_N // _B_R), b_r=_B_R,
+                             chunk_l=_CHUNK_L,
+                             max_chunks=_G500_GROUP_CHUNKS)
+        return ops.cmrs_matvec(dev, x, backend="auto")
+    compiled = _compile(
+        f, on_chip, ((_G500_ROWS, _B_R), jnp.float32),
+        ((_G500_ROWS, _B_R), jnp.int32), ((_G500_ROWS, _B_R), jnp.int8),
+        ((_G500_ROWS // _CHUNK_L,), jnp.int32), ((_G500_N,), jnp.float32))
+    assert len(_kernel_operands(compiled.as_text(), "cmrs_spmv")) == 6

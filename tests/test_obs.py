@@ -169,6 +169,7 @@ def test_operator_build_notes_its_stored_slots():
         op = operator(M.power_law(700), format="pjds")
         assert obs.gauges() == {"repro.stored_slots": op.stored_slots,
                                 "repro.window_share": 0.0,
+                                "repro.fused_unpermute": 0.0,
                                 "repro.grid_fill": op.grid_fill}
         assert set(obs.totals()) == {"repro.convert", "repro.transfer",
                                      "repro.operator.build"}
@@ -177,5 +178,6 @@ def test_operator_build_notes_its_stored_slots():
         dop = dist_operator(M.poisson_2d(12, 12), mesh, transpose=None)
         assert obs.gauges()["repro.stored_slots"] == dop.stored_slots
         assert obs.gauges()["repro.window_share"] == 0.0
+        assert obs.gauges()["repro.fused_unpermute"] == 0.0
     finally:
         obs.reset()

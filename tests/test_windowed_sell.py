@@ -187,6 +187,99 @@ def test_values_carry_the_remainder(mats):
         np.asarray(x)[np.asarray(d.rem_col)], rtol=1e-6)
 
 
+# ------------------------------------------------------ in-kernel unpermute
+# The kernel restores the row order inside each output group of
+# OUT_BLOCKS * 128 = 1024 rows where sigma divides it, and leaves it to
+# XLA otherwise; the remainder is each group's starting value.
+UNPERMUTE_CASES = {
+    # 4 full groups and a partial fifth, 5% of the entries far away
+    "partial_group": lambda: _banded(5000, 40, far_share=0.05, seed=12),
+    # whole groups only
+    "whole_groups": lambda: _banded(5120, 30, far_share=0.05, seed=13),
+    # x fits one window: an empty remainder
+    "no_remainder": lambda: _banded(1500, 20, seed=14),
+}
+
+
+@pytest.fixture(scope="module")
+def unpermute_mats():
+    return {k: f() for k, f in UNPERMUTE_CASES.items()}
+
+
+def _stored(dense, dtype):
+    """``dense`` with its values rounded as the operand stores them."""
+    if dtype is None:
+        return dense.astype(np.float64)
+    return np.asarray(jnp.asarray(dense).astype(dtype).astype(jnp.float32),
+                      np.float64)
+
+
+@pytest.mark.parametrize("dtype,idt", POLICIES)
+@pytest.mark.parametrize("case", sorted(UNPERMUTE_CASES))
+@pytest.mark.parametrize("sigma", [128, 512, 1024, 2048, 4096])
+def test_kernel_unpermute_matches_ref_and_dense(unpermute_mats, sigma, case,
+                                                dtype, idt):
+    m = unpermute_mats[case]
+    kw = dict(format="wsell", sigma=sigma, dtype=dtype, index_dtype=idt)
+    op = operator(m, backend="kernel", **kw)
+    d = op.dev.dev
+    assert d.sigma == sigma and d.fuses_unpermute == (sigma <= 1024)
+    assert d.inv_perm.shape[0] % 1024 == 0
+    assert (d.rem_row.shape[0] == 0) == (case == "no_remainder")
+    x = jnp.asarray(np.random.default_rng(15).standard_normal(m.shape[1]),
+                    jnp.float32)
+    y = op @ x
+    _close(y, np.asarray(operator(m, backend="ref", **kw) @ x, np.float64))
+    w = F.csr_to_wsell(m, sigma=sigma, diag_align=16)
+    _close(y, _stored(F.wsell_to_dense(w), dtype) @ np.asarray(x, np.float64))
+    # the fused apply runs no XLA unpermute; the others keep theirs
+    hlo = jax.jit(lambda v: op.matvec(v)).lower(x).as_text(debug_info=True)
+    assert ("repro.unpermute" in hlo) == (sigma > 1024)
+
+
+@pytest.mark.parametrize("sigma", [512, 4096])
+def test_fused_operator_keeps_its_other_applies(unpermute_mats, sigma):
+    """matmat, rmatvec, the diagonal, a value swap and the gradient read
+    the group-padded inverse permutation as they read the plain one."""
+    m = unpermute_mats["partial_group"]
+    op = operator(m, format="wsell", backend="kernel", sigma=sigma)
+    a = F.csr_to_dense(m).astype(np.float64)
+    rng = np.random.default_rng(16)
+    xs = rng.standard_normal((m.shape[1], 2)).astype(np.float32)
+    _close(op @ jnp.asarray(xs), a @ xs)
+    y = rng.standard_normal(m.shape[0]).astype(np.float32)
+    _close(op.rmatvec(jnp.asarray(y)), a.T @ y)
+    np.testing.assert_allclose(np.asarray(op.diagonal()), np.diag(a),
+                               rtol=1e-6)
+    x = jnp.asarray(xs[:, 0])
+    _close(op.with_values(2 * op.values) @ x, 2 * a @ xs[:, 0])
+    g = jax.grad(lambda v: jnp.sum(op.with_values(v) @ x))(op.values)
+    # d(sum A x)/d(a_ij) = x_j: the slots' and the remainder's alike
+    d = op.dev.dev
+    cols = np.asarray(d.columns())
+    slot_g = np.asarray(g[: d.col_off.shape[0]])
+    live = np.asarray(d.slot_val) != 0
+    np.testing.assert_allclose(slot_g[live], np.asarray(x)[cols[live]],
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(dataclasses.replace(d, val=g).rem_val),
+        np.asarray(x)[np.asarray(d.rem_col)], rtol=1e-6)
+
+
+@pytest.mark.parametrize("fmt,kw,share", [
+    ("wsell", dict(sigma=128), 1.0),
+    ("wsell", dict(sigma=1024), 1.0),
+    ("wsell", dict(sigma=4096), 0.0),
+    ("sell", {}, 0.0),
+    ("pjds", {}, 0.0),
+    ("cmrs", {}, 0.0),
+])
+def test_fused_unpermute_gauge(unpermute_mats, fresh_obs, fmt, kw, share):
+    op = operator(unpermute_mats["whole_groups"], format=fmt, **kw)
+    assert op.fused_unpermute == share
+    assert fresh_obs.gauges()["repro.fused_unpermute"] == share
+
+
 # --------------------------------------------------------------- engagement
 ENGAGED = {
     "samg_like": lambda: M.samg(0.006),        # band 50, 5% far couplings
