@@ -48,15 +48,13 @@ def _stored_bytes(sd: ops.SparseDevice) -> int:
     index stream at their ACTUAL dtypes + per-format metadata arrays."""
     d = sd.dev
     if sd.fmt == "csr":
-        return d.data.nbytes + d.indices.nbytes + d.row_ids.nbytes
+        return d.val.nbytes + d.indices.nbytes + d.row_ids.nbytes
     n = d.val.nbytes + d.col_idx.nbytes
     if sd.fmt == "ellpack_r":
         return n + d.rowlen.nbytes + d.tile_chunks.nbytes
     n += d.chunk_map.nbytes
-    if sd.fmt == "sell":
+    if sd.fmt in ("sell", "pjds"):
         n += d.inv_perm.nbytes
-    elif sd.inv_perm is not None:
-        n += sd.inv_perm.nbytes
     return n
 
 
@@ -74,7 +72,7 @@ def bytes_per_nnz_rows(m, x, truth, mat: str, fmt: str, rows: list,
         ib = sd.index_dtype.itemsize
         # vectors stay f32 whatever the stored width (vec_bytes default)
         pred_s = PM.predicted_spmv_seconds(
-            sd.stored_slots, n, n_nzr,
+            sd.dev.stored_slots, n, n_nzr,
             perm_bytes=(PM.perm_traffic_bytes(n, 4)
                         if fmt in PM.SORTED_ROW_FORMATS else 0.0),
             value_bytes=vb, index_bytes=ib)

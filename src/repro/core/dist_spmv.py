@@ -90,7 +90,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 from typing import Literal
 
 import jax
@@ -106,9 +105,8 @@ from repro.kernels._backend import group_max_chunks
 Mode = Literal["vector", "naive", "overlap", "pipeline"]
 Halo = Literal["gathered", "full"]
 
-__all__ = ["DistPJDS", "partition_csr", "dist_matvec", "make_dist_matvec",
-           "dist_matmat", "make_dist_matmat", "padded_global_size",
-           "halo_distances", "grid_shapes"]
+__all__ = ["DistPJDS", "partition_csr", "dist_matvec", "dist_matmat",
+           "padded_global_size", "halo_distances", "grid_shapes"]
 
 
 def halo_distances(w: int) -> list[int]:
@@ -879,49 +877,6 @@ def _make_dist_op(dist: DistPJDS, mesh: Mesh, axis: str, mode: Mode,
                                  backend=backend, halo=halo)
 
     return functools.partial(_mv, dist)
-
-
-def make_dist_matvec(dist: DistPJDS, mesh: Mesh, axis: str = "data",
-                     mode: Mode = "overlap",
-                     backend: ops.Backend = "ref",
-                     halo: Halo = "gathered"):
-    """Build a jit-able y = A x over a mesh axis.  x: (n_global_pad,)
-    sharded along ``axis``; returns y with the same sharding.
-
-    .. deprecated::
-        Kept as the raw closure under the operator protocol — new code
-        should build ``core.operator.dist_operator(m, mesh)`` instead,
-        which wraps this exact function and adds ``op.T`` (transposed
-        partition), ``diagonal()`` for Jacobi preconditioning, and
-        x-gradients.  ``backend="auto"`` resolves in
-        ``kernels.ops.resolve_backend``.
-    """
-    warnings.warn(
-        "make_dist_matvec is deprecated: use "
-        "core.operator.dist_operator(m, mesh) — the operator wraps this "
-        "closure and adds .T, diagonal() and gradients — or repro.solve "
-        "for whole systems", DeprecationWarning, stacklevel=2)
-    return _make_dist_op(dist, mesh, axis, mode, backend, halo,
-                         multi_rhs=False)
-
-
-def make_dist_matmat(dist: DistPJDS, mesh: Mesh, axis: str = "data",
-                     mode: Mode = "overlap",
-                     backend: ops.Backend = "ref",
-                     halo: Halo = "gathered"):
-    """Build a jit-able Y = A X for a block of RHS vectors.
-    X: (n_global_pad, k) sharded (axis, None); returns Y alike.
-
-    .. deprecated::
-        Shim — see :func:`make_dist_matvec`; prefer
-        ``core.operator.dist_operator(m, mesh).matmat``.
-    """
-    warnings.warn(
-        "make_dist_matmat is deprecated: use "
-        "core.operator.dist_operator(m, mesh).matmat instead",
-        DeprecationWarning, stacklevel=2)
-    return _make_dist_op(dist, mesh, axis, mode, backend, halo,
-                         multi_rhs=True)
 
 
 def dist_matvec(dist: DistPJDS, x: jax.Array, mesh: Mesh, axis: str = "data",

@@ -23,8 +23,9 @@ promise as an API: every operator — whatever lives inside — offers
 Two implementations cover the repo's stacks:
 
 * :class:`DeviceOperator` — wraps the dispatch layer's
-  ``kernels.ops.SparseDevice`` (CSR / ELLPACK-R / pJDS / SELL-C-sigma,
-  chosen by ``format="auto"``).  Build with :func:`operator`.
+  ``kernels.ops.SparseDevice`` (CSR / ELLPACK-R / pJDS / SELL-C-sigma /
+  windowed SELL-C-sigma / CMRS, chosen by ``format="auto"``).  Build
+  with :func:`operator`.
 * :class:`DistOperator` — wraps ``core.dist_spmv`` (row-partitioned
   SELL-windowed storage + gathered halo exchange over a mesh axis).
   Build with :func:`dist_operator`.  Its transpose is the transposed
@@ -229,40 +230,37 @@ class DeviceOperator(SparseOperator):
     @property
     def stored_slots(self) -> int:
         """Value slots one forward apply streams, padding included."""
-        return self.dev.stored_slots
+        return self.dev.dev.stored_slots
 
     @property
     def window_share(self) -> float:
         """Share of the non-zeros served from a window of x inside the
-        kernel (``ops.SparseDevice.window_share``)."""
-        return self.dev.window_share
+        kernel (``ops._Operand.window_share``)."""
+        return self.dev.dev.window_share
 
     @property
     def fused_unpermute(self) -> float:
         """Share of the rows whose unpermute runs inside the kernel
-        (``ops.SparseDevice.fused_unpermute``)."""
-        return self.dev.fused_unpermute
+        (``ops._Operand.fused_unpermute``)."""
+        return self.dev.dev.fused_unpermute
 
     @property
     def grid_fill(self) -> float:
         """Share of the kernel grid's steps that compute a chunk
-        (``ops.SparseDevice.grid_fill``)."""
-        return self.dev.grid_fill
+        (``ops._Operand.grid_fill``)."""
+        return self.dev.dev.grid_fill
 
     @property
     def values(self) -> jax.Array:
         """The stored value leaf (the differentiable parameters)."""
-        d = self.dev.dev
-        return d.data if self.dev.fmt == "csr" else d.val
+        return self.dev.dev.val
 
     def with_values(self, val: jax.Array) -> "DeviceOperator":
         """Same sparsity structure, new stored values — the handle
         ``jax.grad`` differentiates through:
         ``jax.grad(lambda v: loss(op.with_values(v) @ x))(op.values)``.
         Drops any ``t_dev`` (its values live in transposed order)."""
-        inner = self.dev.dev
-        field = "data" if self.dev.fmt == "csr" else "val"
-        inner = dataclasses.replace(inner, **{field: val})
+        inner = dataclasses.replace(self.dev.dev, val=val)
         return DeviceOperator(dataclasses.replace(self.dev, dev=inner),
                               backend=self.backend)
 
@@ -303,64 +301,14 @@ class DeviceOperator(SparseOperator):
 
 
 def _device_diagonal(sd: ops.SparseDevice) -> jax.Array:
-    """diag(A) straight from the device layout (no host matrix needed):
-    mask each stored entry on ``column == original row`` and reduce with
-    the same segment structure the matvec uses.  A preprocessing
-    permutation (``reorder=``) stores B = P A P^T, whose diagonal is
-    diag(A) permuted — ``diag(A) = diag(B)[pre_inv]``."""
-    dg = _device_diagonal_stored(sd)
+    """diag(A) straight from the device layout (no host matrix needed;
+    the operand's ``diagonal``).  A preprocessing permutation
+    (``reorder=``) stores B = P A P^T, whose diagonal is diag(A)
+    permuted — ``diag(A) = diag(B)[pre_inv]``."""
+    dg = sd.dev.diagonal(sd.shape[0])
     if sd.pre_inv is not None:
         dg = dg[sd.pre_inv]
     return dg
-
-
-def _device_diagonal_stored(sd: ops.SparseDevice) -> jax.Array:
-    n = sd.shape[0]
-    d = sd.dev
-    if sd.fmt == "csr":
-        keep = jnp.where(d.indices == d.row_ids, d.data, 0)
-        return jax.ops.segment_sum(keep, d.row_ids, num_segments=n)
-    if sd.fmt == "ellpack_r":
-        rows = jnp.arange(d.val.shape[1], dtype=jnp.int32)[None, :]
-        j = jnp.arange(d.val.shape[0], dtype=jnp.int32)[:, None]
-        mask = (d.col_idx == rows) & (j < d.rowlen[None, :])
-        return jnp.where(mask, d.val, 0).sum(axis=0)[:n]
-    if sd.fmt in ("sell", "pjds"):
-        inv = d.inv_perm if sd.fmt == "sell" else sd.inv_perm
-        n_pad = inv.shape[0]
-        b_r = d.val.shape[1]
-        # original row index of each storage (permuted) position
-        orig = jnp.zeros(n_pad, jnp.int32).at[inv].set(
-            jnp.arange(n_pad, dtype=jnp.int32))
-        pos = d.row_block[:, None] * b_r + jnp.arange(b_r,
-                                                      dtype=jnp.int32)[None]
-        mask = d.col_idx == orig[pos]
-        keep = jnp.where(mask, d.val, 0)
-        blk = jax.ops.segment_sum(keep, d.row_block,
-                                  num_segments=int(n_pad // b_r))
-        return blk.reshape(n_pad)[inv][:n]
-    if sd.fmt == "wsell":
-        n_pad = d.n_rows_pad
-        inv = d.row_inv_perm
-        orig = jnp.zeros(n_pad, jnp.int32).at[inv].set(
-            jnp.arange(n_pad, dtype=jnp.int32))
-        pos = d.row_block[:, None] * d.b_r + jnp.arange(d.b_r,
-                                                        dtype=jnp.int32)[None]
-        keep = jnp.where(d.columns() == orig[pos], d.slot_val, 0)
-        dg = jax.ops.segment_sum(keep, d.row_block,
-                                 num_segments=d.n_blocks).reshape(n_pad)
-        rem = jnp.where(d.rem_col.astype(jnp.int32) == orig[d.rem_row],
-                        d.rem_val, 0)
-        dg = dg + jax.ops.segment_sum(rem, d.rem_row, num_segments=n_pad)
-        return dg[inv][:n]
-    if sd.fmt == "cmrs":
-        b_r = d.val.shape[1]
-        rows = d.strip_map[:, None] * b_r + d.row_in_strip.astype(jnp.int32)
-        keep = jnp.where(d.col_idx.astype(jnp.int32) == rows, d.val, 0)
-        return jax.ops.segment_sum(
-            keep.reshape(-1), rows.reshape(-1),
-            num_segments=d.n_strips * b_r)[:n]
-    raise ValueError(f"unknown format {sd.fmt!r}")
 
 
 # --------------------------------------------------------------------------

@@ -3,19 +3,21 @@ dispatch layer.
 
 Two levels of API live here:
 
-* **Per-format containers and matvecs** — ``to_device_pjds`` /
+* **Device operands and their matvecs** — ``to_device_pjds`` /
   ``to_device_ell`` / ``to_device_sell`` / ``to_device_wsell`` /
-  ``to_device_csr`` move a
-  host-side format (``repro.core.formats``) onto the device with the
-  kernel-side metadata (chunk maps, tile chunk counts, window inverse
-  permutations) precomputed; ``pjds_matvec`` / ``ell_matvec`` /
-  ``sell_matvec`` / ``wsell_matvec`` / ``csr_matvec`` / ``pjds_matmat``
-  dispatch to either
-  the Pallas kernel (``backend='kernel'``, interpret-mode on CPU) or the
-  pure-jnp oracle (``backend='ref'``, fast on CPU and used inside the
-  distributed layer).
+  ``to_device_csr`` / ``to_device_cmrs`` move a host-side format
+  (``repro.core.formats``) onto the device with the kernel-side metadata
+  (chunk maps, tile chunk counts, window inverse permutations)
+  precomputed; ``pjds_matvec`` / ``ell_matvec`` / ``sell_matvec`` /
+  ``wsell_matvec`` / ``csr_matvec`` / ``cmrs_matvec`` / ``pjds_matmat``
+  dispatch to either the Pallas kernel (``backend='kernel'``,
+  interpret-mode on CPU) or the pure-jnp oracle (``backend='ref'``, fast
+  on CPU and used inside the distributed layer).  Each operand a
+  :class:`SparseDevice` holds answers for its own layout (``_Operand``):
+  its applies in the original row order, its transpose, its diagonal and
+  the counters the operator build notes.
 
-* **The unified entry point** — ``spmv(a, x, format="auto")`` wraps any
+* **The dispatch layer** — ``as_device(a, format="auto")`` wraps any
   matrix in a :class:`SparseDevice`: it inspects row-length statistics,
   prices each candidate format with ``core.perf_model``'s overhead
   estimates (``select_format``), converts once, caches the device
@@ -28,7 +30,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
-import warnings
 import weakref
 from typing import Literal, Optional, Tuple, Union
 
@@ -72,7 +73,6 @@ __all__ = [
     "cmrs_matvec",
     "select_format",
     "as_device",
-    "spmv",
     "clear_device_cache",
     "resolve_backend",
     "resolve_interpret",
@@ -102,13 +102,13 @@ def resolve_backend(backend: Backend) -> str:
     return "kernel" if jax.default_backend() == "tpu" else "ref"
 
 
-_resolve_backend = resolve_backend   # the satellite-task spelling
-
-
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class PJDSDevice:
-    """Device-resident pJDS operand.  Registered as a pytree so it can be
+    """Device-resident pJDS operand in the permuted basis, as the
+    distributed partition and the tuner use it (its subclass
+    ``SELLDevice`` is the original-order operand that a
+    ``format="pjds"`` build holds).  Registered as a pytree so it can be
     closed over / passed through jit and shard_map.
 
     ``val`` carries the (possibly bf16-compressed) value stream and
@@ -133,9 +133,82 @@ class PJDSDevice:
         return self.n_blocks * self.b_r
 
 
+class _Operand:
+    """What every operand a :class:`SparseDevice` holds answers for its
+    own layout:
+
+    * ``matvec(x, backend)`` / ``matmat(x, backend)``: y = A x with the
+      rows in the ORIGINAL order (trailing padded rows allowed);
+    * ``rmatmat(y, n_cols)``: x = A^T y for an original-order y of the
+      matrix's rows, (n_rows[, k]) -> (n_cols[, k]);
+    * ``diagonal(n)``: diag(A), length n;
+    * the counters the operator build notes (``repro.obs`` gauges):
+      ``stored_slots``, ``window_share``, ``fused_unpermute`` and
+      ``grid_fill``, and the stored ``index_dtype``.
+
+    The defaults here are those of a blocked operand on the grouped
+    kernel grid with no window of x; an operand overrides what differs.
+    """
+
+    window_share = 0.0       # no non-zero served from a window of x
+    fused_unpermute = 0.0    # no row unpermuted inside the kernel
+
+    @property
+    def stored_slots(self) -> int:
+        """Value slots the apply streams, padding included."""
+        return int(self.val.size)
+
+    @property
+    def index_dtype(self):
+        """Dtype of the stored column-index stream (int16 or int32)."""
+        return self.col_idx.dtype
+
+    @property
+    def grid_fill(self) -> float:
+        """Share of the kernel grid's steps that compute a chunk: the
+        stored ``(chunk_l, b_r)`` chunks over the grouped grid's
+        ``n_groups * max_chunks`` steps, the rest being steps past a
+        group's extent that fetch nothing and skip their compute."""
+        n_chunks = self.chunk_map.shape[0]
+        n_blocks = self.n_rows_pad // self.b_r
+        n_groups = max(-(-n_blocks // OUT_BLOCKS), 1)
+        return n_chunks / (n_groups * (self.max_chunks or n_chunks) or 1)
+
+
+def _pad_rows(y: jax.Array, n_pad: int) -> jax.Array:
+    """y zero-padded to ``n_pad`` rows."""
+    return jnp.pad(y, [(0, n_pad - y.shape[0])] + [(0, 0)] * (y.ndim - 1))
+
+
+def _scatter_to_storage(y: jax.Array, inv_perm: jax.Array) -> jax.Array:
+    """Inverse of the matvec epilogue ``y_p[inv_perm][:n]``: place y's
+    entries at their storage (permuted) positions, zeros in the padded
+    rows (whose stored values are zero anyway)."""
+    y_p = jnp.zeros((inv_perm.shape[0],) + y.shape[1:], y.dtype)
+    return y_p.at[inv_perm[: y.shape[0]]].set(y)
+
+
+def _original_rows(inv_perm: jax.Array) -> jax.Array:
+    """The original row of each storage (permuted) position."""
+    n_pad = inv_perm.shape[0]
+    return jnp.zeros(n_pad, jnp.int32).at[inv_perm].set(
+        jnp.arange(n_pad, dtype=jnp.int32))
+
+
+def _slot_diagonal(val, col, row_block, orig) -> jax.Array:
+    """A sorted blocked operand's diagonal in storage row order: each
+    slot kept where its column is its row's original index."""
+    b_r = val.shape[1]
+    pos = row_block[:, None] * b_r + jnp.arange(b_r, dtype=jnp.int32)[None]
+    keep = jnp.where(col == orig[pos], val, 0)
+    n_pad = orig.shape[0]
+    return jax.ops.segment_sum(keep, row_block,
+                               num_segments=n_pad // b_r).reshape(n_pad)
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
-class ELLDevice:
+class ELLDevice(_Operand):
     val: jax.Array                     # (max_nzr, n_pad)
     col_idx: jax.Array                 # (max_nzr, n_pad) int32
     rowlen: jax.Array                  # (n_pad,) int32
@@ -143,33 +216,66 @@ class ELLDevice:
     chunk_l: int = dataclasses.field(metadata=dict(static=True))
     tile_r: int = dataclasses.field(metadata=dict(static=True))
 
+    def matvec(self, x: jax.Array, backend: str) -> jax.Array:
+        return ell_matvec(self, x, backend)
 
-@jax.tree_util.register_dataclass
-@dataclasses.dataclass(frozen=True)
-class SELLDevice:
-    """Device-resident SELL-C-sigma operand: pJDS chunk layout plus the
-    window-local inverse permutation that takes y back to row order."""
+    def matmat(self, x: jax.Array, backend: str) -> jax.Array:
+        del backend        # no multi-RHS kernel: the generalized ref
+        return R.ell_matvec_ref(self.val, self.col_idx, self.rowlen, x)
 
-    val: jax.Array                     # (total_jds, b_r)
-    col_idx: jax.Array                 # (total_jds, b_r) int16/int32
-    chunk_map: jax.Array               # (total_jds // chunk_l,) int32
-    row_block: jax.Array               # (total_jds,) int32 (for the ref)
-    inv_perm: jax.Array                # (n_blocks * b_r,) int32, window-local
-    n_blocks: int = dataclasses.field(metadata=dict(static=True))
-    b_r: int = dataclasses.field(metadata=dict(static=True))
-    chunk_l: int = dataclasses.field(metadata=dict(static=True))
-    sigma: int = dataclasses.field(metadata=dict(static=True))
-    max_chunks: Optional[int] = dataclasses.field(
-        default=None, metadata=dict(static=True))
+    def rmatmat(self, y: jax.Array, n_cols: int) -> jax.Array:
+        return R.ell_rmatvec_ref(self.val, self.col_idx, self.rowlen,
+                                 _pad_rows(y, self.val.shape[1]), n_cols)
+
+    def diagonal(self, n: int) -> jax.Array:
+        rows = jnp.arange(self.val.shape[1], dtype=jnp.int32)[None, :]
+        j = jnp.arange(self.val.shape[0], dtype=jnp.int32)[:, None]
+        mask = (self.col_idx == rows) & (j < self.rowlen[None, :])
+        return jnp.where(mask, self.val, 0).sum(axis=0)[:n]
 
     @property
-    def n_rows_pad(self) -> int:
-        return self.n_blocks * self.b_r
+    def grid_fill(self) -> float:
+        """Each tile's chunks over its ``n_groups * OUT_BLOCKS *
+        n_chunks`` steps."""
+        n_tiles = self.tile_chunks.shape[0]
+        steps = (-(-n_tiles // OUT_BLOCKS) * OUT_BLOCKS
+                 * (self.val.shape[0] // self.chunk_l))
+        return int(np.asarray(self.tile_chunks).sum()) / max(steps, 1)
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
-class WSELLDevice:
+class SELLDevice(PJDSDevice, _Operand):
+    """Device-resident SELL-C-sigma operand: the pJDS chunk layout plus
+    the inverse permutation that takes y back to row order
+    (window-local; global for a pJDS build, whose sigma is its padded
+    row count)."""
+
+    inv_perm: jax.Array = dataclasses.field(kw_only=True)  # (n_rows_pad,)
+    sigma: int = dataclasses.field(kw_only=True, metadata=dict(static=True))
+
+    def matvec(self, x: jax.Array, backend: str) -> jax.Array:
+        return sell_matvec(self, x, backend)
+
+    def matmat(self, x: jax.Array, backend: str) -> jax.Array:
+        y = pjds_matmat(self, x, backend)
+        with jax.named_scope("repro.unpermute"):
+            return y[self.inv_perm]
+
+    def rmatmat(self, y: jax.Array, n_cols: int) -> jax.Array:
+        y_p = _scatter_to_storage(y, self.inv_perm)
+        return R.blocked_rmatvec_ref(self.val, self.col_idx, self.row_block,
+                                     y_p, n_cols)
+
+    def diagonal(self, n: int) -> jax.Array:
+        dg = _slot_diagonal(self.val, self.col_idx, self.row_block,
+                            _original_rows(self.inv_perm))
+        return dg[self.inv_perm][:n]
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class WSELLDevice(_Operand):
     """Device-resident windowed SELL-C-sigma operand
     (``formats.WindowedSELLMatrix``): the SELL chunk layout with int16
     window-local column offsets, each row block's window start, and the
@@ -203,6 +309,11 @@ class WSELLDevice:
         return self.n_blocks * self.b_r
 
     @property
+    def index_dtype(self):
+        """The window-local offsets' dtype."""
+        return self.col_off.dtype
+
+    @property
     def slot_val(self) -> jax.Array:
         """The slots' values, ``(total, b_r)``."""
         return self.val[: self.col_off.shape[0]]
@@ -225,26 +336,87 @@ class WSELLDevice:
         rows."""
         return (OUT_BLOCKS * self.b_r) % self.sigma == 0
 
+    @property
+    def fused_unpermute(self) -> float:
+        return float(self.fuses_unpermute)
+
     def columns(self) -> jax.Array:
         """The slots' global columns, rebuilt in XLA (not stored)."""
         return R.window_columns(self.col_off, self.wbase, self.row_block)
 
+    def matvec(self, x: jax.Array, backend: str) -> jax.Array:
+        return wsell_matvec(self, x, backend)
+
+    def matmat(self, x: jax.Array, backend: str) -> jax.Array:
+        """The multi-RHS pJDS path on the slots' rebuilt global columns,
+        plus the remainder."""
+        pj = PJDSDevice(val=self.slot_val, col_idx=self.columns(),
+                        chunk_map=self.chunk_map, row_block=self.row_block,
+                        n_blocks=self.n_blocks, b_r=self.b_r,
+                        chunk_l=self.chunk_l, max_chunks=self.max_chunks)
+        y = pjds_matmat(pj, _window_x(x, self.x_len), backend)
+        y = _add_remainder(self, y, x)
+        with jax.named_scope("repro.unpermute"):
+            return y[self.row_inv_perm]
+
+    def rmatmat(self, y: jax.Array, n_cols: int) -> jax.Array:
+        """The blocked scatter-accumulate over the rebuilt global
+        columns plus the remainder's."""
+        y_p = _scatter_to_storage(y, self.row_inv_perm)
+        x = R.blocked_rmatvec_ref(self.slot_val, self.columns(),
+                                  self.row_block, y_p, self.x_len)[:n_cols]
+        if self.rem_row.shape[0]:
+            x = x + R.remainder_rmatvec_ref(
+                self.rem_val, self.rem_row, self.rem_col, y_p,
+                n_cols).astype(x.dtype)
+        return x
+
+    def diagonal(self, n: int) -> jax.Array:
+        inv = self.row_inv_perm
+        orig = _original_rows(inv)
+        dg = _slot_diagonal(self.slot_val, self.columns(), self.row_block,
+                            orig)
+        rem = jnp.where(self.rem_col.astype(jnp.int32) == orig[self.rem_row],
+                        self.rem_val, 0)
+        dg = dg + jax.ops.segment_sum(rem, self.rem_row,
+                                      num_segments=self.n_rows_pad)
+        return dg[inv][:n]
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
-class CSRDevice:
+class CSRDevice(_Operand):
     """Device-resident CSR as flat nnz streams (gather + segment-sum ref;
     no Pallas kernel — the irregular baseline for tiny matrices)."""
 
-    data: jax.Array                    # (nnz,)
+    val: jax.Array                     # (nnz,)
     indices: jax.Array                 # (nnz,) int32
     row_ids: jax.Array                 # (nnz,) int32
     n_rows: int = dataclasses.field(metadata=dict(static=True))
 
+    grid_fill = 1.0                    # no kernel grid
+
+    @property
+    def index_dtype(self):
+        return self.indices.dtype
+
+    def matvec(self, x: jax.Array, backend: str) -> jax.Array:
+        return csr_matvec(self, x, backend)
+
+    matmat = matvec                    # the ref takes (n,) and (n, k)
+
+    def rmatmat(self, y: jax.Array, n_cols: int) -> jax.Array:
+        return R.csr_rmatvec_ref(self.val, self.indices, self.row_ids, y,
+                                 n_cols)
+
+    def diagonal(self, n: int) -> jax.Array:
+        keep = jnp.where(self.indices == self.row_ids, self.val, 0)
+        return jax.ops.segment_sum(keep, self.row_ids, num_segments=n)
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
-class CMRSDevice:
+class CMRSDevice(_Operand):
     """Device-resident CMRS operand (``formats.CMRSMatrix``): strips of
     b_r consecutive ORIGINAL-order rows, nonzeros packed densely with an
     int8 row-in-strip routing stream.  ``chunk_map`` plays pJDS's role —
@@ -267,6 +439,28 @@ class CMRSDevice:
     def n_rows_pad(self) -> int:
         return self.n_strips * self.b_r
 
+    def matvec(self, x: jax.Array, backend: str) -> jax.Array:
+        return cmrs_matvec(self, x, backend)
+
+    def matmat(self, x: jax.Array, backend: str) -> jax.Array:
+        del backend        # no multi-RHS kernel: the generalized ref
+        return R.cmrs_matvec_ref(self.val, self.col_idx, self.row_in_strip,
+                                 self.strip_map, x, self.n_strips)
+
+    def rmatmat(self, y: jax.Array, n_cols: int) -> jax.Array:
+        return R.cmrs_rmatvec_ref(self.val, self.col_idx, self.row_in_strip,
+                                  self.strip_map,
+                                  _pad_rows(y, self.n_rows_pad), n_cols)
+
+    def diagonal(self, n: int) -> jax.Array:
+        b_r = self.b_r
+        rows = (self.strip_map[:, None] * b_r
+                + self.row_in_strip.astype(jnp.int32))
+        keep = jnp.where(self.col_idx.astype(jnp.int32) == rows, self.val, 0)
+        return jax.ops.segment_sum(
+            keep.reshape(-1), rows.reshape(-1),
+            num_segments=self.n_strips * b_r)[:n]
+
 
 def _blocked_maps(block_len: np.ndarray, chunk_l: int, n_blocks: int):
     row_block = np.repeat(np.arange(n_blocks, dtype=np.int32), block_len)
@@ -278,7 +472,7 @@ def to_device_pjds(p: F.PJDSMatrix, chunk_l: int = 8,
     if np.any(p.block_len % chunk_l):
         raise ValueError(
             f"chunk_l={chunk_l} must divide every block length; rebuild the "
-            f"pJDS matrix with diag_align a multiple of chunk_l"
+            f"pJDS or SELL matrix with diag_align a multiple of chunk_l"
         )
     # block id per jagged-diagonal row, then per chunk
     row_block, chunk_map = _blocked_maps(p.block_len, chunk_l, p.n_blocks)
@@ -328,28 +522,18 @@ def to_device_ell(e: F.ELLMatrix, chunk_l: int = 8, tile_r: int = 128,
     )
 
 
-def to_device_sell(s: F.SELLMatrix, chunk_l: int = 8,
+def to_device_sell(s: Union[F.SELLMatrix, F.PJDSMatrix], chunk_l: int = 8,
                    dtype=None) -> SELLDevice:
-    p = s.pjds
-    if np.any(p.block_len % chunk_l):
-        raise ValueError(
-            f"chunk_l={chunk_l} must divide every chunk length; rebuild the "
-            f"SELL matrix with diag_align a multiple of chunk_l"
-        )
-    row_block, chunk_map = _blocked_maps(p.block_len, chunk_l, p.n_blocks)
-    val = p.val if dtype is None else p.val.astype(dtype)
+    """The original-order sorted blocked operand.  A pJDS host matrix
+    builds it too, as SELL-C-sigma with sigma its padded row count and
+    its global inverse permutation (pJDS is the sigma = all rows case);
+    its chunk layout is :func:`to_device_pjds`'s."""
+    p, sigma = ((s, s.n_rows_pad) if isinstance(s, F.PJDSMatrix)
+                else (s.pjds, s.sigma))
+    d = to_device_pjds(p, chunk_l=chunk_l, dtype=dtype)
     return SELLDevice(
-        val=jnp.asarray(val),
-        col_idx=jnp.asarray(p.col_idx),
-        chunk_map=jnp.asarray(chunk_map),
-        row_block=jnp.asarray(row_block),
-        inv_perm=jnp.asarray(p.inv_perm),
-        n_blocks=p.n_blocks,
-        b_r=p.b_r,
-        chunk_l=chunk_l,
-        sigma=s.sigma,
-        max_chunks=group_max_chunks(chunk_map),
-    )
+        **{f.name: getattr(d, f.name) for f in dataclasses.fields(d)},
+        inv_perm=jnp.asarray(p.inv_perm), sigma=sigma)
 
 
 def to_device_wsell(w: F.WindowedSELLMatrix, chunk_l: int = 8,
@@ -392,11 +576,11 @@ def to_device_wsell(w: F.WindowedSELLMatrix, chunk_l: int = 8,
 
 
 def to_device_csr(m: F.CSRMatrix, dtype=None) -> CSRDevice:
-    data = m.data if dtype is None else m.data.astype(dtype)
+    val = m.data if dtype is None else m.data.astype(dtype)
     row_ids = np.repeat(np.arange(m.n_rows, dtype=np.int32),
                         m.row_lengths())
     return CSRDevice(
-        data=jnp.asarray(data),
+        val=jnp.asarray(val),
         indices=jnp.asarray(m.indices),
         row_ids=jnp.asarray(row_ids),
         n_rows=m.n_rows,
@@ -438,7 +622,7 @@ def pjds_matvec(a: PJDSDevice, x: jax.Array,
 
 def pjds_matmat(a: PJDSDevice, x: jax.Array, backend: Backend = "ref",
                 rhs_t: int = 128) -> jax.Array:
-    """Y = A X; X: (n_cols_pad, n_rhs)."""
+    """Y = A X in the permuted basis; X: (n_cols_pad, n_rhs)."""
     if resolve_backend(backend) == "kernel":
         return pjds_matmat_kernel_call(
             a.val, a.col_idx, a.chunk_map, x,
@@ -461,17 +645,11 @@ def ell_matvec(a: ELLDevice, x: jax.Array,
 def sell_matvec(a: SELLDevice, x: jax.Array,
                 backend: Backend = "ref") -> jax.Array:
     """y = A x with rows back in the ORIGINAL order; y has n_rows_pad
-    entries.  The kernel path is the pJDS kernel over the SELL chunks
-    (same storage layout) followed by the window-local unpermute."""
-    if resolve_backend(backend) == "kernel":
-        y = pjds_matvec_kernel_call(
-            a.val, a.col_idx, a.chunk_map, x,
-            n_blocks=a.n_blocks, chunk_l=a.chunk_l, max_chunks=a.max_chunks,
-        )
-        with jax.named_scope("repro.unpermute"):
-            return y[a.inv_perm]
-    return R.sell_matvec_ref(a.val, a.col_idx, a.row_block, a.inv_perm, x,
-                             a.n_blocks)
+    entries: the pJDS path over the SELL chunks (same storage layout)
+    followed by the unpermute."""
+    y = pjds_matvec(a, x, backend)
+    with jax.named_scope("repro.unpermute"):
+        return y[a.inv_perm]
 
 
 def _window_x(x: jax.Array, x_len: int) -> jax.Array:
@@ -534,36 +712,11 @@ def wsell_matvec(a: WSELLDevice, x: jax.Array,
         return y[a.row_inv_perm]
 
 
-def wsell_matmat(a: WSELLDevice, x: jax.Array,
-                 backend: Backend = "ref") -> jax.Array:
-    """Y = A X, rows in the ORIGINAL order: the multi-RHS pJDS path on
-    the slots' rebuilt global columns, plus the remainder."""
-    pj = PJDSDevice(val=a.slot_val, col_idx=a.columns(),
-                    chunk_map=a.chunk_map, row_block=a.row_block,
-                    n_blocks=a.n_blocks, b_r=a.b_r, chunk_l=a.chunk_l,
-                    max_chunks=a.max_chunks)
-    y = _add_remainder(a, pjds_matmat(pj, _window_x(x, a.x_len), backend), x)
-    with jax.named_scope("repro.unpermute"):
-        return y[a.row_inv_perm]
-
-
-def wsell_rmatmat(a: WSELLDevice, y_p: jax.Array, n_cols: int) -> jax.Array:
-    """X = A^T Y with ``y_p`` in the storage row order: the blocked
-    scatter-accumulate over the rebuilt global columns plus the
-    remainder's."""
-    x = R.blocked_rmatvec_ref(a.slot_val, a.columns(), a.row_block, y_p,
-                              a.x_len)[:n_cols]
-    if a.rem_row.shape[0]:
-        x = x + R.remainder_rmatvec_ref(a.rem_val, a.rem_row, a.rem_col,
-                                        y_p, n_cols).astype(x.dtype)
-    return x
-
-
 def csr_matvec(a: CSRDevice, x: jax.Array,
                backend: Backend = "ref") -> jax.Array:
     # No Pallas kernel for CSR — the ref path IS the implementation.
     del backend
-    return R.csr_matvec_ref(a.data, a.indices, a.row_ids, x, a.n_rows)
+    return R.csr_matvec_ref(a.val, a.indices, a.row_ids, x, a.n_rows)
 
 
 def cmrs_matvec(a: CMRSDevice, x: jax.Array,
@@ -579,7 +732,7 @@ def cmrs_matvec(a: CMRSDevice, x: jax.Array,
 
 
 # --------------------------------------------------------------------------
-# Unified dispatch: SparseDevice + spmv(a, x, format="auto")
+# Unified dispatch: select_format, SparseDevice and as_device
 # --------------------------------------------------------------------------
 _CSR_MIN_ROWS_FACTOR = 2       # below 2*b_r rows, block padding dominates
 _CSR_IRREGULAR_FACTOR = 4.0    # scalar gather stream can't saturate HBM
@@ -773,13 +926,16 @@ def sandwich(pre_perm, pre_inv, apply, v):
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class SparseDevice:
-    """A matrix ready for ``spmv``: one chosen format, converted once.
+    """A matrix ready for ``operator()``: one chosen format, converted
+    once.
 
     Whatever the inner format, ``matvec`` consumes x and returns y in the
     ORIGINAL basis (length ``shape[0]``) — permutations, padding and
-    basis changes are internal.  Device arrays are cached per host
-    matrix by ``as_device``; hold on to the wrapper (or keep the host
-    matrix alive) to amortise conversion across calls.
+    basis changes are internal.  The operand ``dev`` answers for its own
+    layout (``_Operand``); this wrapper adds the reorder sandwich, the
+    length checks and the trim to ``shape[0]`` rows.  Device arrays are
+    cached per host matrix by ``as_device``; hold on to the wrapper (or
+    keep the host matrix alive) to amortise conversion across calls.
 
     Registered as a pytree (device arrays are the leaves) so it can flow
     through ``jit`` / ``shard_map`` / ``lax.while_loop`` carriers — the
@@ -788,9 +944,7 @@ class SparseDevice:
 
     fmt: str = dataclasses.field(metadata=dict(static=True))
     shape: Tuple[int, int] = dataclasses.field(metadata=dict(static=True))
-    dev: Union[PJDSDevice, ELLDevice, SELLDevice, WSELLDevice, CSRDevice,
-               CMRSDevice]
-    inv_perm: Optional[jax.Array]      # pjds only: undo the global row sort
+    dev: Union[ELLDevice, SELLDevice, WSELLDevice, CSRDevice, CMRSDevice]
     # Preprocessing (reorder=) permutation: the stored matrix is
     # B = P A P^T with perm[k] = old index at new position k
     # (core.reorder's convention), and every entry point sandwiches —
@@ -807,17 +961,13 @@ class SparseDevice:
     def value_dtype(self):
         """Dtype of the STORED value stream (bf16 for compressed builds);
         results still come back in the accumulator dtype (>= f32)."""
-        return (self.dev.data if self.fmt == "csr" else self.dev.val).dtype
+        return self.dev.val.dtype
 
     @property
     def index_dtype(self):
         """Dtype of the stored column-index stream (int16 or int32; the
         window-local offsets of the windowed SELL)."""
-        if self.fmt == "csr":
-            return self.dev.indices.dtype
-        if self.fmt == "wsell":
-            return self.dev.col_off.dtype
-        return self.dev.col_idx.dtype
+        return self.dev.index_dtype
 
     def matvec(self, x: jax.Array, backend: Backend = "auto") -> jax.Array:
         """y = A x, original basis, length shape[0]."""
@@ -826,64 +976,23 @@ class SparseDevice:
             return self.matmat(x, backend)
         self._check_cols(x)
         return sandwich(self.pre_perm, self.pre_inv,
-                        lambda v: self._matvec_stored(v, backend), x)
-
-    def _matvec_stored(self, x: jax.Array, backend: str) -> jax.Array:
-        if self.fmt == "csr":
-            return csr_matvec(self.dev, x, backend)
-        if self.fmt == "ellpack_r":
-            return ell_matvec(self.dev, x, backend)[: self.n_rows]
-        if self.fmt == "sell":
-            return sell_matvec(self.dev, x, backend)[: self.n_rows]
-        if self.fmt == "wsell":
-            return wsell_matvec(self.dev, x, backend)[: self.n_rows]
-        if self.fmt == "pjds":
-            y_p = pjds_matvec(self.dev, x, backend)
-            with jax.named_scope("repro.unpermute"):
-                return y_p[self.inv_perm][: self.n_rows]
-        if self.fmt == "cmrs":
-            return cmrs_matvec(self.dev, x, backend)[: self.n_rows]
-        raise ValueError(f"unknown format {self.fmt!r}")
+                        lambda v: self.dev.matvec(v, backend)[: self.n_rows],
+                        x)
 
     def matmat(self, x: jax.Array, backend: Backend = "auto") -> jax.Array:
         """Y = A X for a block of RHS vectors, original basis.
 
-        x: (n_cols, k) -> (shape[0], k).  The blocked formats ride the
-        multi-RHS pJDS path (the storage layouts are identical, only the
-        row unpermute differs) and honor ``backend``; CSR/ELLPACK have
-        no multi-RHS Pallas kernel, so they always use the generalized
-        refs — an explicit ``backend="kernel"`` falls back silently.
+        x: (n_cols, k) -> (shape[0], k).  pJDS, SELL and the windowed
+        SELL ride the multi-RHS pJDS path and honor ``backend``; CSR,
+        ELLPACK-R and CMRS have no multi-RHS Pallas kernel, so they
+        always use the generalized refs — an explicit
+        ``backend="kernel"`` falls back silently.
         """
         backend = resolve_backend(backend)
         self._check_cols(x)
         return sandwich(self.pre_perm, self.pre_inv,
-                        lambda v: self._matmat_stored(v, backend), x)
-
-    def _matmat_stored(self, x: jax.Array, backend: str) -> jax.Array:
-        if self.fmt == "csr":
-            return R.csr_matvec_ref(self.dev.data, self.dev.indices,
-                                    self.dev.row_ids, x, self.dev.n_rows)
-        if self.fmt == "ellpack_r":
-            return R.ell_matvec_ref(self.dev.val, self.dev.col_idx,
-                                    self.dev.rowlen, x)[: self.n_rows]
-        if self.fmt in ("sell", "pjds"):
-            d = self.dev
-            a = d if self.fmt == "pjds" else PJDSDevice(
-                val=d.val, col_idx=d.col_idx, chunk_map=d.chunk_map,
-                row_block=d.row_block, n_blocks=d.n_blocks, b_r=d.b_r,
-                chunk_l=d.chunk_l, max_chunks=d.max_chunks)
-            y_p = pjds_matmat(a, x, backend)
-            inv = d.inv_perm if self.fmt == "sell" else self.inv_perm
-            with jax.named_scope("repro.unpermute"):
-                return y_p[inv][: self.n_rows]
-        if self.fmt == "wsell":
-            return wsell_matmat(self.dev, x, backend)[: self.n_rows]
-        if self.fmt == "cmrs":
-            d = self.dev
-            return R.cmrs_matvec_ref(d.val, d.col_idx, d.row_in_strip,
-                                     d.strip_map, x,
-                                     d.n_strips)[: self.n_rows]
-        raise ValueError(f"unknown format {self.fmt!r}")
+                        lambda v: self.dev.matmat(v, backend)[: self.n_rows],
+                        x)
 
     def rmatvec(self, y: jax.Array, backend: Backend = "auto") -> jax.Array:
         """x = A^T y, original basis: (shape[0],) -> (shape[1],).
@@ -901,45 +1010,9 @@ class SparseDevice:
         """X = A^T Y, original basis: (shape[0][, k]) -> (shape[1][, k])."""
         del backend    # scatter path only; see operator(transpose="device")
         self._check_rows(y)
-        return sandwich(self.pre_perm, self.pre_inv,
-                        self._rmatmat_stored, y)
-
-    def _rmatmat_stored(self, y: jax.Array) -> jax.Array:
-        n_cols = self.shape[1]
-        if self.fmt == "csr":
-            return R.csr_rmatvec_ref(self.dev.data, self.dev.indices,
-                                     self.dev.row_ids, y, n_cols)
-        if self.fmt == "ellpack_r":
-            y_pad = self._pad_rows(y, self.dev.val.shape[1])
-            return R.ell_rmatvec_ref(self.dev.val, self.dev.col_idx,
-                                     self.dev.rowlen, y_pad, n_cols)
-        if self.fmt in ("sell", "pjds"):
-            d = self.dev
-            inv = d.inv_perm if self.fmt == "sell" else self.inv_perm
-            y_p = self._scatter_to_storage(y, inv)
-            return R.blocked_rmatvec_ref(d.val, d.col_idx, d.row_block,
-                                         y_p, n_cols)
-        if self.fmt == "wsell":
-            y_p = self._scatter_to_storage(y, self.dev.row_inv_perm)
-            return wsell_rmatmat(self.dev, y_p, n_cols)
-        if self.fmt == "cmrs":
-            d = self.dev
-            y_pad = self._pad_rows(y, d.n_rows_pad)
-            return R.cmrs_rmatvec_ref(d.val, d.col_idx, d.row_in_strip,
-                                      d.strip_map, y_pad, n_cols)
-        raise ValueError(f"unknown format {self.fmt!r}")
-
-    def _pad_rows(self, y: jax.Array, n_pad: int) -> jax.Array:
-        pad = [(0, n_pad - self.n_rows)] + [(0, 0)] * (y.ndim - 1)
-        return jnp.pad(y[: self.n_rows], pad)
-
-    def _scatter_to_storage(self, y: jax.Array, inv_perm) -> jax.Array:
-        """Inverse of the matvec epilogue ``y_p[inv_perm][:n_rows]``:
-        place y's entries at their storage (permuted) positions, zeros in
-        the padded rows (whose stored values are zero anyway)."""
-        n_pad = inv_perm.shape[0]
-        y_p = jnp.zeros((n_pad,) + y.shape[1:], y.dtype)
-        return y_p.at[inv_perm[: self.n_rows]].set(y[: self.n_rows])
+        return sandwich(
+            self.pre_perm, self.pre_inv,
+            lambda v: self.dev.rmatmat(v[: self.n_rows], self.shape[1]), y)
 
     def _check_cols(self, x: jax.Array) -> None:
         n = x.shape[0] if x.ndim == 2 else x.shape[-1]
@@ -953,47 +1026,6 @@ class SparseDevice:
         if y.shape[0] < self.shape[0]:
             raise ValueError(
                 f"y has {y.shape[0]} entries; matrix has {self.shape[0]} rows")
-
-    @property
-    def stored_slots(self) -> int:
-        """Value slots the apply streams, padding included."""
-        if self.fmt == "csr":
-            return int(self.dev.data.size)
-        return int(self.dev.val.size)
-
-    @property
-    def window_share(self) -> float:
-        """Share of the matrix's non-zeros the apply serves from a window
-        of x inside the kernel: 0 for every operand without windows."""
-        return self.dev.window_share if self.fmt == "wsell" else 0.0
-
-    @property
-    def fused_unpermute(self) -> float:
-        """Share of the rows whose unpermute the kernel path runs inside
-        the kernel: 1 for a windowed SELL whose sigma-windows lie in
-        single output groups, 0 for every other operand."""
-        return float(self.fmt == "wsell" and self.dev.fuses_unpermute)
-
-    @property
-    def grid_fill(self) -> float:
-        """Share of the kernel grid's steps that compute a chunk: the
-        stored ``(chunk_l, b_r)`` chunks over the grouped grid's
-        ``n_groups * max_chunks`` steps (ELLPACK-R: each tile's chunks
-        over its ``n_groups * OUT_BLOCKS * n_chunks`` steps), the rest
-        being steps past a group's extent that fetch nothing and skip
-        their compute; 1.0 for CSR, which has no grid."""
-        d = self.dev
-        if self.fmt == "csr":
-            return 1.0
-        if self.fmt == "ellpack_r":
-            n_tiles = d.tile_chunks.shape[0]
-            steps = (-(-n_tiles // OUT_BLOCKS) * OUT_BLOCKS
-                     * (d.val.shape[0] // d.chunk_l))
-            return int(np.asarray(d.tile_chunks).sum()) / max(steps, 1)
-        n_chunks = d.chunk_map.shape[0]
-        n_blocks = d.n_strips if self.fmt == "cmrs" else d.n_blocks
-        n_groups = max(-(-n_blocks // OUT_BLOCKS), 1)
-        return n_chunks / (n_groups * (d.max_chunks or n_chunks) or 1)
 
 
 # Conversion cache: host matrix -> device representation.  Keyed by the
@@ -1222,53 +1254,13 @@ def as_device(
             dev = to_device_ell(stored, chunk_l=chunk_l,
                                 tile_r=ell_tile(b_r), dtype=dtype)
         else:
-            to_device = {"sell": to_device_sell, "pjds": to_device_pjds,
+            to_device = {"sell": to_device_sell, "pjds": to_device_sell,
                          "wsell": to_device_wsell,
                          "cmrs": to_device_cmrs}[fmt]
             dev = to_device(stored, chunk_l=chunk_l, dtype=dtype)
-        inv_perm = jnp.asarray(stored.inv_perm) if fmt == "pjds" else None
         sd = obs.settled(SparseDevice(
-            fmt=fmt, shape=a.shape, dev=dev, inv_perm=inv_perm,
-            pre_perm=pre_perm, pre_inv=pre_inv))
+            fmt=fmt, shape=a.shape, dev=dev, pre_perm=pre_perm,
+            pre_inv=pre_inv))
     _cache_put(key, a_orig, sd)
     return sd
 
-
-def spmv(
-    a: Union[F.CSRMatrix, np.ndarray, SparseDevice],
-    x: jax.Array,
-    format: FormatName = "auto",
-    backend: Backend = "auto",
-    **convert_kwargs,
-) -> jax.Array:
-    """y = A x through the unified dispatch layer (original basis).
-
-    .. deprecated::
-        ``spmv`` is kept as a thin shim over the operator protocol:
-        ``spmv(a, x)`` == ``operator(a) @ x`` (``repro.core.operator``).
-        New code should build the operator once and reuse it — it adds
-        ``.T``, ``rmatvec`` and ``jax.grad`` support that this function
-        does not expose.
-
-    ``format="auto"`` measures the matrix and picks CSR-ref / ELLPACK-R /
-    pJDS / SELL-C-sigma (``select_format``); an explicit name forces the
-    format.  ``backend="auto"`` resolves in :func:`resolve_backend`.  A
-    2-D ``x`` of shape (n_cols, k) is dispatched to the multi-RHS spMM
-    path, returning (n_rows, k).  The converted device representation is
-    cached, so repeated ``spmv`` calls with the same host matrix convert
-    once.  ``convert_kwargs`` (b_r, diag_align, sigma, chunk_l, dtype,
-    index_dtype, tune) pass through to :func:`as_device` — in
-    particular ``dtype=jnp.bfloat16`` stores a compressed value stream,
-    ``index_dtype="auto"`` (the default) compresses indices to int16
-    whenever the column span fits, and ``tune="auto"`` replaces the
-    static format/statics heuristic with the measured autotuner
-    (``repro.tune``; ``"force"`` re-measures, bypassing the persistent
-    cache).
-    """
-    warnings.warn(
-        "kernels.ops.spmv is deprecated: build the operator once — "
-        "`operator(a) @ x` (repro.core.operator) — or call repro.solve "
-        "for whole systems", DeprecationWarning, stacklevel=2)
-    from repro.core.operator import operator as _operator
-    op = _operator(a, format=format, backend=backend, **convert_kwargs)
-    return op @ jnp.asarray(x)

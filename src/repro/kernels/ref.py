@@ -20,7 +20,7 @@ from repro.core.formats import WINDOW_UNIT
 from ._backend import acc_dtype as _acc_dtype
 
 __all__ = ["pjds_matvec_ref", "pjds_matmat_ref", "ell_matvec_ref",
-           "sell_matvec_ref", "csr_matvec_ref", "window_columns",
+           "csr_matvec_ref", "window_columns",
            "remainder_matvec_ref", "remainder_rmatvec_ref",
            "csr_rmatvec_ref", "ell_rmatvec_ref", "blocked_rmatvec_ref",
            "cmrs_matvec_ref", "cmrs_rmatvec_ref",
@@ -51,16 +51,6 @@ def pjds_matmat_ref(val: jax.Array, col_idx: jax.Array, row_block: jax.Array,
     contrib = gathered * val.astype(dt)[..., None]
     y_blk = jax.ops.segment_sum(contrib, row_block, num_segments=n_blocks)
     return y_blk.reshape(n_blocks * b_r, x.shape[1])
-
-
-def sell_matvec_ref(val: jax.Array, col_idx: jax.Array, row_block: jax.Array,
-                    inv_perm: jax.Array, x: jax.Array,
-                    n_blocks: int) -> jax.Array:
-    """SELL-C-sigma y = A x with the window-local unpermute fused: the
-    storage-layout matvec is identical to pJDS, then ``inv_perm`` takes y
-    back to the original row order (y[i] = y_sorted[inv_perm[i]])."""
-    y_sorted = pjds_matvec_ref(val, col_idx, row_block, x, n_blocks)
-    return y_sorted[inv_perm]
 
 
 def window_columns(col_off: jax.Array, wbase: jax.Array,

@@ -276,8 +276,7 @@ class OperatorRegistry:
         tags = np.arange(1, m.nnz + 1, dtype=np.float32)
         m_tag = _dc.replace(m, data=tags)
         dev = K.as_device(m_tag, **e.build_kwargs)
-        stored = dev.dev.data if dev.fmt == "csr" else dev.dev.val
-        stored = np.asarray(stored, dtype=np.float64)
+        stored = np.asarray(dev.dev.val, dtype=np.float64)
         vmap = np.rint(stored).astype(np.int64) - 1
         if vmap.shape != tuple(e.op.values.shape):
             return None                      # layout replay drifted: rebuild

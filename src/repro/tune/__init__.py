@@ -11,7 +11,7 @@ an effective-bandwidth + per-format-overhead calibration
 (``calibrate`` -> ``core.perf_model.set_calibration``).
 
 Entry points most callers want are one level up —
-``ops.spmv(a, x, tune="auto")`` / ``operator(a, tune="auto")`` /
+``ops.as_device(a, tune="auto")`` / ``operator(a, tune="auto")`` /
 ``dist_operator(m, mesh, tune="auto")`` — which route here.
 """
 from .space import (Candidate, enumerate_candidates, heuristic_candidate,

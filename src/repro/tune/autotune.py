@@ -1,7 +1,7 @@
 """The tuning drivers: enumerate -> prune -> measure -> cache.
 
 :func:`autotune` is the single-device driver behind
-``ops.as_device(..., tune=...)`` / ``spmv(..., tune=...)`` /
+``ops.as_device(..., tune=...)`` /
 ``operator(..., tune=...)``; :func:`tune_partition` is the distributed
 driver behind ``dist_operator(..., tune=...)``, which chooses the
 ``chunk_l`` of the LOCAL and REMOTE operands independently — their

@@ -249,7 +249,7 @@ def test_pagerank_gathers_x_from_vmem(on_chip):
                              chunk_l=_CHUNK_L,
                              max_chunks=_G500_GROUP_CHUNKS)
         op = DeviceOperator(ops.SparseDevice(
-            fmt="cmrs", shape=(_G500_N, _G500_N), dev=dev, inv_perm=None))
+            fmt="cmrs", shape=(_G500_N, _G500_N), dev=dev))
         return G.pagerank_steps(G.PageRankOperator(op=op, dangling=dangling),
                                 x, 1)
     compiled = _compile(

@@ -191,19 +191,8 @@ def test_storage_estimates_match_built_matrices(seed, fmt):
 
 
 # --------------------------------------------------------------------------
-# Deprecated pre-protocol shims
+# The operator front door
 # --------------------------------------------------------------------------
-def test_spmv_shim_warns_and_still_works(rng):
-    """ops.spmv is a deprecated shim over the operator API: it must warn
-    (pointing at operator / repro.solve) and keep computing correctly."""
-    a = _uniform(rng, 120, density=0.08)
-    m = F.csr_from_dense(a)
-    x = rng.standard_normal(120).astype(np.float32)
-    with pytest.warns(DeprecationWarning, match="operator"):
-        y = np.asarray(ops.spmv(m, jnp.asarray(x)))
-    np.testing.assert_allclose(y, a.astype(np.float64) @ x, atol=1e-4)
-
-
 def test_operator_path_does_not_warn(rng):
     """The replacement API must be warning-free — otherwise every
     migrated caller would still see deprecation noise."""

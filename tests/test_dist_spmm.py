@@ -257,19 +257,3 @@ def test_distributed_block_cg_matches_independent_cg(spmm_results):
     assert spmm_results["cg_res_max"] < 1e-5
     assert spmm_results["x_diff"] < 1e-3
     assert 0 < spmm_results["blk_cg_iters"] < 1500
-
-
-# --------------------------------------------------------------------------
-# Deprecated closure factories (host-side: building warns, no launch)
-# --------------------------------------------------------------------------
-def test_make_dist_closures_warn():
-    """make_dist_matvec/make_dist_matmat are deprecated shims over
-    dist_operator — both must raise DeprecationWarning at build time."""
-    from repro.launch.mesh import make_host_mesh
-    m = M.poisson_2d(8, 8)
-    dist = D.partition_csr(m, 1, b_r=32)
-    mesh = make_host_mesh(1)
-    with pytest.warns(DeprecationWarning, match="dist_operator"):
-        D.make_dist_matvec(dist, mesh)
-    with pytest.warns(DeprecationWarning, match="dist_operator"):
-        D.make_dist_matmat(dist, mesh)

@@ -130,8 +130,8 @@ def test_stored_slots_per_format(fmt):
     m = M.power_law(700)
     op = operator(m, format=fmt)
     inner = op.dev.dev
-    stored = inner.data if fmt == "csr" else inner.val
-    assert op.stored_slots == op.dev.stored_slots == stored.size
+    stored = inner.val
+    assert op.stored_slots == op.dev.dev.stored_slots == stored.size
     assert op.stored_slots >= m.nnz
 
 

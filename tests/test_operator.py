@@ -6,7 +6,7 @@ The acceptance surface of the operator redesign (DESIGN.md §8):
   matmat / rmatvec / rmatmat against the dense reference, lazy ``.T``,
   pytree round-trips, jit and ``lax.while_loop`` carriers;
 * property tests ``A.T @ x == dense.T @ x`` and ``jax.grad`` (through
-  stored values AND x) vs the dense gradient, across all four formats;
+  stored values AND x) vs the dense gradient, across every format;
 * ONE solver source running unmodified on a single-device operator and
   on a distributed mesh operator (the mesh half runs in a subprocess
   with 8 host devices, like the other distributed tests);
@@ -30,7 +30,13 @@ from repro.core.operator import (DeviceOperator, TransposeOperator, operator)
 from repro.kernels import ops
 
 B_R = 32
-FORMATS = ["csr", "ellpack_r", "pjds", "sell"]
+FORMATS = ["csr", "ellpack_r", "pjds", "sell", "wsell", "cmrs"]
+
+
+def _b_r(fmt):
+    """The row-block width each format is built at: the windowed SELL
+    takes only whole 128-lane blocks."""
+    return 128 if fmt == "wsell" else B_R
 
 
 def _random_sparse(rng, n_rows, n_cols, density=0.1):
@@ -52,7 +58,7 @@ def _scaled_close(got, want, atol=1e-5):
 def test_conformance_device_operator(rng, fmt, transpose):
     a = _random_sparse(rng, 96, 160)
     m = F.csr_from_dense(a)
-    op = operator(m, format=fmt, b_r=B_R, transpose=transpose)
+    op = operator(m, format=fmt, b_r=_b_r(fmt), transpose=transpose)
 
     assert op.shape == (96, 160)
     assert op.dtype == np.float32
@@ -79,7 +85,7 @@ def test_conformance_device_operator(rng, fmt, transpose):
 def test_operator_is_pytree_and_jit_carrier(rng, fmt):
     a = _random_sparse(rng, 96, 96)
     m = F.csr_from_dense(a)
-    op = operator(m, format=fmt, b_r=B_R)
+    op = operator(m, format=fmt, b_r=_b_r(fmt))
     x = jnp.asarray(rng.standard_normal(96).astype(np.float32))
 
     # flatten/unflatten round-trip preserves behaviour
@@ -129,7 +135,7 @@ def test_transpose_matches_dense(seed, fmt):
     rng = np.random.default_rng(seed)
     n, c = rng.integers(40, 200), rng.integers(40, 200)
     a = _random_sparse(rng, int(n), int(c))
-    op = operator(F.csr_from_dense(a), format=fmt, b_r=B_R)
+    op = operator(F.csr_from_dense(a), format=fmt, b_r=_b_r(fmt))
     y = rng.standard_normal(int(n)).astype(np.float32)
     _scaled_close(np.asarray(op.T @ y), a.T @ y)
 
@@ -143,7 +149,7 @@ def test_grad_wrt_x_matches_dense(rng, fmt, backend):
     if fmt == "csr" and backend == "kernel":
         pytest.skip("csr has no kernel")
     a = _random_sparse(rng, 96, 96)
-    op = operator(F.csr_from_dense(a), format=fmt, b_r=B_R,
+    op = operator(F.csr_from_dense(a), format=fmt, b_r=_b_r(fmt),
                   backend=backend)
     x = jnp.asarray(rng.standard_normal(96).astype(np.float32))
     w = rng.standard_normal(96).astype(np.float32)
@@ -156,7 +162,7 @@ def test_jvp_through_operator(rng, fmt):
     """Forward mode works too (the derivative rule is a custom_jvp, so
     spmv keeps the jvp support the plain ref path had)."""
     a = _random_sparse(rng, 96, 96)
-    op = operator(F.csr_from_dense(a), format=fmt, b_r=B_R)
+    op = operator(F.csr_from_dense(a), format=fmt, b_r=_b_r(fmt))
     x = jnp.asarray(rng.standard_normal(96).astype(np.float32))
     dx = jnp.asarray(rng.standard_normal(96).astype(np.float32))
     y, y_dot = jax.jvp(lambda v: op @ v, (x,), (dx,))
@@ -170,7 +176,7 @@ def test_grad_wrt_values_linearity(rng, fmt):
     <grad, u> == loss(op.with_values(u) @ x) exactly — an independent
     check that d(Ax)/d(val) reuses the forward gather structure."""
     a = _random_sparse(rng, 96, 96)
-    op = operator(F.csr_from_dense(a), format=fmt, b_r=B_R)
+    op = operator(F.csr_from_dense(a), format=fmt, b_r=_b_r(fmt))
     x = jnp.asarray(rng.standard_normal(96).astype(np.float32))
     w = jnp.asarray(rng.standard_normal(96).astype(np.float32))
 
@@ -235,9 +241,26 @@ def test_sparse_ffn_trainable_end_to_end(rng):
 def test_diagonal_matches_dense(rng, fmt):
     a = _random_sparse(rng, 96, 96, density=0.15)
     np.fill_diagonal(a, rng.standard_normal(96).astype(np.float32))
-    op = operator(F.csr_from_dense(a), format=fmt, b_r=B_R)
+    op = operator(F.csr_from_dense(a), format=fmt, b_r=_b_r(fmt))
     np.testing.assert_allclose(np.asarray(op.diagonal()), np.diag(a),
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("backend", ["ref", "kernel"])
+def test_pjds_is_sell_with_sigma_all_rows(rng, backend):
+    """pJDS is SELL-C-sigma with sigma equal to the padded rows: built
+    from one matrix, the two operators apply bit for bit alike."""
+    a = _random_sparse(rng, 96, 96, density=0.15)
+    m = F.csr_from_dense(a)
+    pjds = operator(m, format="pjds", b_r=B_R, backend=backend)
+    sell = operator(m, format="sell", b_r=B_R, backend=backend,
+                    sigma=-(-96 // B_R) * B_R)
+    x = jnp.asarray(rng.standard_normal(96).astype(np.float32))
+    xs = jnp.asarray(rng.standard_normal((96, 4)).astype(np.float32))
+    for apply in (lambda o: o.matvec(x), lambda o: o.matmat(xs),
+                  lambda o: o.rmatvec(x), lambda o: o.diagonal()):
+        np.testing.assert_array_equal(np.asarray(apply(pjds)),
+                                      np.asarray(apply(sell)))
 
 
 def test_jacobi_pcg_beats_plain_cg(rng):
